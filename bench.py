@@ -17,8 +17,7 @@ keeps continuity with the pre-round-5 single-threaded comparison).
 
 Measurement discipline: every timed device workload runs 3x warm, median
 with min/max spread (bench.sh runs each workload 3x for the same reason);
-all timings are call + host-readback wall time (jax.block_until_ready
-does not block on this platform).
+all timings are call + host-readback wall time.
 
 Per-phase detail: checker.telemetry() now returns the engine's metrics
 registry (obs/metrics.py) — counters, gauges, AND cumulative per-phase
@@ -111,25 +110,16 @@ RING_EXTRA_LANES = 2  # ebits + depth ride the ring beside the S state lanes
 VISITED_LANES = 4  # key_h1, key_h2, parent_h1, parent_h2 (ops/visited_set.py)
 LANE_BYTES = 4  # every lane is uint32
 
-# Peak HBM bandwidth assumed by `--roofline`, GB/s. Deliberately an env
-# knob, not a hardcoded chip claim — set STATERIGHT_TPU_HBM_GBPS to your
-# part's datasheet number when reading the table. The default is
-# single-sourced with the STR606 program-lint roofline
-# (stateright_tpu/analysis/program.py) so the analytic and the
-# XLA-cost-model predictions never assume different hardware; imported
-# lazily to keep the no-jax `--check` path import-free.
-
-
-def _hbm_gbps_default() -> float:
-    from stateright_tpu.analysis.program import HBM_GBPS_DEFAULT
-
-    return HBM_GBPS_DEFAULT
+# Peak HBM bandwidth comes from the device-kind table single-sourced with
+# the STR606 program-lint roofline (stateright_tpu/analysis/program.py
+# HBM_GBPS_BY_KIND), so the analytic and the XLA-cost-model predictions
+# never assume different hardware. A kind missing there is an error.
 
 
 def roofline_report(
     state_width,
     max_actions,
-    hbm_gbps=None,
+    device_kind,
     generated=None,
     unique=None,
     measured_rate=None,
@@ -155,11 +145,14 @@ def roofline_report(
     st/s ceiling it yields is OPTIMISTIC — headroom numbers read as "at
     most this much is left on the table".
     """
+    from stateright_tpu.analysis.program import hbm_gbps as _peak
     from stateright_tpu.ops.visited_set import PRIMARY_ROUNDS
 
+    hbm_gbps = _peak(device_kind)
     if hbm_gbps is None:
-        hbm_gbps = float(
-            os.environ.get("STATERIGHT_TPU_HBM_GBPS", _hbm_gbps_default())
+        raise ValueError(
+            f"no peak HBM bandwidth is known for device kind {device_kind!r}"
+            " (analysis/program.py HBM_GBPS_BY_KIND)"
         )
     S = int(state_width)
     A = max(1, int(max_actions))
@@ -171,6 +164,7 @@ def roofline_report(
     bytes_per_state = probe_bytes + ring_bytes + expand_hash_bytes
     ceiling = hbm_gbps * 1e9 / bytes_per_state
     out = {
+        "device_kind": device_kind,
         "hbm_gbps_assumed": hbm_gbps,
         "state_width": S,
         "max_actions": A,
@@ -196,8 +190,8 @@ def print_roofline(report, out=None):
     bps = report["bytes_per_state"]
     out.write("-- roofline (probe/ring hot path, analytic lower bound) --\n")
     out.write(
-        f"  assumed HBM: {report['hbm_gbps_assumed']:.0f} GB/s"
-        f"  (STATERIGHT_TPU_HBM_GBPS to override)\n"
+        f"  peak HBM: {report['hbm_gbps_assumed']:.0f} GB/s"
+        f"  ({report['device_kind']})\n"
     )
     out.write(
         f"  bytes/generated state: {bps['total']:.1f}"
@@ -573,10 +567,26 @@ def main() -> int:
             return 2
         return compare_bench(path_a, path_b)
 
+    def _flag_value(flag):
+        if flag in sys.argv:
+            i = sys.argv.index(flag)
+            if i + 1 >= len(sys.argv) or sys.argv[i + 1].startswith("-"):
+                raise SystemExit(f"usage: python bench.py {flag} FILE")
+            return sys.argv[i + 1]
+        return None
+
     if "--roofline" in sys.argv:
         # Standalone roofline: no device run — the analytic traffic model
-        # at the 2pc-7 bench shape, optionally seeded with the measured
-        # rate + generated/unique counters of a prior BENCH json.
+        # at the 2pc-7 bench shape for `--device-kind KIND`, optionally
+        # seeded with the measured rate + generated/unique counters of a
+        # prior BENCH json.
+        kind = _flag_value("--device-kind")
+        if not kind:
+            print(
+                "usage: python bench.py --roofline [BENCH.json] "
+                "--device-kind KIND (e.g. 'TPU v5 lite')"
+            )
+            return 2
         i = sys.argv.index("--roofline")
         generated = unique = measured = None
         if i + 1 < len(sys.argv) and not sys.argv[i + 1].startswith("-"):
@@ -592,6 +602,7 @@ def main() -> int:
         rep = roofline_report(
             tm.state_width,
             tm.max_actions,
+            kind,
             generated=generated,
             unique=unique,
             measured_rate=measured,
@@ -599,14 +610,6 @@ def main() -> int:
         print_roofline(rep, out=sys.stdout)
         print(json.dumps({"roofline": rep}))
         return 0
-
-    def _flag_value(flag):
-        if flag in sys.argv:
-            i = sys.argv.index(flag)
-            if i + 1 >= len(sys.argv) or sys.argv[i + 1].startswith("-"):
-                raise SystemExit(f"usage: python bench.py {flag} FILE")
-            return sys.argv[i + 1]
-        return None
 
     history_path = _flag_value("--history")
     gate_path = _flag_value("--gate")
@@ -631,8 +634,9 @@ def main() -> int:
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    from stateright_tpu.compat import configure_compilation_cache
+
+    configure_compilation_cache()
 
     from stateright_tpu import TensorModelAdapter
     from stateright_tpu.models import IncrementTensor, TwoPhaseTensor
@@ -1086,6 +1090,7 @@ def main() -> int:
     detail["roofline"] = roofline_report(
         tm7.state_width,
         tm7.max_actions,
+        jax.devices()[0].device_kind,
         generated=dev7.telemetry().get("states_generated"),
         unique=dev7.unique_state_count(),
         measured_rate=dev_rate,

@@ -1,60 +1,26 @@
-"""JAX version/platform compatibility shims.
+"""JAX backend compatibility shims (written for the installed JAX 0.9).
 
-Two hazards live here so every call site shares one vetted answer:
+Buffer donation is UNSOUND on XLA:CPU when the persistent compilation
+cache is enabled (first measured on jax 0.4.37; kept for 0.9, where the
+tests still run on the CPU backend with the cache on): an executable
+DESERIALIZED from the cache mis-executes donated-buffer while-loop
+programs — in the visited-set claim protocol, 8 of 2,556 inserted keys
+landed off their double-hash probe sequence, silently breaking dedup (a
+resumed 2pc-5 run counted 28,003 "uniques" in an 8,832-state space).
+Freshly compiled executables are always correct; only the cache-hit path
+corrupts, and only with donation. Donation only matters on device
+backends (it keeps the 2x table/ring footprint out of HBM); on CPU the
+arrays are host RAM and the copy is cheap. `donate_argnums_safe(...)`
+therefore returns the requested argnums on TPU/GPU backends and `()` on
+CPU, keeping the persistent cache sound.
 
-1. `shard_map` moved. jax >= 0.5 exposes it as `jax.shard_map`; on the
-   0.4.x line (0.4.37 in this container) it lives at
-   `jax.experimental.shard_map.shard_map`. `get_shard_map()` resolves
-   whichever exists, once, so the mesh engine imports cannot break on
-   either side of the move.
-
-2. Buffer donation is UNSOUND on XLA:CPU when the persistent compilation
-   cache is enabled (measured on jax 0.4.37, this container): an
-   executable DESERIALIZED from the cache mis-executes donated-buffer
-   while-loop programs — in the visited-set claim protocol, 8 of 2,556
-   inserted keys landed off their double-hash probe sequence, silently
-   breaking dedup (a resumed 2pc-5 run counted 28,003 "uniques" in an
-   8,832-state space). Freshly compiled executables are always correct;
-   only the cache-hit path corrupts, and only with donation. Donation
-   only matters on device backends (it keeps the 2x table/ring footprint
-   out of HBM); on CPU the arrays are host RAM and the copy is cheap.
-   `donate_argnums_safe(...)` therefore returns the requested argnums on
-   TPU/GPU backends and `()` on CPU, keeping the persistent cache (which
-   CI relies on for compile wall-clock) sound.
+`configure_compilation_cache()` is the one place that decides where the
+persistent compilation cache lives.
 """
-
 from __future__ import annotations
 
+import os
 from typing import Tuple
-
-
-def get_shard_map():
-    """The `shard_map` transform for the installed jax version.
-
-    jax >= 0.5: `jax.shard_map`; jax 0.4.x: `jax.experimental.shard_map`.
-    The 0.4.x implementation has no replication rule for `lax.while_loop`
-    (the shape of every per-shard era loop here) and must be told to skip
-    that static check, so when the resolved transform accepts `check_rep`
-    it is pinned False; newer jax dropped the parameter along with the
-    limitation.
-    """
-    import inspect
-
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # builtins without introspectable sigs
-        return sm
-    if "check_rep" in params:
-        import functools
-
-        return functools.partial(sm, check_rep=False)
-    return sm
 
 
 def donate_argnums_safe(*argnums: int) -> Tuple[int, ...]:
@@ -97,3 +63,27 @@ def donate_argnums_pinned(
     """
     pin = set(pinned)
     return tuple(a for a in donate_argnums_safe(*argnums) if a not in pin)
+
+
+#: The checkout root: the persistent compilation cache's fixed home when
+#: the environment names none.
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def configure_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing here names another path. Otherwise the cache lives at one
+    fixed path, ``<checkout>/.jax_cache`` (the path is part of what makes
+    an entry hit, so it must not move between runs).
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path

@@ -404,6 +404,7 @@ class HostEngineBase(Checker):
                 {
                     "flops_per_step": cost.get("flops_per_step"),
                     "bytes_per_step": cost.get("bytes_per_step"),
+                    "device_kind": cost.get("device_kind"),
                     "hbm_gbps": cost.get("hbm_gbps"),
                 }
             )

@@ -32,6 +32,7 @@ not supported (they would serialize every path across processes).
 from __future__ import annotations
 
 import multiprocessing as mp
+import os
 import queue as queue_mod
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -261,6 +262,22 @@ def _worker(
     res_q.put(("table", wid, visited))
 
 
+def _start_on_cpu(procs) -> None:
+    """Start spawn-context `procs` with ``JAX_PLATFORMS=cpu`` in their
+    environment, which a spawned child inherits before it imports
+    anything; the parent's own JAX has read its platform already."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for p in procs:
+            p.start()
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
+
+
 class ParallelBfsChecker(HostEngineBase):
     """Multiprocessing ownership-sharded BFS over any picklable Model."""
 
@@ -290,8 +307,9 @@ class ParallelBfsChecker(HostEngineBase):
         model_blob = cloudpickle.dumps(model)
         n = self._n
         # spawn, not fork: the parent typically holds a live JAX runtime
-        # (device tunnels, threads) that must not be duplicated into the
-        # workers; workers import only the model's own modules.
+        # (the device, threads) that must not be duplicated into the
+        # workers. A worker imports this package, and so JAX: it is pinned
+        # to the CPU, since the chip belongs to the parent alone.
         ctx = mp.get_context("spawn")
         in_qs = [ctx.Queue() for _ in range(n)]
         ctl_qs = [ctx.Queue() for _ in range(n)]
@@ -314,8 +332,7 @@ class ParallelBfsChecker(HostEngineBase):
             )
             for w in range(n)
         ]
-        for p in procs:
-            p.start()
+        _start_on_cpu(procs)
 
         # Seed: route init states to their owners.
         seeds: List[List] = [[] for _ in range(n)]

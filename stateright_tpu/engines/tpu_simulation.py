@@ -260,9 +260,8 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
                     jnp.minimum(ptr, u(DEPTH_CAP - 1))
                 ].add(counted.astype(u))
                 covc = (act, covp, dhist)
-            # maxd is a PER-WALK lane, reduced once in the epilogue — a
-            # scalar max-reduce in the carry knocks the loop off the fast
-            # dispatch path on this platform (see engines/tpu_bfs.py).
+            # maxd is a PER-WALK lane, reduced once in the epilogue rather
+            # than by a scalar max-reduce in the carry every step.
             maxd = jnp.maximum(maxd, ptr)
 
             # Property evaluation on the current states (simulation.rs
@@ -469,8 +468,8 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
 
         walk_out = tuple(rows) + (seed, ptr, ebits, frozen.astype(u))
         # Discovery walk indices and path lengths ride the params tail so
-        # the era result is ONE download (each separate device read costs
-        # ~100ms here — the simulation TTFC floor). With coverage enabled
+        # the era result is ONE download (each separate device read is a
+        # host round-trip on the TTFC path). With coverage enabled
         # the era's histograms (act[A] | prop_hits[P] | depth[DEPTH_CAP])
         # ride the same download.
         parts = [
@@ -535,9 +534,9 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
     @jax.jit
     def seed_run(params):
         """Fused seeding + first era: ONE small upload, walk state and
-        path buffers created on device (host<->device round-trips are the
-        TTFC floor on this platform; the walk lanes would otherwise cost
-        an upload each). Walk 0 uses the master seed directly for
+        path buffers created on device (host<->device round-trips sit on
+        the TTFC path; the walk lanes would otherwise cost an upload
+        each). Walk 0 uses the master seed directly for
         reproducibility parity with the host engine (simulation.rs:154)."""
         u = jnp.uint32
         master = params[P_SEED]
@@ -994,41 +993,33 @@ class TpuSimulationChecker(HostEngineBase):
 
     def _profile_stages(self, fp1buf, fp2buf) -> None:
         """Post-run per-stage attribution of device_era wall time
-        (CheckerBuilder.stage_profile(); obs/stageprof.py). Never fatal."""
+        (CheckerBuilder.stage_profile(); obs/stageprof.py). A failure fails
+        the run: the caller asked for the breakdown."""
         if not self._stage_profile:
             return
-        try:
-            import jax.numpy as jnp
+        import jax.numpy as jnp
 
-            from ..obs import stageprof
+        from ..obs import stageprof
 
-            steps = int(self._metrics.get("steps"))
-            era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
-            if steps <= 0 or era_secs <= 0.0:
-                return
-            kernels = _build_sim_stage_kernels(
-                self.tm, self._tprops, self._B, self._L, self._stage_iters
+        steps = int(self._metrics.get("steps"))
+        era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
+        if steps <= 0 or era_secs <= 0.0:
+            return
+        kernels = _build_sim_stage_kernels(
+            self.tm, self._tprops, self._B, self._L, self._stage_iters
+        )
+        seed = jnp.asarray(1, dtype=jnp.uint32)
+        with self._metrics.phase("profiler_overhead"):
+            timed = stageprof.measure_stage_kernels(
+                {
+                    name: (fn, (fp1buf, fp2buf, seed))
+                    for name, fn in kernels.items()
+                },
+                self._stage_iters,
             )
-            seed = jnp.asarray(1, dtype=jnp.uint32)
-            with self._metrics.phase("profiler_overhead"):
-                timed = stageprof.measure_stage_kernels(
-                    {
-                        name: (fn, (fp1buf, fp2buf, seed))
-                        for name, fn in kernels.items()
-                    },
-                    self._stage_iters,
-                )
-            stageprof.attribute_stages(
-                self._metrics, timed, era_secs, steps, self._stage_iters
-            )
-        except Exception as exc:
-            from ..obs.log import get_logger
-
-            self._metrics.set_gauge("stage_profile_error", repr(exc)[:200])
-            get_logger("engines.tpu_simulation").warning(
-                "stage profiling failed (run results unaffected)",
-                error=repr(exc),
-            )
+        stageprof.attribute_stages(
+            self._metrics, timed, era_secs, steps, self._stage_iters
+        )
 
     # -- accessors ----------------------------------------------------------
 

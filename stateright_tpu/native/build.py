@@ -1,11 +1,14 @@
 """Build the native components: `python -m stateright_tpu.native.build`.
 
 Compiles each .cpp target into a .so next to this file with g++ (no
-pybind11 — the binding layers are ctypes in runtime.py / vset.py).
+pybind11 — the binding layers are ctypes in runtime.py / vset.py). A .so
+counts as built only when its ``.sha256`` stamp names the current source's
+content, so a .so built from other source is rebuilt, never loaded.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import shutil
 import subprocess
@@ -36,6 +39,11 @@ def build_one(source: str, output: str, quiet: bool = False) -> bool:
         if not quiet:
             _log.warning("native build: no C++ compiler found")
         return False
+    # Build beside the target and rename into place, so a concurrent
+    # loader never sees a half-written .so. The stamp names the source as
+    # it was read for this build.
+    digest = _source_digest(source)
+    tmp = f"{output}.{os.getpid()}.tmp"
     cmd = [
         gxx,
         "-O2",
@@ -43,7 +51,7 @@ def build_one(source: str, output: str, quiet: bool = False) -> bool:
         "-shared",
         "-fPIC",
         "-o",
-        output,
+        tmp,
         source,
         "-lpthread",
     ]
@@ -59,7 +67,19 @@ def build_one(source: str, output: str, quiet: bool = False) -> bool:
                 "native build failed", compiler=gxx, stderr=proc.stderr
             )
         return False
+    os.replace(tmp, output)
+    with open(_stamp(output), "w", encoding="utf-8") as fh:
+        fh.write(digest)
     return True
+
+
+def _stamp(output: str) -> str:
+    return output + ".sha256"
+
+
+def _source_digest(source: str) -> str:
+    with open(source, "rb") as fh:
+        return hashlib.sha256(fh.read()).hexdigest()
 
 
 def build(quiet: bool = False, target: str = "core") -> bool:
@@ -74,10 +94,14 @@ def build_all(quiet: bool = False) -> dict:
 
 
 def is_built(target: str = "core") -> bool:
+    """True when the target's .so was built from its current source."""
     source, output = TARGETS[target]
-    return os.path.exists(output) and os.path.getmtime(output) >= os.path.getmtime(
-        source
-    )
+    try:
+        with open(_stamp(output), encoding="utf-8") as fh:
+            stamp = fh.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(output) and stamp == _source_digest(source)
 
 
 if __name__ == "__main__":

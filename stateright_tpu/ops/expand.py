@@ -14,12 +14,12 @@ lanes — never a [C, A, S] materialization, whose small minor axes would
 waste the TPU's 8x128 vector tiles.
 
 Property verdicts are returned as RAW PER-ROW HIT MASKS (`prop_hits`), not
-as extracted fingerprints: on the target platform, a loop-carried value
-computed through a reduction -> broadcast -> reduction chain (argmax
-selects, one-hot extractions, max reduces) knocks the whole loop off the
-fast dispatch path (~200ms per iteration, measured). Callers carry the
-masks (or mask snapshots) through their loops with pure elementwise ops
-and extract fingerprints once per block, outside the loop.
+as extracted fingerprints: a loop-carried value computed through a
+reduction -> broadcast -> reduction chain (argmax selects, one-hot
+extractions, max reduces) adds dependent reductions to every step.
+Callers carry the masks (or mask snapshots) through their loops with pure
+elementwise ops and extract fingerprints once per block, outside the
+loop.
 """
 
 from __future__ import annotations
@@ -56,9 +56,8 @@ def build_expand_lean(tm, props, chunk: int):
 
     Returns f(rows, ebits, depth, active, depth_limit) -> ExpandedLean.
 
-    Rationale (measured on this platform, round 5): per-kernel launch
-    overhead is negligible, but EVERY random-access op costs ~7-14ns per
-    padded slot of its width — so the old contract, which materialized
+    Rationale (round 5, before any v5e measurement): EVERY random-access
+    op costs time in proportion to its padded width — so the old contract, which materialized
     fingerprints, parent tiles, and ebits/depth tiles at the padded [C*A]
     width, made the engine pay the full padded width in a dozen wide ops
     per step while only ~20% of slots are valid. This builder returns only

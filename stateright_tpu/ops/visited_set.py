@@ -43,28 +43,19 @@ Duplicate keys *within* a batch therefore need no separate dedup pass:
 the claim protocol guarantees exactly one winner per distinct key, and
 `is_new` counts each distinct new key exactly once.
 
-PALLAS NOTE (round 4, measured on this platform): a hand-written Pallas
-probe kernel was prototyped and is NOT viable here. Pallas itself works
-(basic elementwise kernels compile and run), but TPU Pallas rejects
-vector dynamic indexing into a ref ("Cannot do int indexing on TPU"), so
-the open-addressing probe's random gathers cannot be expressed inside a
-kernel — they must go through XLA's native gather, which is exactly what
-this module does. The insert's cost is dependent-gather latency
-(~65ns/element at rcap widths, chained per probe round), a bound a kernel
-could only beat with scatter/gather DMA primitives TPU Pallas does not
-expose for this access pattern.
+PALLAS NOTE (round 4, on an earlier JAX and chip): a hand-written Pallas
+probe kernel was prototyped and set aside. TPU Pallas rejected vector
+dynamic indexing into a ref ("Cannot do int indexing on TPU"), so the
+open-addressing probe's random gathers could not be expressed inside a
+kernel — they go through XLA's native gather, which is exactly what this
+module does. The insert's cost is dependent-gather latency, chained per
+probe round (not measured on the v5e yet).
 
 The probe loops are COUNTED fori loops in phases: a short full-width
 phase resolves the overwhelming majority, then the rare stragglers are
 cumsum-compacted into a cascade of count-gated tail stages at narrowing
-widths that probe further. Two constraints force this shape on the target
-platform: (a) a top-level `lax.while_loop` with a data-dependent
-predicate costs a host round-trip per iteration on remote-attached
-devices, and (b) compiled programs whose probe loop exceeds ~10 rounds
-fall off the runtime's fast dispatch path entirely (measured: 8 rounds =
-10us/step, 12 rounds = 270ms/step) — so the stages that WOULD push past
-that budget must stay behind count gates that keep them out of the
-common-case step. The candidates that no phase resolves are reported
+widths that probe further, so the common-case step pays only the short
+full-width phase. The candidates that no phase resolves are reported
 `unresolved`; callers must grow the table and keep load <= MAX_LOAD so
 that outcome stays (measurably) one-in-millions — and fail loudly if it
 happens.
@@ -76,7 +67,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-PRIMARY_ROUNDS = 3  # primary probe rounds (platform fast-path limit ~10/loop)
+PRIMARY_ROUNDS = 3  # primary full-width probe rounds
 # At MAX_LOAD=0.25, P(probe chain > 3) ~ 1.6%: three full-width rounds keep
 # the straggler population under the first tail stage's cap even for the
 # largest bench batches (2pc-10: rcap ~ 85K distinct candidates at load
@@ -411,10 +402,9 @@ def insert(table, h1, h2, p1, p2, active, rcap: int | None = None,
 def lookup_parent(table, h1, h2):
     """Probe for fingerprints; returns (found, parent_h1, parent_h2).
 
-    Same double-hashing sequence as `insert`. NOTE: exceeds the platform's
-    fast-dispatch round limit, so each call may take ~100ms — use only for
-    rare host-side queries (prefer `lookup_parent_np` on a downloaded
-    table for chain walks).
+    Same double-hashing sequence as `insert`. One device call per query:
+    use only for rare host-side queries (prefer `lookup_parent_np` on a
+    downloaded table for chain walks).
     """
     keys, v1, v2 = table
     capacity = v1.shape[0]
@@ -473,11 +463,9 @@ def rehash(old_table, new_table):
     return new_table, unresolved.sum(dtype=jnp.uint32)
 
 
-# Host-callable jitted twins. CRITICAL: never call `insert`/`lookup_parent`/
-# `rehash` eagerly — an eagerly-traced lax loop closes over its operands as
-# embedded array constants, which this platform dispatches on a ~100ms
-# degraded path (and the degradation sticks for the whole process). Under jit
-# the operands are tracers and the programs stay on the fast path.
+# Host-callable jitted twins. Never call `insert`/`lookup_parent`/`rehash`
+# eagerly: an eagerly-traced lax loop closes over its operands as embedded
+# array constants. Under jit the operands are tracers.
 # Donation is gated off on CPU: persistent-cache-deserialized executables
 # corrupt donated buffers there (stateright_tpu.compat docstring).
 from ..compat import donate_argnums_safe as _donate

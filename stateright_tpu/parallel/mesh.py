@@ -167,7 +167,7 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
     from jax import lax
     from jax.sharding import PartitionSpec
 
-    from ..compat import donate_argnums_pinned, get_shard_map
+    from ..compat import donate_argnums_pinned
     from ..engines.tpu_bfs import _vcap
     from ..fingerprint import hash_lanes_jnp
     from ..obs.coverage import DEPTH_CAP
@@ -823,7 +823,7 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
         )
 
     spec = PartitionSpec(axis)
-    mapped = get_shard_map()(
+    mapped = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(spec,) * 5,
@@ -878,7 +878,6 @@ def _build_mesh_stage_kernels(tm: TensorModel, props, chunk: int, qcap: int,
     from jax import lax
     from jax.sharding import PartitionSpec
 
-    from ..compat import get_shard_map
     from ..engines.tpu_bfs import _vcap
     from ..fingerprint import hash_lanes_jnp
     from ..ops import frontier as fr
@@ -918,7 +917,7 @@ def _build_mesh_stage_kernels(tm: TensorModel, props, chunk: int, qcap: int,
 
         spec = PartitionSpec(axis)
         return jax.jit(
-            get_shard_map()(
+            jax.shard_map(
                 per_device, mesh=mesh, in_specs=(spec,) * 3,
                 out_specs=spec,
             )
@@ -1032,7 +1031,9 @@ def _build_mesh_stage_kernels(tm: TensorModel, props, chunk: int, qcap: int,
             + me * u(0x85EBCA77) + u(21)
         )
         pool2 = _mix(pool1 ^ u(0x6C62272E))
-        ones = jnp.ones(rwidth, dtype=bool)
+        # The insert's probe carry turns per-shard once it meets the table
+        # shard, so its all-true mask must start device-varying too.
+        ones = lax.pcast(jnp.ones(rwidth, dtype=bool), (axis,), to="varying")
 
         def body(_i, carry):
             tbl, acc = carry
@@ -1102,7 +1103,7 @@ def _build_grow(old_cap: int, new_cap: int, mesh, axis: str):
     import jax
     from jax.sharding import PartitionSpec
 
-    from ..compat import donate_argnums_safe, get_shard_map
+    from ..compat import donate_argnums_safe
     from ..ops import visited_set as vs
 
     def per_device(table):
@@ -1122,7 +1123,7 @@ def _build_grow(old_cap: int, new_cap: int, mesh, axis: str):
 
     spec = PartitionSpec(axis)
     grow = jax.jit(
-        get_shard_map()(
+        jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=((spec,) * 3,),
@@ -1373,15 +1374,15 @@ class ShardedBfsChecker(HostEngineBase):
         # Pack the host-seeded 4-lane rows into the device table layout:
         # per-shard key buffer [2*tcap] (h1 half | h2 half) + parent lanes.
         table = (
-            jnp.asarray(
+            self._put(
                 np.concatenate([table_np[:, :, 0], table_np[:, :, 1]], axis=1)
             ),
-            jnp.asarray(table_np[:, :, 2]),
-            jnp.asarray(table_np[:, :, 3]),
+            self._put(table_np[:, :, 2]),
+            self._put(table_np[:, :, 3]),
         )
-        queue = tuple(jnp.asarray(queue_np[:, :, t]) for t in range(W))
-        rec_fp1 = jnp.zeros((N, NP_), dtype=jnp.uint32)
-        rec_fp2 = jnp.zeros((N, NP_), dtype=jnp.uint32)
+        queue = tuple(self._put(queue_np[:, :, t]) for t in range(W))
+        rec_fp1 = self._put(np.zeros((N, NP_), dtype=np.uint32))
+        rec_fp2 = self._put(np.zeros((N, NP_), dtype=np.uint32))
         heads = np.zeros(N, dtype=np.int64)
 
         depth_limit = (
@@ -2056,7 +2057,7 @@ class ShardedBfsChecker(HostEngineBase):
             _era_w0 = _time.monotonic()
             table, queue, rec_fp1, rec_fp2, params, disc_depth = (
                 self._block.serial(
-                    table, queue, rec_fp1, rec_fp2, jnp.asarray(params_np)
+                    table, queue, rec_fp1, rec_fp2, self._put(params_np)
                 )
             )
             self._metrics.inc("dispatches")
@@ -2238,43 +2239,35 @@ class ShardedBfsChecker(HostEngineBase):
         the mesh (CheckerBuilder.stage_profile(); obs/stageprof.py). The
         kernels run every shard in lockstep, so the attributed `stage_*`
         phases are GLOBAL times; `steps` is normalized to lockstep era
-        iterations (total steps / n_shards). Never fatal."""
+        iterations (total steps / n_shards). A failure fails the run: the
+        caller asked for the breakdown."""
         if not self._stage_profile:
             return
-        try:
-            import jax.numpy as jnp
+        import jax.numpy as jnp
 
-            from ..obs import stageprof
+        from ..obs import stageprof
 
-            steps = int(self._metrics.get("steps")) // max(1, self.n_shards)
-            era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
-            if steps <= 0 or era_secs <= 0.0:
-                return
-            kernels = _build_mesh_stage_kernels(
-                self.tm, self._tprops, self._chunk, self._qcap,
-                self.n_shards, self._quota, self.mesh, "shards",
+        steps = int(self._metrics.get("steps")) // max(1, self.n_shards)
+        era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
+        if steps <= 0 or era_secs <= 0.0:
+            return
+        kernels = _build_mesh_stage_kernels(
+            self.tm, self._tprops, self._chunk, self._qcap,
+            self.n_shards, self._quota, self.mesh, "shards",
+            self._stage_iters,
+        )
+        seeds = jnp.arange(1, self.n_shards + 1, dtype=jnp.uint32)
+        with self._metrics.phase("profiler_overhead"):
+            timed = stageprof.measure_stage_kernels(
+                {
+                    name: (fn, (table, queue, seeds))
+                    for name, fn in kernels.items()
+                },
                 self._stage_iters,
             )
-            seeds = jnp.arange(1, self.n_shards + 1, dtype=jnp.uint32)
-            with self._metrics.phase("profiler_overhead"):
-                timed = stageprof.measure_stage_kernels(
-                    {
-                        name: (fn, (table, queue, seeds))
-                        for name, fn in kernels.items()
-                    },
-                    self._stage_iters,
-                )
-            stageprof.attribute_stages(
-                self._metrics, timed, era_secs, steps, self._stage_iters
-            )
-        except Exception as exc:
-            from ..obs.log import get_logger
-
-            self._metrics.set_gauge("stage_profile_error", repr(exc)[:200])
-            get_logger("parallel.mesh").warning(
-                "stage profiling failed (run results unaffected)",
-                error=repr(exc),
-            )
+        stageprof.attribute_stages(
+            self._metrics, timed, era_secs, steps, self._stage_iters
+        )
 
     # -- checkpoint/resume --------------------------------------------------
 
@@ -2399,21 +2392,19 @@ class ShardedBfsChecker(HostEngineBase):
         # next save must be a fresh full base).
         self._ckpt_delta = None
         table = (
-            jnp.asarray(
-                np.concatenate([data["table0"], data["table1"]], axis=1)
-            ),
-            jnp.asarray(data["table2"]),
-            jnp.asarray(data["table3"]),
+            self._put(np.concatenate([data["table0"], data["table1"]], axis=1)),
+            self._put(data["table2"]),
+            self._put(data["table3"]),
         )
-        queue = tuple(jnp.asarray(data[f"queue{w}"]) for w in range(W))
+        queue = tuple(self._put(data[f"queue{w}"]) for w in range(W))
         return (
             table,
             queue,
             data["heads"].astype(np.int64),
             data["counts"].astype(np.int64),
             meta["rec_bits"],
-            jnp.asarray(data["rec_fp1"]),
-            jnp.asarray(data["rec_fp2"]),
+            self._put(data["rec_fp1"]),
+            self._put(data["rec_fp2"]),
             list(meta["take_caps"]),
             {k: int(v) for k, v in meta["disc_depth_best"].items()},
             list(meta["per_shard_unique"]),
@@ -2429,6 +2420,16 @@ class ShardedBfsChecker(HostEngineBase):
                 return
             idx = (idx + stride) & (cap - 1)
         table_shard[idx] = (h1, h2, 0, 0)
+
+    def _put(self, x):
+        """Upload a host ``[n_shards, ...]`` array straight to its shards:
+        row s goes to mesh device s, never through one device first."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(
+            x, NamedSharding(self.mesh, PartitionSpec("shards"))
+        )
 
     def _per_shard_uniques(self, table_np) -> List[int]:
         return [

@@ -114,12 +114,6 @@ def test_action_counts_match_vbfs():
 
 
 def test_action_counts_match_sharded():
-    try:
-        from stateright_tpu.compat import get_shard_map
-
-        get_shard_map()
-    except Exception:
-        pytest.skip("shard_map unavailable on this jax version")
     tm = TwoPhaseTensor(3)
     host = TensorModelAdapter(tm).checker().spawn_bfs().join()
     s = (

@@ -266,9 +266,12 @@ def test_unstable_config_digest_flagged():
 
 
 def test_deep_tier_produces_predicted_roofline():
-    summary = proglint.program_summary(IncrementTensor(2), cost=True)
+    summary = proglint.program_summary(
+        IncrementTensor(2), cost=True, device_kind="TPU v5 lite"
+    )
     cost = summary["cost"]
     assert cost["bytes_per_step"] > 0
+    assert cost["hbm_gbps"] == 819.0
     assert cost["predicted_states_per_sec"] > 0
     # The deep tier lowered every device program, not just the era loop.
     for name in (
@@ -278,12 +281,29 @@ def test_deep_tier_produces_predicted_roofline():
         assert summary["programs"][name]["ops"] > 0, name
 
 
+def test_unknown_device_kind_gets_no_prediction():
+    report = AnalysisReport("IncrementTensor")
+    proglint.run(
+        IncrementTensor(2), report, cost=True, device_kind="no such chip"
+    )
+    assert any(
+        d.code == "STR606" and "no such chip" in d.message
+        for d in report.diagnostics
+    )
+    summary = proglint.program_summary(
+        IncrementTensor(2), cost=True, device_kind="no such chip"
+    )
+    assert summary["cost"]["hbm_gbps"] is None
+    assert "predicted_states_per_sec" not in summary["cost"]
+
+
 def test_device_run_telemetry_carries_program_snapshot():
     from stateright_tpu import TensorModelAdapter
     from stateright_tpu.engines.compiled import model_signature
 
     tm = IncrementTensor(2)
-    proglint.program_summary(tm, cost=True)  # prime the summary cache
+    # Prime the summary cache.
+    proglint.program_summary(tm, cost=True, device_kind="TPU v5 lite")
     assert proglint.cached_summary(model_signature(tm)) is not None
     checker = (
         TensorModelAdapter(tm)
