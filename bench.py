@@ -44,11 +44,6 @@ fused seed+first-era TTFC lines. Full bench is ~35-45 minutes; sections
 run cheapest-first and each one re-emits the JSON line when it lands.
 
 Round 6 additions:
-  - STAGE TABLE: one extra (untimed) 2pc-7 device run with
-    `.stage_profile()` decomposes the era wall time across the engine's
-    stages (expand/hash/probe/claim/compact/ring) — printed as a table
-    on stderr and recorded under detail.tpc7.stage_profile. The timed
-    headline runs stay unprofiled so the rate is clean.
   - ROOFLINE (`python bench.py --roofline [BENCH.json]`, also emitted
     in-run as detail.roofline): analytic bytes-moved-per-generated-state
     through the probe/ring hot path vs HBM bandwidth — the memory-bound
@@ -211,27 +206,6 @@ def print_roofline(report, out=None):
             f" -> {report['headroom_x']:.0f}x headroom"
             " (dispatch/serialization-bound, not bandwidth-bound)\n"
         )
-
-
-def print_stage_table(phase_ms, us_per_step=None, out=None):
-    """Human-readable per-stage era breakdown (stderr; stdout is the
-    bench's JSON line)."""
-    from stateright_tpu.obs import stage_rows
-
-    out = out if out is not None else sys.stderr
-    rows = stage_rows(phase_ms)
-    if not rows:
-        out.write("-- stage profile: no stage_* phases recorded --\n")
-        return
-    era_ms = phase_ms.get("device_era", sum(ms for _, ms in rows))
-    out.write("-- era stage breakdown (attributed device_era wall ms) --\n")
-    for name, ms in rows:
-        pct = 100.0 * ms / era_ms if era_ms else 0.0
-        line = f"  {name:<8} {ms:>12.1f} ms  {pct:>5.1f}%"
-        if us_per_step and name in us_per_step:
-            line += f"  ({us_per_step[name]:.1f} us/step isolated)"
-        out.write(line + "\n")
-    out.write(f"  {'total':<8} {era_ms:>12.1f} ms\n")
 
 
 def timed3(mk_checker, golden=None, check=None):
@@ -437,7 +411,6 @@ _GATE_EXCLUDE = (
     ".coverage.",
     ".speclint.",
     ".roofline.",
-    ".stage_profile.",
     ".flight.",
 )
 
@@ -1058,32 +1031,6 @@ def main() -> int:
     assert space7.get("samples") == space7.get("k"), detail["tpc7_sample"]
     assert not space7.get("degraded"), detail["tpc7_sample"]
     assert sample_overhead_pct < 2.0, detail["tpc7_sample"]
-
-    # Stage profile: ONE extra run with `.stage_profile()` — kept out of
-    # the timed3 window above so the isolated-stage microbenches (a few
-    # extra dispatches at era shapes) never pollute the headline rate.
-    prof7 = (
-        TensorModelAdapter(tm7)
-        .checker()
-        .stage_profile()
-        .spawn_tpu_bfs(**opts)
-        .join()
-    )
-    assert prof7.unique_state_count() == tpc7_golden
-    ptel = prof7.telemetry()
-    pphase = ptel.get("phase_ms", {})
-    stage_ms = {k: v for k, v in pphase.items() if k.startswith("stage_")}
-    assert stage_ms, "stage_profile() produced no stage_* phases"
-    era_ms = pphase.get("device_era", 0.0)
-    assert era_ms > 0 and abs(sum(stage_ms.values()) - era_ms) <= 0.1 * era_ms
-    print_stage_table(pphase, ptel.get("stage_us_per_step"))
-    detail["tpc7"]["stage_profile"] = {
-        "stage_ms": stage_ms,
-        "device_era_ms": era_ms,
-        "stage_us_per_step": ptel.get("stage_us_per_step"),
-        "model_pct": ptel.get("stage_profile_model_pct"),
-        "iters": ptel.get("stage_profile_iters"),
-    }
 
     # Roofline: analytic bandwidth ceiling seeded with THIS run's
     # generated/unique ratio and measured rate (see roofline_report).

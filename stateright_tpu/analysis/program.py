@@ -7,7 +7,7 @@ multiplexed lane program, the sharded shard_map block) are traced and
 lowered to jaxpr/StableHLO from `jax.ShapeDtypeStruct` abstract arguments
 — no device buffer is allocated and nothing executes — then the lowered
 artifacts are scanned for the regression classes that runtime profilers
-(stageprof, the flight recorder) can only report AFTER a run paid for
+(profiler traces, the flight recorder) can only report AFTER a run paid for
 them:
 
   STR600  a program failed to trace/lower — the family's findings for it

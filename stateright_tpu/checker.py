@@ -51,8 +51,6 @@ class CheckerBuilder:
         self.trace_format_: str = "jsonl"
         self.profile_dir_: Optional[str] = None
         self.coverage_: bool = True
-        self.stage_profile_: bool = False
-        self.stage_profile_iters_: int = 32
         self.strict_: bool = False
         self.strict_samples_: int = 128
         self.lint_report_: Optional[Any] = None
@@ -220,7 +218,10 @@ class CheckerBuilder:
 
     def profile(self, log_dir: str) -> "CheckerBuilder":
         """Bracket the run with `jax.profiler` start/stop_trace into
-        `log_dir`. A no-op when the profiler is unavailable."""
+        `log_dir`. A no-op when the profiler is unavailable. The profile
+        carries the run's phase timers as ``phase:<name>`` host spans and
+        the device engines' stages as ``era/<stage>`` scopes of their
+        device operations (obs/spans.py), on one clock."""
         self.profile_dir_ = log_dir
         return self
 
@@ -243,21 +244,6 @@ class CheckerBuilder:
         self.span_recorder_ = recorder
         self.span_trace_id_ = trace_id
         self.span_parent_id_ = parent_id
-        return self
-
-    def stage_profile(self, enable: bool = True, iters: int = 32) -> "CheckerBuilder":
-        """Attribute the device engines' era wall time across the stages
-        of one BFS/simulation step (expand / hash / probe / claim /
-        compact / ring / canon — obs/stageprof.py). After the run, the
-        engine microbenches each stage in isolation at the run's exact
-        compiled shapes (`iters` repetitions per dispatch) and scales the
-        measured `device_era` time by the resulting shares, surfacing
-        `stage_*` phase timers through `Checker.telemetry()`, the JSONL
-        and Chrome traces, and Prometheus. Costs a few extra dispatches
-        plus one compile per stage at run end; ignored by the host
-        engines (their phases are timed directly)."""
-        self.stage_profile_ = enable
-        self.stage_profile_iters_ = max(1, int(iters))
         return self
 
     def pipeline(

@@ -43,7 +43,7 @@ class HostEngineBase(Checker):
     # engine's job. Engines that genuinely parallelize set this True.
     _supports_threads = False
 
-    def __init__(self, builder: CheckerBuilder, model=None):
+    def __init__(self, builder: CheckerBuilder, model=None, metrics=None):
         if builder.thread_count_ > 1 and not self._supports_threads:
             raise NotImplementedError(
                 f"{type(self).__name__} is single-threaded; "
@@ -70,8 +70,9 @@ class HostEngineBase(Checker):
         self._max_depth = 0
         # Observability: one metrics registry per run (obs/metrics.py) backs
         # Checker.telemetry() for every engine; an optional JSONL trace
-        # stream and jax.profiler bracket ride the builder options.
-        self._metrics = MetricsRegistry()
+        # stream and jax.profiler bracket ride the builder options. An
+        # engine that times its own constructor passes its registry in.
+        self._metrics = metrics if metrics is not None else MetricsRegistry()
         # Speclint pre-flight (stateright_tpu.analysis): in strict mode the
         # engine refuses to launch over error-severity findings; whenever a
         # report exists (strict auto-run or an explicit builder.lint()),

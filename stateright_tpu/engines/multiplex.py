@@ -24,7 +24,7 @@ budget raises with guidance to raise the capacities or run solo
 (`spawn_tpu_bfs` exists precisely for those).
 
 Deliberate non-goals (run solo instead): symmetry reduction, visitors,
-timeouts, state-count targets, tracing, stage profiling.
+timeouts, state-count targets, tracing.
 
 Durability: `run_multiplexed(checkpoint_path=...)` snapshots every
 completed batch of lanes (the per-lane result vectors + lane tables) via
@@ -333,10 +333,6 @@ def _reject_unsupported(builder: CheckerBuilder) -> None:
                 f"multiplexed lanes do not support {what}; run this check "
                 "solo via spawn_tpu_bfs/spawn_bfs"
             )
-    if getattr(builder, "stage_profile_", False):
-        raise ValueError(
-            "multiplexed lanes do not support stage profiling; run solo"
-        )
 
 
 def _batch_snapshot_path(base: str, off: int) -> str:

@@ -56,6 +56,7 @@ def _dbg(msg: str) -> None:
 from ..checker import CheckerBuilder
 from ..core import Expectation
 from ..fingerprint import combine64, split64
+from ..obs.metrics import MetricsRegistry
 from ..path import Path
 from ..tensor import TensorModel, TensorModelAdapter
 from ..ops.tiering import TieredSpillStore, spill_host_budget_bytes
@@ -315,6 +316,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
     from ..obs.coverage import DEPTH_CAP
     from ..ops import frontier as fr
     from ..ops import visited_set as vs
+    from ..obs.spans import stage_scope
     from ..ops.expand import build_expand_lean
 
     S = tm.state_width
@@ -452,16 +454,19 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                     take, jnp.where(loose, u(s_take), u(chunk))
                 )
             active = jnp.arange(chunk, dtype=jnp.uint32) < take
-            popped, _idx = fr.ring_gather(queue, head, chunk)
+            with stage_scope("ring"):
+                popped, _idx = fr.ring_gather(queue, head, chunk)
             rows = popped[:S]
             ebits = popped[S]
             depth = popped[S + 1]
             # Fingerprints are recomputed on pop (elementwise — effectively
             # free) instead of being carried in the ring: two fewer ring
             # lanes in every ring gather/scatter, which ARE the cost here.
-            row_h1, row_h2 = hash_lanes_jnp(rows)
+            with stage_scope("hash"):
+                row_h1, row_h2 = hash_lanes_jnp(rows)
 
-            ex = expand_lean(rows, ebits, depth, active, depth_limit)
+            with stage_scope("expand"):
+                ex = expand_lean(rows, ebits, depth, active, depth_limit)
             # COMPACT EARLY (the round-5 redesign): validity compaction is
             # the only [C*A]-wide random-access work in the step. Hashing,
             # parent lookup, the visited-set insert, and the ring append
@@ -470,14 +475,17 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             # protocol arbitrates them exactly (one winner per distinct
             # key, same benign-race semantics as the reference's DashMap
             # entry API, bfs.rs:302-315).
-            vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
-            cl = tuple(ex.flat[s][vids] for s in range(S))
+            with stage_scope("compact"):
+                vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
+                cl = tuple(ex.flat[s][vids] for s in range(S))
             if canon:
                 # Symmetry reduction: canonicalize at the compacted width,
                 # before fingerprinting — the ring and table then only
                 # ever see representatives.
-                cl = tm.representative_lanes(jnp, cl)
-            ch1, ch2 = hash_lanes_jnp(cl)
+                with stage_scope("canon"):
+                    cl = tm.representative_lanes(jnp, cl)
+            with stage_scope("hash"):
+                ch1, ch2 = hash_lanes_jnp(cl)
             # Stage-2 compaction: in-batch dedup (claim-arbitrated,
             # approximate — the insert arbitrates leftovers exactly) then
             # re-compact to the distinct-candidate width, so the probe
@@ -485,66 +493,71 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             # (~half of vcap) instead of the valid width. The dedup's
             # scratch is small (cache-hot), so its four random ops cost
             # far less than the width they save downstream.
-            reps = fr.claim_dedup(ch1, ch2, vvalid, dedup_cap)
-            dids, dvalid, n_d = vs._compact_ids(reps, rcap)
-            dh1 = ch1[dids]
-            dh2 = ch2[dids]
-            dl = tuple(cl[s][dids] for s in range(S))
-            src = vids[dids] % u(chunk)  # parent row of candidate a*C+c is c
-            dp1 = jnp.where(dvalid, row_h1[src], u(0))
-            dp2 = jnp.where(dvalid, row_h2[src], u(0))
-            debits = ex.ebits[src]
-            ddepth = depth[src] + u(1)
-            table, c_new, unresolved, _n_ovf = vs.insert(
-                table, dh1, dh2, dp1, dp2, dvalid
-            )
-            unres = unresolved.sum(dtype=jnp.uint32)
-            new_count = c_new.sum(dtype=jnp.uint32)
+            with stage_scope("claim"):
+                reps = fr.claim_dedup(ch1, ch2, vvalid, dedup_cap)
+            with stage_scope("compact"):
+                dids, dvalid, n_d = vs._compact_ids(reps, rcap)
+                dh1 = ch1[dids]
+                dh2 = ch2[dids]
+                dl = tuple(cl[s][dids] for s in range(S))
+                # parent row of candidate a*C+c is c
+                src = vids[dids] % u(chunk)
+                dp1 = jnp.where(dvalid, row_h1[src], u(0))
+                dp2 = jnp.where(dvalid, row_h2[src], u(0))
+                debits = ex.ebits[src]
+                ddepth = depth[src] + u(1)
+            with stage_scope("probe"):
+                table, c_new, unresolved, _n_ovf = vs.insert(
+                    table, dh1, dh2, dp1, dp2, dvalid
+                )
+                unres = unresolved.sum(dtype=jnp.uint32)
+                new_count = c_new.sum(dtype=jnp.uint32)
 
             if sample_k:
-                # Space-sampling capture: exactly-once inserts (c_new is
-                # exact even on retried partial steps — inserts commit
-                # once) whose 64-bit fingerprint is lexicographically
-                # below the threshold. Compacted to the small fixed
-                # capture width and appended to the slab; entries past
-                # the width are counted (sdrop) — astronomically rare
-                # under a tight threshold and impossible under a loose
-                # one thanks to the take clamp.
-                below = c_new & (
-                    (dh1 < st1) | ((dh1 == st1) & (dh2 < st2))
-                )
-
-                def _capture(sc):
-                    sfp1, sfp2, sdep, sact, socc, sdrp = sc
-                    cids, cvalid, n_c = vs._compact_ids(
-                        below, DEVICE_STEP_CAP
-                    )
-                    fit = jnp.minimum(n_c, u(DEVICE_STEP_CAP))
-                    pos = socc + jnp.arange(DEVICE_STEP_CAP, dtype=u)
-                    ok_w = cvalid & (pos < u(scap))
-                    # Masked lanes land in the trash slot (index scap) —
-                    # the slab lanes are scap+1 wide and the epilogue
-                    # reads [:scap] only.
-                    widx = jnp.where(ok_w, pos, u(scap))
-                    # flat id a*C+c -> action a
-                    dact = vids[dids] // u(chunk)
-                    return (
-                        sfp1.at[widx].set(dh1[cids]),
-                        sfp2.at[widx].set(dh2[cids]),
-                        sdep.at[widx].set(ddepth[cids]),
-                        sact.at[widx].set(dact[cids]),
-                        socc + fit,
-                        sdrp + (n_c - fit),
+                with stage_scope("sample"):
+                    # Space-sampling capture: exactly-once inserts (c_new is
+                    # exact even on retried partial steps — inserts commit
+                    # once) whose 64-bit fingerprint is lexicographically
+                    # below the threshold. Compacted to the small fixed
+                    # capture width and appended to the slab; entries past
+                    # the width are counted (sdrop) — astronomically rare
+                    # under a tight threshold and impossible under a loose
+                    # one thanks to the take clamp.
+                    below = c_new & (
+                        (dh1 < st1) | ((dh1 == st1) & (dh2 < st2))
                     )
 
-                # Once the threshold tightens (k-th smallest of the seen
-                # set) almost every step captures NOTHING — the cond
-                # skips the compaction and four slab scatters entirely,
-                # so steady-state sampling costs one compare + reduce
-                # per step.
-                sampc = lax.cond(
-                    below.any(), _capture, lambda sc: sc, sampc
-                )
+                    def _capture(sc):
+                        sfp1, sfp2, sdep, sact, socc, sdrp = sc
+                        cids, cvalid, n_c = vs._compact_ids(
+                            below, DEVICE_STEP_CAP
+                        )
+                        fit = jnp.minimum(n_c, u(DEVICE_STEP_CAP))
+                        pos = socc + jnp.arange(DEVICE_STEP_CAP, dtype=u)
+                        ok_w = cvalid & (pos < u(scap))
+                        # Masked lanes land in the trash slot (index scap) —
+                        # the slab lanes are scap+1 wide and the epilogue
+                        # reads [:scap] only.
+                        widx = jnp.where(ok_w, pos, u(scap))
+                        # flat id a*C+c -> action a
+                        dact = vids[dids] // u(chunk)
+                        return (
+                            sfp1.at[widx].set(dh1[cids]),
+                            sfp2.at[widx].set(dh2[cids]),
+                            sdep.at[widx].set(ddepth[cids]),
+                            sact.at[widx].set(dact[cids]),
+                            socc + fit,
+                            sdrp + (n_c - fit),
+                        )
+
+                    # Once the threshold tightens (k-th smallest of the seen
+                    # set) almost every step captures NOTHING — the cond
+                    # skips the compaction and four slab scatters entirely,
+                    # so steady-state sampling costs one compare + reduce
+                    # per step.
+                    sampc = lax.cond(
+                        below.any(), _capture, lambda sc: sc, sampc
+                    )
 
             # Overflow (> vcap valid candidates, > rcap distinct
             # candidates, OR probe-tail overflow reported as unresolved
@@ -558,10 +571,11 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             # probe chains, i.e. state loss.
             err_cnt = err_cnt + jnp.where(take <= u(1), unres, u(0))
             ovf = (n_val > u(vcap)) | (n_d > u(rcap)) | (unres > u(0))
-            tail = (head + count) & u(qmask)
-            queue = fr.ring_scatter(
-                queue, tail, dl + (debits, ddepth), c_new
-            )
+            with stage_scope("ring"):
+                tail = (head + count) & u(qmask)
+                queue = fr.ring_scatter(
+                    queue, tail, dl + (debits, ddepth), c_new
+                )
 
             consumed = jnp.where(ovf, u(0), take)
             head = (head + consumed) & u(qmask)
@@ -589,67 +603,68 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             )
 
             if cov:
-                # Coverage histograms (obs/coverage.py), all in-carry:
-                # per-action valid counts (the action-major [A*C] validity
-                # mask reshaped and row-summed; gated on ovf exactly like
-                # `gen`, so retried partial steps never double-count),
-                # consumed rows (the per-property evaluation count), and
-                # the per-depth insert histogram (inserts count
-                # unconditionally, matching `unique`). None of these feed
-                # the loop gate.
-                #
-                # The depth histogram deliberately avoids a scatter at the
-                # distinct-candidate width (XLA:CPU scatter-adds cost
-                # ~90ns/slot — 1.1ms/step at rcap width vs 0.13ms for this
-                # form, microbenched) AND the reduction->broadcast
-                # min-select the platform notes forbid in this carry:
-                # candidate depths bucket into a fixed window of relative
-                # offsets around depth[0]+1 via plain masked uint32 sums
-                # (the carry-safe reduction pattern, same class as the
-                # discovery-gate sums) and ONE fixed-width scatter lands
-                # them. The window is TWO-SIDED: ring depth is only
-                # non-decreasing up to the interleaved zones the
-                # candidate-order append leaves at depth boundaries (see
-                # _COV_LO above), so lane 0 is an anchor, not a minimum —
-                # inserts up to _COV_LO levels below it count exactly via
-                # wrapped-offset equality. Offsets past either edge clamp
-                # into the boundary bucket — a step would have to pop
-                # states spanning >= _COV_W (or interleave >= _COV_LO)
-                # BFS levels at once to smear a depth, which no bundled
-                # model comes near.
-                act, covp, expanded, dhist = covc
-                pa = ex.valid.reshape(A, chunk).sum(axis=1, dtype=u)
-                act = act + jnp.where(ovf, u(0), pa)
-                expanded = expanded + consumed
-                dmin = depth[0] + u(1)
-                # Biased offset: soffs == _COV_LO <=> ddepth == dmin.
-                soffs = ddepth + u(_COV_LO) - dmin
-                under = soffs >= u(0x80000000)  # beyond the low-side slack
-                cnts = jnp.stack(
-                    [(((soffs == u(0)) | under) & c_new).sum(dtype=u)]
-                    + [
-                        ((soffs == u(w)) & c_new).sum(dtype=u)
-                        for w in range(1, _COV_LO + _COV_W - 1)
-                    ]
-                    + [
-                        (
-                            (soffs >= u(_COV_LO + _COV_W - 1))
-                            & ~under
-                            & c_new
-                        ).sum(dtype=u)
-                    ]
-                )
-                # Bucket w holds depth dmin - _COV_LO + w; saturate the
-                # subtraction at 0 (early eras have dmin < _COV_LO — the
-                # duplicate zero indices only ever receive zero counts,
-                # since no insert sits at depth < 2).
-                dd = dmin + jnp.arange(_COV_LO + _COV_W, dtype=u)
-                idx = jnp.minimum(
-                    jnp.where(dd >= u(_COV_LO), dd - u(_COV_LO), u(0)),
-                    u(DEPTH_CAP - 1),
-                )
-                dhist = dhist.at[idx].add(cnts)
-                covc = (act, covp, expanded, dhist)
+                with stage_scope("coverage"):
+                    # Coverage histograms (obs/coverage.py), all in-carry:
+                    # per-action valid counts (the action-major [A*C] validity
+                    # mask reshaped and row-summed; gated on ovf exactly like
+                    # `gen`, so retried partial steps never double-count),
+                    # consumed rows (the per-property evaluation count), and
+                    # the per-depth insert histogram (inserts count
+                    # unconditionally, matching `unique`). None of these feed
+                    # the loop gate.
+                    #
+                    # The depth histogram deliberately avoids a scatter at the
+                    # distinct-candidate width (XLA:CPU scatter-adds cost
+                    # ~90ns/slot — 1.1ms/step at rcap width vs 0.13ms for this
+                    # form, microbenched) AND the reduction->broadcast
+                    # min-select the platform notes forbid in this carry:
+                    # candidate depths bucket into a fixed window of relative
+                    # offsets around depth[0]+1 via plain masked uint32 sums
+                    # (the carry-safe reduction pattern, same class as the
+                    # discovery-gate sums) and ONE fixed-width scatter lands
+                    # them. The window is TWO-SIDED: ring depth is only
+                    # non-decreasing up to the interleaved zones the
+                    # candidate-order append leaves at depth boundaries (see
+                    # _COV_LO above), so lane 0 is an anchor, not a minimum —
+                    # inserts up to _COV_LO levels below it count exactly via
+                    # wrapped-offset equality. Offsets past either edge clamp
+                    # into the boundary bucket — a step would have to pop
+                    # states spanning >= _COV_W (or interleave >= _COV_LO)
+                    # BFS levels at once to smear a depth, which no bundled
+                    # model comes near.
+                    act, covp, expanded, dhist = covc
+                    pa = ex.valid.reshape(A, chunk).sum(axis=1, dtype=u)
+                    act = act + jnp.where(ovf, u(0), pa)
+                    expanded = expanded + consumed
+                    dmin = depth[0] + u(1)
+                    # Biased offset: soffs == _COV_LO <=> ddepth == dmin.
+                    soffs = ddepth + u(_COV_LO) - dmin
+                    under = soffs >= u(0x80000000)  # beyond the low-side slack
+                    cnts = jnp.stack(
+                        [(((soffs == u(0)) | under) & c_new).sum(dtype=u)]
+                        + [
+                            ((soffs == u(w)) & c_new).sum(dtype=u)
+                            for w in range(1, _COV_LO + _COV_W - 1)
+                        ]
+                        + [
+                            (
+                                (soffs >= u(_COV_LO + _COV_W - 1))
+                                & ~under
+                                & c_new
+                            ).sum(dtype=u)
+                        ]
+                    )
+                    # Bucket w holds depth dmin - _COV_LO + w; saturate the
+                    # subtraction at 0 (early eras have dmin < _COV_LO — the
+                    # duplicate zero indices only ever receive zero counts,
+                    # since no insert sits at depth < 2).
+                    dd = dmin + jnp.arange(_COV_LO + _COV_W, dtype=u)
+                    idx = jnp.minimum(
+                        jnp.where(dd >= u(_COV_LO), dd - u(_COV_LO), u(0)),
+                        u(DEPTH_CAP - 1),
+                    )
+                    dhist = dhist.at[idx].add(cnts)
+                    covc = (act, covp, expanded, dhist)
 
             if P:
                 hseen_n = []
@@ -785,66 +800,67 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             # Under fusion this executes per INNER era — still bounded by
             # the fusion factor, not the step count, so the platform rule
             # (reductions only at era granularity) holds.
-            rec_bits_out = rec_bits
-            for i in range(P):
-                found = jnp.any(hseen[i])
-                # Select the SHALLOWEST snapshot hit, not an arbitrary
-                # one: BFS must report a shortest counterexample even when
-                # later, deeper iterations hit the property at other chunk
-                # positions.
-                sel = jnp.argmin(
-                    jnp.where(hseen[i], faccd[i], u(0xFFFFFFFF))
+            with stage_scope("epilogue"):
+                rec_bits_out = rec_bits
+                for i in range(P):
+                    found = jnp.any(hseen[i])
+                    # Select the SHALLOWEST snapshot hit, not an arbitrary
+                    # one: BFS must report a shortest counterexample even when
+                    # later, deeper iterations hit the property at other chunk
+                    # positions.
+                    sel = jnp.argmin(
+                        jnp.where(hseen[i], faccd[i], u(0xFFFFFFFF))
+                    )
+                    take_new = found & (((rec_bits_out >> u(i)) & u(1)) == u(0))
+                    rec_fp1 = rec_fp1.at[i].set(
+                        jnp.where(take_new, facc1[i][sel], rec_fp1[i])
+                    )
+                    rec_fp2 = rec_fp2.at[i].set(
+                        jnp.where(take_new, facc2[i][sel], rec_fp2[i])
+                    )
+                    rec_bits_out = rec_bits_out | (found.astype(u) << u(i))
+                maxd = jnp.where(
+                    steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
                 )
-                take_new = found & (((rec_bits_out >> u(i)) & u(1)) == u(0))
-                rec_fp1 = rec_fp1.at[i].set(
-                    jnp.where(take_new, facc1[i][sel], rec_fp1[i])
+                # Adaptive era budget (device-side emission): the NEXT era's
+                # step budget rides the P_MAX_STEPS output slot, so a chained
+                # (speculative) dispatch — or the next INNER era of a fused
+                # dispatch — follows the exact deterministic schedule the
+                # serial driver would. TCP-slow-start shape: double after an
+                # era that exhausted its budget with no other exit reason
+                # pending, halve under spill/grow pressure, floor at
+                # BUDGET_MIN, clamp at budget_cap. budget_cap == 0 turns the
+                # emission off (pure pass-through — free-running and
+                # target-bounded runs keep their fixed budgets). The host's
+                # wall-clock cap keeps checkpoint cadence and reporter updates
+                # honest (see the engine driver).
+                fin_hit_final = ((rec_bits_out & fin_any) != u(0)) | (
+                    (fin_all_en != u(0)) & ((rec_bits_out & fin_all) == fin_all)
                 )
-                rec_fp2 = rec_fp2.at[i].set(
-                    jnp.where(take_new, facc2[i][sel], rec_fp2[i])
+                pressure = (count > high_water) | (unique > grow_limit)
+                budget_only = (
+                    (steps >= max_steps)
+                    & (count > u(0))
+                    & ~pressure
+                    & (err_cnt == u(0))
+                    & ~fin_hit_final
                 )
-                rec_bits_out = rec_bits_out | (found.astype(u) << u(i))
-            maxd = jnp.where(
-                steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
-            )
-            # Adaptive era budget (device-side emission): the NEXT era's
-            # step budget rides the P_MAX_STEPS output slot, so a chained
-            # (speculative) dispatch — or the next INNER era of a fused
-            # dispatch — follows the exact deterministic schedule the
-            # serial driver would. TCP-slow-start shape: double after an
-            # era that exhausted its budget with no other exit reason
-            # pending, halve under spill/grow pressure, floor at
-            # BUDGET_MIN, clamp at budget_cap. budget_cap == 0 turns the
-            # emission off (pure pass-through — free-running and
-            # target-bounded runs keep their fixed budgets). The host's
-            # wall-clock cap keeps checkpoint cadence and reporter updates
-            # honest (see the engine driver).
-            fin_hit_final = ((rec_bits_out & fin_any) != u(0)) | (
-                (fin_all_en != u(0)) & ((rec_bits_out & fin_all) == fin_all)
-            )
-            pressure = (count > high_water) | (unique > grow_limit)
-            budget_only = (
-                (steps >= max_steps)
-                & (count > u(0))
-                & ~pressure
-                & (err_cnt == u(0))
-                & ~fin_hit_final
-            )
-            # In adaptive mode max_steps <= budget_cap <= 2^30 always
-            # (host clamp), so the doubling cannot overflow uint32.
-            grown = jnp.minimum(
-                jnp.maximum(max_steps, u(1)) * u(2), budget_cap
-            )
-            shrunk = jnp.maximum(
-                jnp.minimum(max_steps, budget_cap) >> u(1), u(BUDGET_MIN)
-            )
-            next_budget = jnp.where(
-                budget_cap == u(0),
-                max_steps,
-                jnp.where(
-                    pressure, shrunk,
-                    jnp.where(budget_only, grown, max_steps),
-                ),
-            )
+                # In adaptive mode max_steps <= budget_cap <= 2^30 always
+                # (host clamp), so the doubling cannot overflow uint32.
+                grown = jnp.minimum(
+                    jnp.maximum(max_steps, u(1)) * u(2), budget_cap
+                )
+                shrunk = jnp.maximum(
+                    jnp.minimum(max_steps, budget_cap) >> u(1), u(BUDGET_MIN)
+                )
+                next_budget = jnp.where(
+                    budget_cap == u(0),
+                    max_steps,
+                    jnp.where(
+                        pressure, shrunk,
+                        jnp.where(budget_only, grown, max_steps),
+                    ),
+                )
             return (table, queue, head, count, unique, rec_bits_out,
                     err_cnt, take_cap_out, covc_out, sampc_out,
                     rec_fp1, rec_fp2, steps, gen, maxd, next_budget,
@@ -942,65 +958,66 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 fsteps, fgen, funiq, fcount,
             ]
 
-        parts = [
-            jnp.stack(
-                [
-                    head,
-                    count,
-                    unique,
-                    rec_bits_out,
-                    depth_limit,
-                    grow_limit,
-                    high_water,
-                    next_budget,
-                    gen,
-                    maxd,
-                    steps,
-                    (err_cnt > 0).astype(u),
-                    take_cap_out,
-                    fin_any,
-                    fin_all,
-                    fin_all_en,
-                    budget_cap,
+        with stage_scope("epilogue"):
+            parts = [
+                jnp.stack(
+                    [
+                        head,
+                        count,
+                        unique,
+                        rec_bits_out,
+                        depth_limit,
+                        grow_limit,
+                        high_water,
+                        next_budget,
+                        gen,
+                        maxd,
+                        steps,
+                        (err_cnt > 0).astype(u),
+                        take_cap_out,
+                        fin_any,
+                        fin_all,
+                        fin_all_en,
+                        budget_cap,
+                    ]
+                ),
+                rec_fp1,
+                rec_fp2,
+            ]
+            if cov:
+                # Coverage tail: act[A] | prop_hits[P] | expanded[1] | depth
+                # hist[DEPTH_CAP] — this era's deltas, consumed by the host in
+                # the SAME params download as everything else.
+                act, covp, expanded, dhist = covc_out
+                parts += [
+                    act,
+                    jnp.stack(list(covp)) if P else jnp.zeros(0, dtype=u),
+                    expanded[None],
+                    dhist,
                 ]
-            ),
-            rec_fp1,
-            rec_fp2,
-        ]
-        if cov:
-            # Coverage tail: act[A] | prop_hits[P] | expanded[1] | depth
-            # hist[DEPTH_CAP] — this era's deltas, consumed by the host in
-            # the SAME params download as everything else.
-            act, covp, expanded, dhist = covc_out
-            parts += [
-                act,
-                jnp.stack(list(covp)) if P else jnp.zeros(0, dtype=u),
-                expanded[None],
-                dhist,
-            ]
-        if sample_k:
-            # Sample tail: [T1, T2, occupied, sdrop] + the sk2 smallest
-            # slab entries by h1 (one top_k in the once-per-block
-            # epilogue, where such reductions are cheap) with an explicit
-            # validity lane — a real fp1 of 0xFFFFFFFF keys to 0 and
-            # would otherwise be indistinguishable from padding. Ranking
-            # by h1 alone skips 64-bit compares on device; the sk2 - k
-            # pad rows plus the host's tie cut make the 64-bit bottom-k
-            # exact (obs/sample.py module doc).
-            sfp1, sfp2, sdep, sact, socc, sdrp = sampc_out
-            used = jnp.arange(scap, dtype=u) < socc
-            skey = jnp.where(used, ~sfp1[:scap], u(0))
-            _topv, topi = lax.top_k(skey, sk2)
-            parts += [
-                jnp.stack([st1, st2, socc, sdrp]),
-                sfp1[:scap][topi],
-                sfp2[:scap][topi],
-                sdep[:scap][topi],
-                sact[:scap][topi],
-                used[topi].astype(u),
-            ]
-        parts += ftail
-        params_out = jnp.concatenate(parts)
+            if sample_k:
+                # Sample tail: [T1, T2, occupied, sdrop] + the sk2 smallest
+                # slab entries by h1 (one top_k in the once-per-block
+                # epilogue, where such reductions are cheap) with an explicit
+                # validity lane — a real fp1 of 0xFFFFFFFF keys to 0 and
+                # would otherwise be indistinguishable from padding. Ranking
+                # by h1 alone skips 64-bit compares on device; the sk2 - k
+                # pad rows plus the host's tie cut make the 64-bit bottom-k
+                # exact (obs/sample.py module doc).
+                sfp1, sfp2, sdep, sact, socc, sdrp = sampc_out
+                used = jnp.arange(scap, dtype=u) < socc
+                skey = jnp.where(used, ~sfp1[:scap], u(0))
+                _topv, topi = lax.top_k(skey, sk2)
+                parts += [
+                    jnp.stack([st1, st2, socc, sdrp]),
+                    sfp1[:scap][topi],
+                    sfp2[:scap][topi],
+                    sdep[:scap][topi],
+                    sact[:scap][topi],
+                    used[topi].astype(u),
+                ]
+            parts += ftail
+            params_out = jnp.concatenate(parts)
         return table, queue, rec_fp1, rec_fp2, params_out
 
     if raw:
@@ -1096,246 +1113,36 @@ def _build_seed(S: int, qcap: int, tcap: int):
     import jax
     import jax.numpy as jnp
 
+    from ..obs.spans import SEED_SCOPE
     from ..ops import visited_set as vs
 
     W = S + 2  # ring lanes: state | ebits | depth (hashes recomputed on pop)
 
     @jax.jit
     def seed(qinit, h1, h2, params):
-        u = jnp.uint32
-        n_init = qinit.shape[1]
-        table = vs.empty_table(tcap)
-        zero = jnp.zeros(n_init, dtype=jnp.uint32)
-        table, is_new, unresolved, _ovf = vs.insert(
-            table, h1, h2, zero, zero,
-            jnp.ones(n_init, bool),
-        )
-        queue = tuple(
-            jnp.zeros(qcap, dtype=jnp.uint32).at[:n_init].set(qinit[i])
-            for i in range(W)
-        )
-        params_out = (
-            params.at[P_HEAD].set(u(0))
-            .at[P_COUNT].set(u(n_init))
-            .at[P_UNIQUE].set(is_new.sum(dtype=u))
-            .at[P_ERR].set(unresolved.sum(dtype=u))
-        )
-        return table, queue, params_out
+        with jax.named_scope(SEED_SCOPE):
+            u = jnp.uint32
+            n_init = qinit.shape[1]
+            table = vs.empty_table(tcap)
+            zero = jnp.zeros(n_init, dtype=jnp.uint32)
+            table, is_new, unresolved, _ovf = vs.insert(
+                table, h1, h2, zero, zero,
+                jnp.ones(n_init, bool),
+            )
+            queue = tuple(
+                jnp.zeros(qcap, dtype=jnp.uint32).at[:n_init].set(qinit[i])
+                for i in range(W)
+            )
+            params_out = (
+                params.at[P_HEAD].set(u(0))
+                .at[P_COUNT].set(u(n_init))
+                .at[P_UNIQUE].set(is_new.sum(dtype=u))
+                .at[P_ERR].set(unresolved.sum(dtype=u))
+            )
+            return table, queue, params_out
 
     _SEED_CACHE[key] = seed
     return seed
-
-
-# Stage-profiler kernels: (id(tm), chunk, qcap, P, canon, iters) -> dict of
-# jitted per-stage microbench kernels (obs/stageprof.py). Bounded like the
-# loop caches; keyed without tcap because jit re-specializes per table shape.
-_STAGE_KERNEL_CACHE: Dict[Tuple, Tuple[TensorModel, Dict[str, Any]]] = {}
-
-
-def _build_stage_kernels(tm: TensorModel, props, chunk: int, qcap: int,
-                         canon: bool, iters: int) -> Dict[str, Any]:
-    """Build one jitted microbench kernel per era-loop stage.
-
-    Each kernel has the uniform signature (table, queue, seed) -> uint32
-    scalar and repeats EXACTLY the array program of one stage of one BFS
-    step — at the era loop's compiled widths (chunk / C*A / vcap / rcap /
-    dedup_cap, same derivations as `_build_loop`) — `iters` times inside a
-    `lax.fori_loop`. A data dependence threads every iteration through the
-    carried accumulator (or, for probe/ring, through the table/ring buffers
-    themselves), so XLA can neither elide repetitions nor overlap them;
-    the returned scalar anchors every stage output against dead-code
-    elimination. Synthetic operands come from a lowbias32-style mixer at
-    the right widths; the probe kernel inserts into (a copy-on-write fork
-    of) the run's REAL table so it probes at the run's true load factor —
-    it alternates between two bounded key pools, so the fork's load rises
-    by at most 2*rcap/capacity over the whole measurement.
-    """
-    key = (id(tm), chunk, qcap, len(props), canon, iters)
-    cached = _STAGE_KERNEL_CACHE.get(key)
-    if cached is not None and cached[0] is tm:
-        return cached[1]
-    while len(_STAGE_KERNEL_CACHE) >= 8:
-        _STAGE_KERNEL_CACHE.pop(next(iter(_STAGE_KERNEL_CACHE)))
-
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ..fingerprint import hash_lanes_jnp
-    from ..ops import frontier as fr
-    from ..ops import visited_set as vs
-    from ..ops.expand import build_expand_lean
-
-    S = tm.state_width
-    A = tm.max_actions
-    W = S + 2
-    u = jnp.uint32
-    expand_lean = build_expand_lean(tm, props, chunk)
-    qmask = qcap - 1
-    vcap = _vcap(A, chunk)
-    rcap = max(128 * A, (2 * vcap) // 5)
-    dedup_cap = 1 << max(1, (4 * vcap - 1).bit_length())
-
-    def _mix(x):
-        # lowbias32: cheap elementwise mixer for synthetic lane data.
-        x = x ^ (x >> 16)
-        x = x * u(0x7FEB352D)
-        x = x ^ (x >> 15)
-        x = x * u(0x846CA68B)
-        return x ^ (x >> 16)
-
-    def _lane(n, salt):
-        return _mix(jnp.arange(n, dtype=u) * u(0x9E3779B1) + u(salt))
-
-    @jax.jit
-    def k_expand(table, queue, seed):
-        # Successor generation + property evaluation (expand_lean fuses
-        # them, exactly as the era loop consumes it) over real ring rows.
-        rows0 = tuple(queue[s][:chunk] for s in range(S))
-        ebits0 = queue[S][:chunk]
-        depth0 = queue[S + 1][:chunk]
-        active = jnp.ones(chunk, dtype=bool)
-
-        def body(_i, acc):
-            rows = (rows0[0] ^ (acc & u(1)),) + rows0[1:]
-            ex = expand_lean(rows, ebits0, depth0, active, u(0xFFFFFFFF))
-            return acc + ex.generated
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_hash(table, queue, seed):
-        # Both fingerprint passes of one step: popped rows at [chunk] and
-        # compacted candidates at [vcap].
-        rows0 = tuple(queue[s][:chunk] for s in range(S))
-        cl0 = tuple(_lane(vcap, 11 + s) for s in range(S))
-
-        def body(_i, acc):
-            r = (rows0[0] ^ (acc & u(1)),) + rows0[1:]
-            h1, h2 = hash_lanes_jnp(r)
-            c = (cl0[0] ^ (acc & u(1)),) + cl0[1:]
-            g1, g2 = hash_lanes_jnp(c)
-            return acc + h1[0] + h2[0] + g1[0] + g2[0]
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_probe(table, queue, seed):
-        # Visited-set insert at the distinct-candidate width, against the
-        # run's real table (forked copy-on-write into the loop carry) so
-        # probe chains run at the run's true load factor. Keys alternate
-        # between two fixed pools (flip = acc & 1, data-dependent), so the
-        # fork's load rises by at most 2*rcap over all iterations.
-        pool1 = _lane(rcap, 21)
-        pool2 = _mix(pool1 ^ u(0x6C62272E))
-        ones = jnp.ones(rcap, dtype=bool)
-
-        def body(_i, carry):
-            tbl, acc = carry
-            flip = acc & u(1)
-            dh1 = pool1 ^ flip
-            dh2 = pool2 ^ flip
-            tbl, c_new, _unres, _ovf = vs.insert(tbl, dh1, dh2, dh1, dh2, ones)
-            return tbl, acc + c_new.sum(dtype=u)
-
-        tbl, acc = lax.fori_loop(0, iters, body, (table, seed))
-        return acc + (tbl[0][0] & u(1))
-
-    @jax.jit
-    def k_claim(table, queue, seed):
-        # In-batch dedup (fr.claim_dedup) at the valid-candidate width.
-        p1 = _lane(vcap, 31)
-        p2 = _lane(vcap, 37)
-        valid = jnp.ones(vcap, dtype=bool)
-
-        def body(_i, acc):
-            h1 = p1 ^ (acc & u(1))
-            reps = fr.claim_dedup(h1, p2, valid, dedup_cap)
-            return acc + reps.sum(dtype=u)
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_compact(table, queue, seed):
-        # Both validity compactions of one step — [C*A] -> vcap and
-        # vcap -> rcap — INCLUDING the dependent gathers to the compacted
-        # widths (the S state-lane gathers at vcap, then the S+3
-        # candidate/parent gathers at rcap), which are the stage's real
-        # cost.
-        flat0 = tuple(_lane(chunk * A, 41 + s) for s in range(S))
-        r1 = _lane(chunk * A, 53)
-        r2 = _lane(vcap, 59)
-        rowl = _lane(chunk, 61)
-
-        def body(_i, acc):
-            m1 = ((r1 ^ acc) & u(3)) == u(0)  # ~25% valid: protocol fanout
-            vids, _vv, n1 = vs._compact_ids(m1, vcap)
-            cl = tuple(flat0[s][vids] for s in range(S))
-            m2 = ((r2 ^ acc) & u(1)) == u(0)  # ~50% distinct post-dedup
-            dids, _dv, n2 = vs._compact_ids(m2, rcap)
-            dl = tuple(cl[s][dids] for s in range(S))
-            src = vids[dids] % u(chunk)
-            acc = acc + n1 + n2 + rowl[src].sum(dtype=u)
-            for lane in dl:
-                acc = acc + lane.sum(dtype=u)
-            return acc
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_ring(table, queue, seed):
-        # One step's ring traffic: pop-gather [chunk] rows, append-scatter
-        # [rcap] rows, threaded through the (forked) ring so iterations
-        # chain. Per-lane sums anchor the full-width gathers and seed the
-        # appended rows (gather -> cand -> scatter -> next gather).
-        base = jnp.arange(rcap, dtype=u)
-
-        def body(_i, carry):
-            q, head, acc = carry
-            popped, _idx = fr.ring_gather(q, head, chunk)
-            cand = tuple(
-                _mix(base * u(2654435761) + popped[w].sum(dtype=u) + u(w * 17))
-                for w in range(W)
-            )
-            valid = jnp.ones(rcap, dtype=bool)
-            q = fr.ring_scatter(q, (head + u(chunk)) & u(qmask), cand, valid)
-            head = (head + u(chunk)) & u(qmask)
-            return q, head, acc + cand[0][0]
-
-        _q, _h, acc = lax.fori_loop(0, iters, body, (queue, seed, seed))
-        return acc
-
-    kernels: Dict[str, Any] = {
-        "expand": k_expand,
-        "hash": k_hash,
-        "probe": k_probe,
-        "claim": k_claim,
-        "compact": k_compact,
-        "ring": k_ring,
-    }
-
-    if canon:
-
-        @jax.jit
-        def k_canon(table, queue, seed):
-            # Symmetry canonicalization at the valid-candidate width.
-            # Lane values are masked into a small domain so the model's
-            # representative program sees plausible field encodings.
-            cl0 = tuple(_lane(vcap, 71 + s) & u(7) for s in range(S))
-
-            def body(_i, acc):
-                cl = (((cl0[0] ^ (acc & u(1))) & u(7)),) + cl0[1:]
-                reps = tm.representative_lanes(jnp, cl)
-                for lane in reps:
-                    acc = acc + lane.sum(dtype=u)
-                return acc
-
-            return lax.fori_loop(0, iters, body, seed)
-
-        kernels["canon"] = k_canon
-
-    _STAGE_KERNEL_CACHE[key] = (tm, kernels)
-    return kernels
 
 
 # Below roughly this many unique states, the host engine's per-state cost
@@ -1366,175 +1173,174 @@ class TpuBfsChecker(HostEngineBase):
         keep_checkpoints: int = 2,
         compiled=None,
     ):
-        model = builder.model
-        if isinstance(model, TensorModel):
-            model = TensorModelAdapter(model)
-        if not isinstance(model, TensorModelAdapter):
-            raise TypeError(
-                "spawn_tpu_bfs requires a TensorModel (or its adapter); "
-                "rich host models must be encoded first — see stateright_tpu.tensor."
-            )
-        if compiled is not None:
-            # Build/run split (engines/compiled.py): run against the
-            # compiled check's interned model instance so every id(tm)-keyed
-            # jit cache below hits — the request pays a dict lookup, not a
-            # trace + XLA compile.
-            from .compiled import model_signature
-
-            if model_signature(model.tm) != compiled.signature:
-                raise ValueError(
-                    "CompiledCheck signature mismatch: executable was built "
-                    f"for {compiled.signature!r}, builder model is "
-                    f"{model_signature(model.tm)!r}"
+        # The constructor's own time, in the caller's thread (the base
+        # class adopts the registry).
+        metrics = MetricsRegistry()
+        with metrics.phase("engine_init"):
+            model = builder.model
+            if isinstance(model, TensorModel):
+                model = TensorModelAdapter(model)
+            if not isinstance(model, TensorModelAdapter):
+                raise TypeError(
+                    "spawn_tpu_bfs requires a TensorModel (or its adapter); "
+                    "rich host models must be encoded first — see stateright_tpu.tensor."
                 )
-            if model.tm is not compiled.tm:
-                model = TensorModelAdapter(compiled.tm)
-        super().__init__(builder, model=model)
-        if self._visitor is not None:
-            raise ValueError("the TPU engine does not support visitors")
+            if compiled is not None:
+                # Build/run split (engines/compiled.py): run against the
+                # compiled check's interned model instance so every id(tm)-keyed
+                # jit cache below hits — the request pays a dict lookup, not a
+                # trace + XLA compile.
+                from .compiled import model_signature
 
-        self.tm: TensorModel = model.tm
-        # Symmetry reduction ON DEVICE (beyond the reference, whose BFS
-        # ignores options.symmetry — only its DFS canonicalizes): when the
-        # builder asks for symmetry, candidates are canonicalized by the
-        # model's batched representative_lanes program before hashing and
-        # insertion, so the frontier and visited set live entirely in
-        # representative space (2pc-5: 8,832 -> 1,092 states).
-        self._canon = builder.symmetry_fn_ is not None
-        if self._canon and self.tm.representative_lanes is None:
-            raise ValueError(
-                f"symmetry requested but {type(self.tm).__name__} defines "
-                "no representative_lanes canonicalizer"
+                if model_signature(model.tm) != compiled.signature:
+                    raise ValueError(
+                        "CompiledCheck signature mismatch: executable was built "
+                        f"for {compiled.signature!r}, builder model is "
+                        f"{model_signature(model.tm)!r}"
+                    )
+                if model.tm is not compiled.tm:
+                    model = TensorModelAdapter(compiled.tm)
+            super().__init__(builder, model=model, metrics=metrics)
+            if self._visitor is not None:
+                raise ValueError("the TPU engine does not support visitors")
+
+            self.tm: TensorModel = model.tm
+            # Symmetry reduction ON DEVICE (beyond the reference, whose BFS
+            # ignores options.symmetry — only its DFS canonicalizes): when the
+            # builder asks for symmetry, candidates are canonicalized by the
+            # model's batched representative_lanes program before hashing and
+            # insertion, so the frontier and visited set live entirely in
+            # representative space (2pc-5: 8,832 -> 1,092 states).
+            self._canon = builder.symmetry_fn_ is not None
+            if self._canon and self.tm.representative_lanes is None:
+                raise ValueError(
+                    f"symmetry requested but {type(self.tm).__name__} defines "
+                    "no representative_lanes canonicalizer"
+                )
+            self._tprops = self.tm.tensor_properties()
+            n_event = sum(
+                1 for p in self._tprops if p.expectation == Expectation.EVENTUALLY
             )
-        self._tprops = self.tm.tensor_properties()
-        n_event = sum(
-            1 for p in self._tprops if p.expectation == Expectation.EVENTUALLY
-        )
-        if n_event > 32:
-            raise ValueError("at most 32 eventually-properties supported")
-        if len(self._tprops) > 32:
-            # The recorded-discovery set crosses the host boundary as one
-            # uint32 bitmask (see the packed-params layout above).
-            raise ValueError("at most 32 tensor properties supported")
-        if queue_capacity & (queue_capacity - 1):
-            raise ValueError("queue_capacity must be a power of two")
-        # qcap >= 2*C*A guarantees (a) the ring append never wraps over
-        # unconsumed rows while count <= high_water and (b) a spill block
-        # (<= C*A rows) always fits during refill, so spilled states are
-        # never stranded.
-        self._chunk = min(
-            chunk_size, queue_capacity // (2 * max(1, self.tm.max_actions))
-        )
-        if self._chunk == 0:
-            raise ValueError("queue_capacity too small for this model's fanout")
-        self._qcap = queue_capacity
-        self._tcap = table_capacity
-        self._max_sync_steps = sync_steps
-        # Checkpoint/resume: a capability the reference lacks (its runs are
-        # in-memory only, SURVEY.md §5) — the dense table/ring layout makes
-        # a checkpoint a straight array download. Writes are crash-atomic
-        # with rolling generations and a content digest (engines/common.py);
-        # checkpoint_every is wall-clock seconds, polled at era boundaries.
-        validate_checkpoint_cadence(
-            checkpoint_every, checkpoint_path, keep_checkpoints
-        )
-        self._ckpt_path = checkpoint_path
-        self._ckpt_every = checkpoint_every
-        self._ckpt_keep = keep_checkpoints
-        self._resume_from = resume_from
-        self._last_ckpt = time.monotonic()
-        # Chaos-injection hook (tests/test_durability_chaos.py): pretend the
-        # probe budget was exhausted once this era count is reached,
-        # exercising the degraded-regrow recovery without needing a
-        # pathological probe sequence.
-        self._chaos_probe_error_era: Optional[int] = None
-        if checkpoint_path is not None:
-            register_signal_checkpoint_flush(self)
-        self._cov = self._coverage.enabled
-        # Bottom-k space sampling (obs/sample.py): the compiled loop
-        # carries the capture slab only when the builder asked for it.
-        self._sample_k = self._sampler.k if self._sampler is not None else 0
-        # Multi-era fusion factor (CheckerBuilder.pipeline(fuse=N)): the
-        # compiled program chains up to N eras on device per dispatch.
-        # The factor is part of the loop-cache / executable-cache key.
-        self._fuse = max(1, int(getattr(builder, "fuse_eras_", None) or 1))
-        program = _build_loop(
-            self.tm, self._tprops, self._chunk, self._qcap, self._canon,
-            self._cov, sample_k=self._sample_k, fuse=self._fuse,
-        )
-        self._loop = program.serial
-        self._loop_chain = program.chain
-        # Absolute params offset of the fusion tail (only present when
-        # fuse > 1); cached for the driver's readback splitting.
-        self._fbase = params_len(
-            self.tm.max_actions, len(self._tprops), self._cov,
-            self._sample_k,
-        )
+            if n_event > 32:
+                raise ValueError("at most 32 eventually-properties supported")
+            if len(self._tprops) > 32:
+                # The recorded-discovery set crosses the host boundary as one
+                # uint32 bitmask (see the packed-params layout above).
+                raise ValueError("at most 32 tensor properties supported")
+            if queue_capacity & (queue_capacity - 1):
+                raise ValueError("queue_capacity must be a power of two")
+            # qcap >= 2*C*A guarantees (a) the ring append never wraps over
+            # unconsumed rows while count <= high_water and (b) a spill block
+            # (<= C*A rows) always fits during refill, so spilled states are
+            # never stranded.
+            self._chunk = min(
+                chunk_size, queue_capacity // (2 * max(1, self.tm.max_actions))
+            )
+            if self._chunk == 0:
+                raise ValueError("queue_capacity too small for this model's fanout")
+            self._qcap = queue_capacity
+            self._tcap = table_capacity
+            self._max_sync_steps = sync_steps
+            # Checkpoint/resume: a capability the reference lacks (its runs are
+            # in-memory only, SURVEY.md §5) — the dense table/ring layout makes
+            # a checkpoint a straight array download. Writes are crash-atomic
+            # with rolling generations and a content digest (engines/common.py);
+            # checkpoint_every is wall-clock seconds, polled at era boundaries.
+            validate_checkpoint_cadence(
+                checkpoint_every, checkpoint_path, keep_checkpoints
+            )
+            self._ckpt_path = checkpoint_path
+            self._ckpt_every = checkpoint_every
+            self._ckpt_keep = keep_checkpoints
+            self._resume_from = resume_from
+            self._last_ckpt = time.monotonic()
+            # Chaos-injection hook (tests/test_durability_chaos.py): pretend the
+            # probe budget was exhausted once this era count is reached,
+            # exercising the degraded-regrow recovery without needing a
+            # pathological probe sequence.
+            self._chaos_probe_error_era: Optional[int] = None
+            if checkpoint_path is not None:
+                register_signal_checkpoint_flush(self)
+            self._cov = self._coverage.enabled
+            # Bottom-k space sampling (obs/sample.py): the compiled loop
+            # carries the capture slab only when the builder asked for it.
+            self._sample_k = self._sampler.k if self._sampler is not None else 0
+            # Multi-era fusion factor (CheckerBuilder.pipeline(fuse=N)): the
+            # compiled program chains up to N eras on device per dispatch.
+            # The factor is part of the loop-cache / executable-cache key.
+            self._fuse = max(1, int(getattr(builder, "fuse_eras_", None) or 1))
+            program = _build_loop(
+                self.tm, self._tprops, self._chunk, self._qcap, self._canon,
+                self._cov, sample_k=self._sample_k, fuse=self._fuse,
+            )
+            self._loop = program.serial
+            self._loop_chain = program.chain
+            # Absolute params offset of the fusion tail (only present when
+            # fuse > 1); cached for splitting the era loop's readbacks.
+            self._fbase = params_len(
+                self.tm.max_actions, len(self._tprops), self._cov,
+                self._sample_k,
+            )
 
-        # Host-side bookkeeping.
-        self._unique = 0
-        self._discovery_fps: Dict[str, int] = {}
-        # Tiered spill staging (ops/tiering.py): a budgeted host-RAM LIFO
-        # with an npz disk tier below it; unbudgeted (env unset) it is a
-        # plain in-RAM stack, byte-for-byte the old list behavior.
-        self._spill = TieredSpillStore(
-            host_budget_bytes=spill_host_budget_bytes(),
-            on_tier=self._on_spill_tier,
-        )
-        # Delta-checkpoint chain state (engines/common.py
-        # save_checkpoint_tiered): None = next save is a full base.
-        self._ckpt_delta = None
-        # Era of the last proactive reshard (one doubling per forecast).
-        self._reshard_last_era = -1
-        # The metrics registry (obs/metrics.py, created by the base class)
-        # carries the engine's health gauges — eras dispatched, steps
-        # executed, spill/refill row volume, table growths, take_cap —
-        # surfaced via Checker.telemetry() / report, plus per-era phase
-        # timers (device_era, readback, spill, refill, table_grow).
-        self._metrics.set_gauge("take_cap", self._chunk)
-        self._era_t0: Optional[float] = None
-        # Per-stage era profiling (CheckerBuilder.stage_profile()): after
-        # the run, microbench each loop stage at the compiled shapes and
-        # attribute the measured device_era time (obs/stageprof.py).
-        self._stage_profile = bool(getattr(builder, "stage_profile_", False))
-        self._stage_iters = int(getattr(builder, "stage_profile_iters_", 32))
-        # Multiplexed-lane runs are the INTENDED path for sub-crossover
-        # state spaces (serve/README.md): a lane shares one compiled
-        # executable and one fused era with its whole batch, so the
-        # per-run dispatch/compile overheads the hint warns about do not
-        # apply — firing it there would steer users away from the right
-        # engine.
-        self._mux_lane = bool(getattr(builder, "multiplex_lane_", False))
-        # Speculative era pipelining (CheckerBuilder.pipeline(), default
-        # on): keep up to K eras chained off the still-on-device state
-        # while their readbacks are in flight. See the _run driver for
-        # the soundness argument (chained dispatch is an identity no-op
-        # on every device-visible host-intervention exit).
-        self._pipeline = bool(getattr(builder, "pipeline_", True))
-        depth = getattr(builder, "pipeline_depth_", None)
-        # Auto depth 2: one extra era of overlap beyond PR 14's depth-1
-        # covers the readback+bookkeeping gap; deeper chains only pay off
-        # when host work per era exceeds a full device era (rare), while
-        # every extra in-flight era grows the wasted-work window on
-        # host-intervention exits.
-        self._chain_depth = max(1, int(depth)) if depth is not None else 2
-        # High-water mark of in-flight chained dispatches (gauge).
-        self._chain_max = 0
-        # Small-workload guard: with a state-count target under the
-        # crossover, the host engine will beat this one — say so up front
-        # (the run-end check below catches untargeted small runs).
-        if (
-            builder.target_state_count_ is not None
-            and builder.target_state_count_ < SMALL_WORKLOAD_STATES
-        ):
-            self._small_workload_hint(builder.target_state_count_, "targeted")
+            # Host-side bookkeeping.
+            self._unique = 0
+            self._discovery_fps: Dict[str, int] = {}
+            # Tiered spill staging (ops/tiering.py): a budgeted host-RAM LIFO
+            # with an npz disk tier below it; unbudgeted (env unset) it is a
+            # plain in-RAM stack, byte-for-byte the old list behavior.
+            self._spill = TieredSpillStore(
+                host_budget_bytes=spill_host_budget_bytes(),
+                on_tier=self._on_spill_tier,
+            )
+            # Delta-checkpoint chain state (engines/common.py
+            # save_checkpoint_tiered): None = next save is a full base.
+            self._ckpt_delta = None
+            # Era of the last proactive reshard (one doubling per forecast).
+            self._reshard_last_era = -1
+            # The metrics registry (obs/metrics.py, created by the base class)
+            # carries the engine's health gauges — eras dispatched, steps
+            # executed, spill/refill row volume, table growths, take_cap —
+            # surfaced via Checker.telemetry() / report, plus per-era phase
+            # timers (device_era, readback, spill, refill, table_grow).
+            self._metrics.set_gauge("take_cap", self._chunk)
+            self._era_t0: Optional[float] = None
+            # Multiplexed-lane runs are the INTENDED path for sub-crossover
+            # state spaces (serve/README.md): a lane shares one compiled
+            # executable and one fused era with its whole batch, so the
+            # per-run dispatch/compile overheads the hint warns about do not
+            # apply — firing it there would steer users away from the right
+            # engine.
+            self._mux_lane = bool(getattr(builder, "multiplex_lane_", False))
+            # Speculative era pipelining (CheckerBuilder.pipeline(), default
+            # on): keep up to K eras chained off the still-on-device state
+            # while their readbacks are in flight. See the _run driver for
+            # the soundness argument (chained dispatch is an identity no-op
+            # on every device-visible host-intervention exit).
+            self._pipeline = bool(getattr(builder, "pipeline_", True))
+            depth = getattr(builder, "pipeline_depth_", None)
+            # Auto depth 2: one extra era of overlap beyond a single chained
+            # era covers the readback+bookkeeping gap; deeper chains only pay off
+            # when host work per era exceeds a full device era (rare), while
+            # every extra in-flight era grows the wasted-work window on
+            # host-intervention exits.
+            self._chain_depth = max(1, int(depth)) if depth is not None else 2
+            # High-water mark of in-flight chained dispatches (gauge).
+            self._chain_max = 0
+            # Small-workload guard: with a state-count target under the
+            # crossover, the host engine will beat this one — say so up front
+            # (the run-end check below catches untargeted small runs).
+            if (
+                builder.target_state_count_ is not None
+                and builder.target_state_count_ < SMALL_WORKLOAD_STATES
+            ):
+                self._small_workload_hint(builder.target_state_count_, "targeted")
 
-        self._init_ebits_tensor = 0
-        e = 0
-        for p in self._tprops:
-            if p.expectation == Expectation.EVENTUALLY:
-                self._init_ebits_tensor |= 1 << e
-                e += 1
+            self._init_ebits_tensor = 0
+            e = 0
+            for p in self._tprops:
+                if p.expectation == Expectation.EVENTUALLY:
+                    self._init_ebits_tensor |= 1 << e
+                    e += 1
 
         self._start()
 
@@ -1643,115 +1449,123 @@ class TpuBfsChecker(HostEngineBase):
             return self._fuse_auto_n(self._fuse)
 
         _dbg("run: encoding inits")
-        if self._resume_from is not None:
-            table, queue, head, count, rec_bits, rec_fp1, rec_fp2 = (
-                self._load_checkpoint(self._resume_from, W)
-            )
-            first_result_pending = False
-        else:
-            inits = np.asarray(tm.init_states_array(), dtype=np.uint32)
-            init_lanes = tuple(inits[:, i] for i in range(S))
-            inb = np.asarray(
-                tm.within_boundary_lanes(np, init_lanes), dtype=bool
-            )
-            inits = inits[inb]
-            if self._canon:
-                canon_lanes = tm.representative_lanes(
-                    np, tuple(inits[:, i] for i in range(S))
+        with self._metrics.phase("run_setup"):
+            if self._resume_from is not None:
+                table, queue, head, count, rec_bits, rec_fp1, rec_fp2 = (
+                    self._load_checkpoint(self._resume_from, W)
                 )
-                inits = np.stack(
-                    [np.asarray(l, dtype=np.uint32) for l in canon_lanes],
-                    axis=1,
+                first_result_pending = False
+            else:
+                inits = np.asarray(tm.init_states_array(), dtype=np.uint32)
+                init_lanes = tuple(inits[:, i] for i in range(S))
+                inb = np.asarray(
+                    tm.within_boundary_lanes(np, init_lanes), dtype=bool
                 )
-                # Dedupe representatives host-side: distinct raw inits can
-                # canonicalize to one representative, and while the table's
-                # claim insert would keep exactly one, every duplicate ROW
-                # would still enqueue (a redundant re-expansion each) and
-                # count toward state_count — ring contents and counters
-                # must agree with the table's view under symmetry.
-                inits = np.unique(inits, axis=0)
-            n_init = len(inits)
-            self._state_count = n_init
-            if n_init == 0:
-                return
-            if self._cov:
-                # Unique initial states enter the visited set at depth 1
-                # inside the fused seeder, before the loop's histogram
-                # starts counting — record them host-side (distinct rows
-                # == distinct fingerprints short of a hash collision).
-                self._coverage.record_depth(
-                    1, len(np.unique(inits, axis=0))
-                )
-            if n_init > self._qcap:
-                raise ValueError("more initial states than queue capacity")
-            vcap = _vcap(A, C)
-            while n_init + vcap > vs.MAX_LOAD * self._tcap:
-                self._tcap *= 2
+                inits = inits[inb]
+                if self._canon:
+                    canon_lanes = tm.representative_lanes(
+                        np, tuple(inits[:, i] for i in range(S))
+                    )
+                    inits = np.stack(
+                        [np.asarray(l, dtype=np.uint32) for l in canon_lanes],
+                        axis=1,
+                    )
+                    # Dedupe representatives host-side: distinct raw inits can
+                    # canonicalize to one representative, and while the table's
+                    # claim insert would keep exactly one, every duplicate ROW
+                    # would still enqueue (a redundant re-expansion each) and
+                    # count toward state_count — ring contents and counters
+                    # must agree with the table's view under symmetry.
+                    inits = np.unique(inits, axis=0)
+                n_init = len(inits)
+                self._state_count = n_init
+                if n_init == 0:
+                    return
+                if self._cov:
+                    # Unique initial states enter the visited set at depth 1
+                    # inside the fused seeder, before the loop's histogram
+                    # starts counting — record them host-side (distinct rows
+                    # == distinct fingerprints short of a hash collision).
+                    self._coverage.record_depth(
+                        1, len(np.unique(inits, axis=0))
+                    )
+                if n_init > self._qcap:
+                    raise ValueError("more initial states than queue capacity")
+                vcap = _vcap(A, C)
+                while n_init + vcap > vs.MAX_LOAD * self._tcap:
+                    self._tcap *= 2
 
-            # One upload (qinit rows + params template), zero downloads: the
-            # jitted seeder builds the table/ring on device, claim-inserts
-            # the init fingerprints (dup inits resolve like bfs.rs:76-82;
-            # all rows enqueue), and fills count/unique/err into the packed
-            # params, which feed the first era dispatch directly.
-            h1, h2 = hash_words_np(inits)
-            if self._sampler is not None:
-                # The seeder inserts init states before the era loop's
-                # slab starts capturing — offer them host-side (their
-                # rows are in hand anyway, so the sample records carry
-                # real state lanes for free).
-                self._sampler.offer_array(
-                    (h1.astype(np.uint64) << np.uint64(32))
-                    | h2.astype(np.uint64),
-                    depths=np.ones(n_init, dtype=np.int64),
-                    states=inits,
-                )
-            qinit = np.zeros((W, n_init), dtype=np.uint32)
-            qinit[:S] = inits.T
-            qinit[S] = self._init_ebits_tensor
-            qinit[S + 1] = 1
+                # One upload (qinit rows + params template), zero downloads: the
+                # jitted seeder builds the table/ring on device, claim-inserts
+                # the init fingerprints (dup inits resolve like bfs.rs:76-82;
+                # all rows enqueue), and fills count/unique/err into the packed
+                # params, which feed the first era dispatch directly.
+                h1, h2 = hash_words_np(inits)
+                if self._sampler is not None:
+                    # The seeder inserts init states before the era loop's
+                    # slab starts capturing — offer them host-side (their
+                    # rows are in hand anyway, so the sample records carry
+                    # real state lanes for free).
+                    self._sampler.offer_array(
+                        (h1.astype(np.uint64) << np.uint64(32))
+                        | h2.astype(np.uint64),
+                        depths=np.ones(n_init, dtype=np.int64),
+                        states=inits,
+                    )
+                qinit = np.zeros((W, n_init), dtype=np.uint32)
+                qinit[:S] = inits.T
+                qinit[S] = self._init_ebits_tensor
+                qinit[S + 1] = 1
 
-            max_steps0 = max_sync
-            if self._target_state_count is not None:
-                remaining = max(0, self._target_state_count - n_init)
-                max_steps0 = max(
-                    1, min(max_steps0, 1 + remaining // max(1, C * A))
+                max_steps0 = max_sync
+                if self._target_state_count is not None:
+                    remaining = max(0, self._target_state_count - n_init)
+                    max_steps0 = max(
+                        1, min(max_steps0, 1 + remaining // max(1, C * A))
+                    )
+                template = np.zeros(
+                    P_LEN + 2 * P + ncov + nsamp + nfuse, dtype=np.uint32
                 )
-            template = np.zeros(
-                P_LEN + 2 * P + ncov + nsamp + nfuse, dtype=np.uint32
-            )
-            if nfuse:
-                last_fuse_lim = _fuse_lim_now()
-                template[fb] = last_fuse_lim
-            if self._sampler is not None:
-                t1, t2 = self._sampler.threshold_parts()
-                template[s_base] = t1
-                template[s_base + 1] = t2
-                last_thresh = (t1, t2)
-            template[P_DEPTH_LIMIT] = depth_limit
-            template[P_HIGH_WATER] = high_water
-            template[P_MAX_STEPS] = max_steps0
-            template[P_TAKE_CAP] = take_cap
-            template[P_FIN_ANY] = fin_any
-            template[P_FIN_ALL] = fin_all
-            template[P_FIN_ALL_EN] = fin_all_en
-            template[P_BUDGET_CAP] = budget_cap
-            template[P_GROW_LIMIT] = max(
-                0, int(vs.MAX_LOAD * self._tcap) - vcap
-            )
+                if nfuse:
+                    last_fuse_lim = _fuse_lim_now()
+                    template[fb] = last_fuse_lim
+                if self._sampler is not None:
+                    t1, t2 = self._sampler.threshold_parts()
+                    template[s_base] = t1
+                    template[s_base + 1] = t2
+                    last_thresh = (t1, t2)
+                template[P_DEPTH_LIMIT] = depth_limit
+                template[P_HIGH_WATER] = high_water
+                template[P_MAX_STEPS] = max_steps0
+                template[P_TAKE_CAP] = take_cap
+                template[P_FIN_ANY] = fin_any
+                template[P_FIN_ALL] = fin_all
+                template[P_FIN_ALL_EN] = fin_all_en
+                template[P_BUDGET_CAP] = budget_cap
+                template[P_GROW_LIMIT] = max(
+                    0, int(vs.MAX_LOAD * self._tcap) - vcap
+                )
 
-            rec_bits = 0
-            rec_fp1 = jnp.zeros(P, dtype=jnp.uint32)
-            rec_fp2 = jnp.zeros(P, dtype=jnp.uint32)
-            _dbg("run: dispatching fused seed+first-era")
-            seed_run = _build_seed_loop(
-                tm, self._tprops, C, self._qcap, self._tcap, self._canon,
-                self._cov, sample_k=self._sample_k, fuse=self._fuse,
-            )
+                rec_bits = 0
+                rec_fp1 = jnp.zeros(P, dtype=jnp.uint32)
+                rec_fp2 = jnp.zeros(P, dtype=jnp.uint32)
+                _dbg("run: dispatching fused seed+first-era")
+                seed_run = _build_seed_loop(
+                    tm, self._tprops, C, self._qcap, self._tcap, self._canon,
+                    self._cov, sample_k=self._sample_k, fuse=self._fuse,
+                )
+                seed_args = (
+                    jnp.asarray(qinit), jnp.asarray(h1), jnp.asarray(h2),
+                    jnp.asarray(template), rec_fp1, rec_fp2,
+                )
+                first_result_pending = True
+
+        if first_result_pending:
             self._era_t0 = time.monotonic()
-            table, queue, rec_fp1, rec_fp2, params_dev = seed_run(
-                jnp.asarray(qinit), jnp.asarray(h1), jnp.asarray(h2),
-                jnp.asarray(template), rec_fp1, rec_fp2,
-            )
+            with self._metrics.phase("era_dispatch"):
+                table, queue, rec_fp1, rec_fp2, params_dev = seed_run(
+                    *seed_args
+                )
             self._metrics.inc("dispatches")
             head = 0
             count = n_init
@@ -1759,7 +1573,6 @@ class TpuBfsChecker(HostEngineBase):
             self._unique = n_init
             self._mem_register(table, queue, (rec_fp1, rec_fp2), params_dev)
             last_max_steps = max_steps0
-            first_result_pending = True
             _dbg("run: seeded; entering era loop")
 
         # Spill hysteresis: drain down to / refill up to this margin below
@@ -1770,10 +1583,18 @@ class TpuBfsChecker(HostEngineBase):
         stop = False
 
         def process_result(spec_in_flight=False):
-            """Consume one era result (the fused seed+first-era dispatch or
-            a loop dispatch): counters, discoveries, spill, checkpoints,
-            and stop conditions. With ``spec_in_flight`` a chained
-            speculative era is still executing on device: the checkpoint
+            """Read back one era result (the fused seed+first-era dispatch
+            or a loop dispatch) and consume it on the host."""
+            with self._metrics.phase("readback"):
+                vals = np.asarray(params_dev)  # the ONE download per block
+            with self._metrics.phase("era_host"):
+                consume(vals, spec_in_flight)
+
+        def consume(vals, spec_in_flight):
+            """Consume one era's readback `vals`: counters, discoveries,
+            spill, checkpoints, and stop conditions. With
+            ``spec_in_flight`` a chained speculative era is still
+            executing on device: the checkpoint
             save is deferred to the next serial boundary (the table/queue
             bindings here are the NEXT era's output buffers, so a save now
             could pair this era's head/count with a ring the next era has
@@ -1781,8 +1602,6 @@ class TpuBfsChecker(HostEngineBase):
             caller cannot know yet)."""
             nonlocal head, count, take_cap, rec_bits, stop, params_dev
             nonlocal budget, budget_cap, last_thresh
-            with self._metrics.phase("readback"):
-                vals = np.asarray(params_dev)  # the ONE download per block
             era_dt = 0.0
             if self._era_t0 is not None:
                 # The era's true wall time: dispatch through readback
@@ -2181,7 +2000,8 @@ class TpuBfsChecker(HostEngineBase):
                     fin_all_en,
                     budget_cap,
                 ]
-                params_in = jnp.asarray(arr)
+                with self._metrics.phase("upload"):
+                    params_in = jnp.asarray(arr)
             else:
                 params_in = params_dev
             last_max_steps = max_steps
@@ -2189,9 +2009,10 @@ class TpuBfsChecker(HostEngineBase):
 
             _t0 = time.monotonic()
             self._era_t0 = _t0
-            table, queue, rec_fp1, rec_fp2, params_dev = self._loop(
-                table, queue, rec_fp1, rec_fp2, params_in
-            )
+            with self._metrics.phase("era_dispatch"):
+                table, queue, rec_fp1, rec_fp2, params_dev = self._loop(
+                    table, queue, rec_fp1, rec_fp2, params_in
+                )
             self._metrics.inc("dispatches")
             _dbg(
                 f"block dirty={host_dirty} max_steps={max_steps} "
@@ -2232,11 +2053,12 @@ class TpuBfsChecker(HostEngineBase):
                         except AttributeError:
                             pass  # CPU backend: the copy is free anyway
                         t0 = time.monotonic()
-                        (
-                            table, queue, rec_fp1, rec_fp2, nxt,
-                        ) = self._loop_chain(
-                            table, queue, rec_fp1, rec_fp2, src
-                        )
+                        with self._metrics.phase("era_dispatch"):
+                            (
+                                table, queue, rec_fp1, rec_fp2, nxt,
+                            ) = self._loop_chain(
+                                table, queue, rec_fp1, rec_fp2, src
+                            )
                         self._metrics.inc("dispatches")
                         self._metrics.inc("spec_dispatch")
                         chain.append(nxt)
@@ -2366,8 +2188,6 @@ class TpuBfsChecker(HostEngineBase):
             round(self._metrics.get("eras") / n_disp, 3),
         )
 
-        self._profile_stages(table, queue)
-
         # Retained (on device) for path reconstruction; downloaded lazily.
         self._table_dev = table
         if self._memory is not None:
@@ -2489,37 +2309,6 @@ class TpuBfsChecker(HostEngineBase):
             states=n,
             kind=kind,
             crossover=SMALL_WORKLOAD_STATES,
-        )
-
-    def _profile_stages(self, table, queue) -> None:
-        """Post-run per-stage attribution of the device_era wall time
-        (CheckerBuilder.stage_profile(); obs/stageprof.py). A failure fails
-        the run: the caller asked for the breakdown."""
-        if not self._stage_profile:
-            return
-        import jax.numpy as jnp
-
-        from ..obs import stageprof
-
-        steps = int(self._metrics.get("steps"))
-        era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
-        if steps <= 0 or era_secs <= 0.0:
-            return
-        kernels = _build_stage_kernels(
-            self.tm, self._tprops, self._chunk, self._qcap, self._canon,
-            self._stage_iters,
-        )
-        seed = jnp.asarray(1, dtype=jnp.uint32)
-        with self._metrics.phase("profiler_overhead"):
-            timed = stageprof.measure_stage_kernels(
-                {
-                    name: (fn, (table, queue, seed))
-                    for name, fn in kernels.items()
-                },
-                self._stage_iters,
-            )
-        stageprof.attribute_stages(
-            self._metrics, timed, era_secs, steps, self._stage_iters
         )
 
     def _grow_table(self, table):
@@ -2675,8 +2464,9 @@ class TpuBfsChecker(HostEngineBase):
     def _reconstruct(self, fp64: int) -> Path:
         """Walk table parent pointers, then re-execute the model along the
         fingerprint chain (reference bfs.rs:380-409). The table is downloaded
-        once; chains are walked in numpy (per-node device lookups would cost
-        a host round-trip each)."""
+        once (`path_download`); chains are walked in numpy (`path_walk`; per-
+        node device lookups would cost a host round-trip each) and replayed
+        through the model (`path_replay`)."""
         from ..ops import visited_set as vs
 
         if not hasattr(self, "_table_np"):
@@ -2685,26 +2475,28 @@ class TpuBfsChecker(HostEngineBase):
             # Concatenate on device, download ONCE (not one transfer per
             # lane), then split into the four flat lanes lookup_parent_np
             # walks.
-            flat = np.asarray(jnp.concatenate(self._table_dev))
+            with self._metrics.phase("path_download"):
+                flat = np.asarray(jnp.concatenate(self._table_dev))
             cap = flat.shape[0] // 4
             self._table_np = tuple(
                 flat[t * cap:(t + 1) * cap] for t in range(4)
             )
-        chain = [fp64]
-        cur = fp64
-        for _ in range(10_000_000):
-            h1, h2 = split64(cur)
-            found, p1, p2 = vs.lookup_parent_np(self._table_np, h1, h2)
-            if not found:
-                raise RuntimeError(
-                    f"fingerprint {cur} missing from visited table during "
-                    "path reconstruction"
-                )
-            if p1 == 0 and p2 == 0:
-                break
-            cur = combine64(p1, p2)
-            chain.append(cur)
-        chain.reverse()
+        with self._metrics.phase("path_walk"):
+            chain = [fp64]
+            cur = fp64
+            for _ in range(10_000_000):
+                h1, h2 = split64(cur)
+                found, p1, p2 = vs.lookup_parent_np(self._table_np, h1, h2)
+                if not found:
+                    raise RuntimeError(
+                        f"fingerprint {cur} missing from visited table "
+                        "during path reconstruction"
+                    )
+                if p1 == 0 and p2 == 0:
+                    break
+                cur = combine64(p1, p2)
+                chain.append(cur)
+            chain.reverse()
         model = self._model
         if self._canon:
             # The table stores representative fingerprints; match raw
@@ -2712,4 +2504,5 @@ class TpuBfsChecker(HostEngineBase):
             from ..tensor import CanonicalTensorAdapter
 
             model = CanonicalTensorAdapter(self.tm)
-        return Path.from_fingerprints(model, chain)
+        with self._metrics.phase("path_replay"):
+            return Path.from_fingerprints(model, chain)

@@ -89,6 +89,7 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
 
     from ..fingerprint import hash_lanes_jnp
     from ..obs.coverage import DEPTH_CAP
+    from ..obs.spans import stage_scope
 
     S = tm.state_width
     A = tm.max_actions
@@ -196,107 +197,119 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
                 sampc,
             ) = carry
             active = ~frozen
-            h1, h2 = hash_lanes_jnp(rows)
+            with stage_scope("hash"):
+                h1, h2 = hash_lanes_jnp(rows)
 
             # Cycle detection: membership of the current state in the
             # walk's own path so far ([B, L] elementwise compare).
-            f1m = fp1buf.reshape(B, L)
-            f2m = fp2buf.reshape(B, L)
-            in_path = (
-                ((f1m == h1[:, None]) & (f2m == h2[:, None])
-                 & (iota_l[None, :] < ptr[:, None])).sum(axis=1, dtype=u)
-                > u(0)
-            )
-            cycle = active & in_path
+            with stage_scope("cycle"):
+                f1m = fp1buf.reshape(B, L)
+                f2m = fp2buf.reshape(B, L)
+                in_path = (
+                    ((f1m == h1[:, None]) & (f2m == h2[:, None])
+                     & (iota_l[None, :] < ptr[:, None])).sum(axis=1, dtype=u)
+                    > u(0)
+                )
+                cycle = active & in_path
 
             # Record the current state into the path buffer.
-            pos = jnp.where(active & ~cycle, iota_b * u(L) + ptr, u(B * L) + iota_b)
-            fp1buf = fp1buf.at[pos].set(h1, mode="drop", unique_indices=True)
-            fp2buf = fp2buf.at[pos].set(h2, mode="drop", unique_indices=True)
+            with stage_scope("record"):
+                pos = jnp.where(
+                    active & ~cycle, iota_b * u(L) + ptr, u(B * L) + iota_b
+                )
+                fp1buf = fp1buf.at[pos].set(
+                    h1, mode="drop", unique_indices=True
+                )
+                fp2buf = fp2buf.at[pos].set(
+                    h2, mode="drop", unique_indices=True
+                )
             counted = active & ~cycle
             ptr = jnp.where(counted, ptr + u(1), ptr)
             gen = gen + counted.sum(dtype=u)
             if sample_k:
-                # Sample capture: counted states lexicographically below
-                # the threshold, with their state rows in hand (walks
-                # revisit states across traces — the host sampler dedups
-                # by fingerprint, so the sample stays a pure function of
-                # the visited set). Full-B capture width: no drops, ever.
-                from ..ops.visited_set import _compact_ids
+                with stage_scope("sample"):
+                    # Sample capture: counted states lexicographically below
+                    # the threshold, with their state rows in hand (walks
+                    # revisit states across traces — the host sampler dedups
+                    # by fingerprint, so the sample stays a pure function of
+                    # the visited set). Full-B capture width: no drops, ever.
+                    from ..ops.visited_set import _compact_ids
 
-                below = counted & (
-                    (h1 < st1) | ((h1 == st1) & (h2 < st2))
-                )
-
-                def _capture(sc):
-                    sfp1, sfp2, sdep, socc, sst = sc
-                    cids, cvalid, n_c = _compact_ids(below, B)
-                    pos = socc + iota_b
-                    ok_w = cvalid & (pos < u(scap))
-                    widx = jnp.where(ok_w, pos, u(scap))
-                    return (
-                        sfp1.at[widx].set(h1[cids]),
-                        sfp2.at[widx].set(h2[cids]),
-                        sdep.at[widx].set(ptr[cids]),
-                        socc + n_c,
-                        tuple(
-                            sst[s].at[widx].set(rows[s][cids])
-                            for s in range(S)
-                        ),
+                    below = counted & (
+                        (h1 < st1) | ((h1 == st1) & (h2 < st2))
                     )
 
-                # Tight-threshold steps capture nothing almost always;
-                # the cond skips the compaction and the (3+S)-lane slab
-                # scatter on those steps.
-                sampc = lax.cond(
-                    below.any(), _capture, lambda sc: sc, sampc
-                )
+                    def _capture(sc):
+                        sfp1, sfp2, sdep, socc, sst = sc
+                        cids, cvalid, n_c = _compact_ids(below, B)
+                        pos = socc + iota_b
+                        ok_w = cvalid & (pos < u(scap))
+                        widx = jnp.where(ok_w, pos, u(scap))
+                        return (
+                            sfp1.at[widx].set(h1[cids]),
+                            sfp2.at[widx].set(h2[cids]),
+                            sdep.at[widx].set(ptr[cids]),
+                            socc + n_c,
+                            tuple(
+                                sst[s].at[widx].set(rows[s][cids])
+                                for s in range(S)
+                            ),
+                        )
+
+                    # Tight-threshold steps capture nothing almost always;
+                    # the cond skips the compaction and the (3+S)-lane slab
+                    # scatter on those steps.
+                    sampc = lax.cond(
+                        below.any(), _capture, lambda sc: sc, sampc
+                    )
             if cov:
-                # Depth histogram: each counted state lands at its walk
-                # depth (the just-incremented ptr; clamped into the
-                # DEPTH_CAP overflow bucket). One scatter-add at [B].
-                act, covp, dhist = covc
-                dhist = dhist.at[
-                    jnp.minimum(ptr, u(DEPTH_CAP - 1))
-                ].add(counted.astype(u))
-                covc = (act, covp, dhist)
+                with stage_scope("coverage"):
+                    # Depth histogram: each counted state lands at its walk
+                    # depth (the just-incremented ptr; clamped into the
+                    # DEPTH_CAP overflow bucket). One scatter-add at [B].
+                    act, covp, dhist = covc
+                    dhist = dhist.at[
+                        jnp.minimum(ptr, u(DEPTH_CAP - 1))
+                    ].add(counted.astype(u))
+                    covc = (act, covp, dhist)
             # maxd is a PER-WALK lane, reduced once in the epilogue rather
             # than by a scalar max-reduce in the carry every step.
             maxd = jnp.maximum(maxd, ptr)
 
             # Property evaluation on the current states (simulation.rs
             # property loop; eventually-bits clear on satisfaction).
-            prop_hits = [None] * P
-            for i, p in enumerate(props):
-                if p.expectation == Expectation.EVENTUALLY:
-                    sat = p.check(jnp, rows) & counted
-                    ebits = jnp.where(sat, ebits & ~u(1 << e_slot[i]), ebits)
-                elif p.expectation == Expectation.ALWAYS:
-                    prop_hits[i] = counted & ~p.check(jnp, rows)
-                else:
-                    prop_hits[i] = counted & p.check(jnp, rows)
+            with stage_scope("expand"):
+                prop_hits = [None] * P
+                for i, p in enumerate(props):
+                    if p.expectation == Expectation.EVENTUALLY:
+                        sat = p.check(jnp, rows) & counted
+                        ebits = jnp.where(sat, ebits & ~u(1 << e_slot[i]), ebits)
+                    elif p.expectation == Expectation.ALWAYS:
+                        prop_hits[i] = counted & ~p.check(jnp, rows)
+                    else:
+                        prop_hits[i] = counted & p.check(jnp, rows)
 
-            # Successors + enabled mask.
-            succs, amask = tm.step_lanes(jnp, rows)
-            valid_a = []
-            ne = jnp.zeros(B, dtype=u)
-            for a in range(A):
-                v = amask[a] & tm.within_boundary_lanes(jnp, succs[a])
-                valid_a.append(v)
-                ne = ne + v.astype(u)
+                # Successors + enabled mask.
+                succs, amask = tm.step_lanes(jnp, rows)
+                valid_a = []
+                ne = jnp.zeros(B, dtype=u)
+                for a in range(A):
+                    v = amask[a] & tm.within_boundary_lanes(jnp, succs[a])
+                    valid_a.append(v)
+                    ne = ne + v.astype(u)
 
-            terminal = counted & (ne == u(0))
-            capped = counted & (ptr >= u(L))
-            # Walk-end eventually discoveries (terminal rule; a cycle exit
-            # matches the host engine, which also falls through to the
-            # terminal ebits check on loops. Depth-capped walks skip it,
-            # like the host's target_max_depth path).
-            ended_for_ebits = terminal | cycle
-            for i, p in enumerate(props):
-                if p.expectation == Expectation.EVENTUALLY:
-                    prop_hits[i] = ended_for_ebits & (
-                        (ebits & u(1 << e_slot[i])) != u(0)
-                    )
+                terminal = counted & (ne == u(0))
+                capped = counted & (ptr >= u(L))
+                # Walk-end eventually discoveries (terminal rule; a cycle exit
+                # matches the host engine, which also falls through to the
+                # terminal ebits check on loops. Depth-capped walks skip it,
+                # like the host's target_max_depth path).
+                ended_for_ebits = terminal | cycle
+                for i, p in enumerate(props):
+                    if p.expectation == Expectation.EVENTUALLY:
+                        prop_hits[i] = ended_for_ebits & (
+                            (ebits & u(1 << e_slot[i])) != u(0)
+                        )
 
             # Discovery snapshots: first hit per property freezes its walk
             # so the path buffer survives until the era ends.
@@ -329,49 +342,53 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
             frozen = frozen | newly_frozen
 
             # Choose one enabled action uniformly (counter-based PRNG).
-            r = prng(seed ^ (ptr * u(0x9E3779B9)))
-            pick = jnp.where(ne > u(0), r % jnp.maximum(ne, u(1)), u(0))
-            cum = jnp.zeros(B, dtype=u)
-            new_rows = rows
-            chosen_any = ne < u(0)  # all-false, varying
-            sels = []
-            for a in range(A):
-                sel = valid_a[a] & (cum == pick) & ~chosen_any
-                chosen_any = chosen_any | sel
-                sels.append(sel)
-                new_rows = tuple(
-                    jnp.where(sel, succs[a][s], new_rows[s]) for s in range(S)
-                )
-                cum = cum + valid_a[a].astype(u)
+            with stage_scope("choose"):
+                r = prng(seed ^ (ptr * u(0x9E3779B9)))
+                pick = jnp.where(ne > u(0), r % jnp.maximum(ne, u(1)), u(0))
+                cum = jnp.zeros(B, dtype=u)
+                new_rows = rows
+                chosen_any = ne < u(0)  # all-false, varying
+                sels = []
+                for a in range(A):
+                    sel = valid_a[a] & (cum == pick) & ~chosen_any
+                    chosen_any = chosen_any | sel
+                    sels.append(sel)
+                    new_rows = tuple(
+                        jnp.where(sel, succs[a][s], new_rows[s]) for s in range(S)
+                    )
+                    cum = cum + valid_a[a].astype(u)
 
             advance = counted & ~terminal & ~capped & ~newly_frozen
             restart = active & ~newly_frozen & (cycle | terminal | capped)
             if cov:
-                # Action coverage: the transition each advancing walk
-                # actually took this step (the simulation twin of the BFS
-                # engines' valid-successor attribution).
-                act, covp, dhist = covc
-                act = act + jnp.stack(
-                    [(sels[a] & advance).sum(dtype=u) for a in range(A)]
-                )
-                covc = (act, covp, dhist)
+                with stage_scope("coverage"):
+                    # Action coverage: the transition each advancing walk
+                    # actually took this step (the simulation twin of the BFS
+                    # engines' valid-successor attribution).
+                    act, covp, dhist = covc
+                    act = act + jnp.stack(
+                        [(sels[a] & advance).sum(dtype=u) for a in range(A)]
+                    )
+                    covc = (act, covp, dhist)
 
             # Restarts: evolved seed, fresh init state, cleared path row.
-            seed2 = prng(seed + u(0x6A09E667))
-            init_pick = prng(seed2) % u(n_init)
-            rows = tuple(
-                jnp.where(
-                    restart,
-                    inits[s][init_pick],
-                    jnp.where(advance, new_rows[s], rows[s]),
+            with stage_scope("choose"):
+                seed2 = prng(seed + u(0x6A09E667))
+                init_pick = prng(seed2) % u(n_init)
+                rows = tuple(
+                    jnp.where(
+                        restart,
+                        inits[s][init_pick],
+                        jnp.where(advance, new_rows[s], rows[s]),
+                    )
+                    for s in range(S)
                 )
-                for s in range(S)
-            )
             seed = jnp.where(restart, seed2, seed)
             ebits = jnp.where(restart, u(init_ebits), ebits)
-            keep_row = ~restart
-            fp1buf = (fp1buf.reshape(B, L) * keep_row[:, None]).reshape(-1)
-            fp2buf = (fp2buf.reshape(B, L) * keep_row[:, None]).reshape(-1)
+            with stage_scope("record"):
+                keep_row = ~restart
+                fp1buf = (fp1buf.reshape(B, L) * keep_row[:, None]).reshape(-1)
+                fp2buf = (fp2buf.reshape(B, L) * keep_row[:, None]).reshape(-1)
             ptr = jnp.where(restart, u(0), ptr)
 
             steps = steps + u(1)
@@ -456,78 +473,79 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
         # Epilogue: per newly-hit property, report the SHORTEST hit's walk
         # (parity with the BFS engine's shallowest-snapshot rule) as
         # (walk_index, path_length, fp pair).
-        rec_bits_out = rec_bits0
-        disc_walk = jnp.zeros(P, dtype=u)
-        disc_plen = jnp.zeros(P, dtype=u)
-        for i in range(P):
-            found = jnp.any(hseen[i])
-            sel = jnp.argmin(jnp.where(hseen[i], plen[i], u(0xFFFFFFFF)))
-            disc_walk = disc_walk.at[i].set(sel.astype(u))
-            disc_plen = disc_plen.at[i].set(plen[i][sel])
-            rec_bits_out = rec_bits_out | (found.astype(u) << u(i))
+        with stage_scope("epilogue"):
+            rec_bits_out = rec_bits0
+            disc_walk = jnp.zeros(P, dtype=u)
+            disc_plen = jnp.zeros(P, dtype=u)
+            for i in range(P):
+                found = jnp.any(hseen[i])
+                sel = jnp.argmin(jnp.where(hseen[i], plen[i], u(0xFFFFFFFF)))
+                disc_walk = disc_walk.at[i].set(sel.astype(u))
+                disc_plen = disc_plen.at[i].set(plen[i][sel])
+                rec_bits_out = rec_bits_out | (found.astype(u) << u(i))
 
-        walk_out = tuple(rows) + (seed, ptr, ebits, frozen.astype(u))
-        # Discovery walk indices and path lengths ride the params tail so
-        # the era result is ONE download (each separate device read is a
-        # host round-trip on the TTFC path). With coverage enabled
-        # the era's histograms (act[A] | prop_hits[P] | depth[DEPTH_CAP])
-        # ride the same download.
-        parts = [
-            jnp.stack(
-                [
-                    rec_bits_out,
-                    params[P_MAX_STEPS],
-                    params[P_FIN_ANY],
-                    params[P_FIN_ALL],
-                    params[P_FIN_ALL_EN],
-                    params[P_TARGET_GEN],
-                    gen0 + gen,
-                    gen0 + gen,
-                    steps,
-                    maxd.max(),
-                    params[P_SEED],
+            walk_out = tuple(rows) + (seed, ptr, ebits, frozen.astype(u))
+            # Discovery walk indices and path lengths ride the params tail so
+            # the era result is ONE download (each separate device read is a
+            # host round-trip on the TTFC path). With coverage enabled
+            # the era's histograms (act[A] | prop_hits[P] | depth[DEPTH_CAP])
+            # ride the same download.
+            parts = [
+                jnp.stack(
+                    [
+                        rec_bits_out,
+                        params[P_MAX_STEPS],
+                        params[P_FIN_ANY],
+                        params[P_FIN_ALL],
+                        params[P_FIN_ALL_EN],
+                        params[P_TARGET_GEN],
+                        gen0 + gen,
+                        gen0 + gen,
+                        steps,
+                        maxd.max(),
+                        params[P_SEED],
+                    ]
+                ),
+                disc_walk,
+                disc_plen,
+            ]
+            if cov:
+                act, covp, dhist = covc_out
+                parts += [
+                    act,
+                    jnp.stack(list(covp)) if P else jnp.zeros(0, dtype=u),
+                    dhist,
                 ]
-            ),
-            disc_walk,
-            disc_plen,
-        ]
-        if cov:
-            act, covp, dhist = covc_out
-            parts += [
-                act,
-                jnp.stack(list(covp)) if P else jnp.zeros(0, dtype=u),
-                dhist,
-            ]
-        if sample_k:
-            # Sample tail: [T1, T2, occupied, sdrop=0] + the sk2 smallest
-            # slab entries by h1 (fp1 | fp2 | depth | S state lanes | ok)
-            # — one top_k in the once-per-era epilogue. The ok lane
-            # disambiguates padding from a real fp1 of 0xFFFFFFFF.
-            # Walks revisit states, so the slab holds duplicate fps and a
-            # plain top_k would spend all sk2 lanes on copies of the few
-            # smallest — dedup first (first occurrence wins; O(scap^2)
-            # bool matrix, epilogue-only so it costs once per era).
-            sfp1, sfp2, sdep, socc, sst = sampc_out
-            used = jnp.arange(scap, dtype=u) < socc
-            f1, f2 = sfp1[:scap], sfp2[:scap]
-            same = (
-                (f1[:, None] == f1[None, :])
-                & (f2[:, None] == f2[None, :])
-                & used[None, :]
-            )
-            idx = jnp.arange(scap, dtype=u)
-            dup = (same & (idx[None, :] < idx[:, None])).any(axis=1)
-            used = used & ~dup
-            skey = jnp.where(used, ~sfp1[:scap], u(0))
-            _topv, topi = lax.top_k(skey, sk2)
-            parts += [
-                jnp.stack([st1, st2, socc, u(0)]),
-                sfp1[:scap][topi],
-                sfp2[:scap][topi],
-                sdep[:scap][topi],
-            ]
-            parts += [sst[s][:scap][topi] for s in range(S)]
-            parts += [used[topi].astype(u)]
+            if sample_k:
+                # Sample tail: [T1, T2, occupied, sdrop=0] + the sk2 smallest
+                # slab entries by h1 (fp1 | fp2 | depth | S state lanes | ok)
+                # — one top_k in the once-per-era epilogue. The ok lane
+                # disambiguates padding from a real fp1 of 0xFFFFFFFF.
+                # Walks revisit states, so the slab holds duplicate fps and a
+                # plain top_k would spend all sk2 lanes on copies of the few
+                # smallest — dedup first (first occurrence wins; O(scap^2)
+                # bool matrix, epilogue-only so it costs once per era).
+                sfp1, sfp2, sdep, socc, sst = sampc_out
+                used = jnp.arange(scap, dtype=u) < socc
+                f1, f2 = sfp1[:scap], sfp2[:scap]
+                same = (
+                    (f1[:, None] == f1[None, :])
+                    & (f2[:, None] == f2[None, :])
+                    & used[None, :]
+                )
+                idx = jnp.arange(scap, dtype=u)
+                dup = (same & (idx[None, :] < idx[:, None])).any(axis=1)
+                used = used & ~dup
+                skey = jnp.where(used, ~sfp1[:scap], u(0))
+                _topv, topi = lax.top_k(skey, sk2)
+                parts += [
+                    jnp.stack([st1, st2, socc, u(0)]),
+                    sfp1[:scap][topi],
+                    sfp2[:scap][topi],
+                    sdep[:scap][topi],
+                ]
+                parts += [sst[s][:scap][topi] for s in range(S)]
+                parts += [used[topi].astype(u)]
         params_out = jnp.concatenate(parts)
         return walk_out, fp1buf, fp2buf, params_out
 
@@ -558,179 +576,6 @@ def _build_sim_loop(tm: TensorModel, props, B: int, L: int, cov: bool = True,
 
     _LOOP_CACHE[key] = (tm, (loop, seed_run, n_init))
     return loop, seed_run, n_init
-
-
-# Stage-profiler kernels (obs/stageprof.py): one jitted microbench per
-# sim-loop stage, uniform signature (fp1buf, fp2buf, seed) -> uint32.
-_STAGE_KERNEL_CACHE: Dict[Tuple, Tuple[TensorModel, Dict[str, Any]]] = {}
-
-
-def _build_sim_stage_kernels(tm: TensorModel, props, B: int, L: int,
-                             iters: int) -> Dict[str, Any]:
-    """Per-stage microbench kernels for the simulation era loop.
-
-    Stage map (one walk step): `hash` — fingerprint the B current states;
-    `cycle` — the [B, L] own-path membership compare; `record` — the path
-    buffer scatter plus the restart row-clear multiply; `expand` —
-    `tm.step_lanes` + boundary masks + property evaluation (evaluated
-    together in the loop); `choose` — the counter-PRNG pick and the
-    A-round successor select. Same measurement discipline as the BFS
-    engine's `_build_stage_kernels` (engines/tpu_bfs.py): `iters`
-    repetitions per dispatch chained through the carry, outputs anchored
-    into the returned scalar.
-    """
-    key = (id(tm), B, L, len(props), iters)
-    cached = _STAGE_KERNEL_CACHE.get(key)
-    if cached is not None and cached[0] is tm:
-        return cached[1]
-    while len(_STAGE_KERNEL_CACHE) >= 8:
-        _STAGE_KERNEL_CACHE.pop(next(iter(_STAGE_KERNEL_CACHE)))
-
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ..fingerprint import hash_lanes_jnp
-
-    S = tm.state_width
-    A = tm.max_actions
-    u = jnp.uint32
-
-    def _mix(x):
-        x = x ^ (x >> 16)
-        x = x * u(0x7FEB352D)
-        x = x ^ (x >> 15)
-        x = x * u(0x846CA68B)
-        return x ^ (x >> 16)
-
-    def _lane(n, salt):
-        return _mix(jnp.arange(n, dtype=u) * u(0x9E3779B1) + u(salt))
-
-    @jax.jit
-    def k_hash(fp1buf, fp2buf, seed):
-        rows0 = tuple(_lane(B, 3 + s) & u(7) for s in range(S))
-
-        def body(_i, acc):
-            r = ((rows0[0] ^ (acc & u(1))) & u(7),) + rows0[1:]
-            h1, h2 = hash_lanes_jnp(r)
-            return acc + h1[0] + h2[0]
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_cycle(fp1buf, fp2buf, seed):
-        h0 = _lane(B, 13)
-        g0 = _lane(B, 17)
-        ptr = _lane(B, 19) % u(max(1, L))
-        il = jnp.arange(L, dtype=u)
-        f1m = fp1buf.reshape(B, L)
-        f2m = fp2buf.reshape(B, L)
-
-        def body(_i, acc):
-            h1 = h0 ^ (acc & u(1))
-            in_path = (
-                ((f1m == h1[:, None]) & (f2m == g0[:, None])
-                 & (il[None, :] < ptr[:, None])).sum(axis=1, dtype=u)
-                > u(0)
-            )
-            return acc + in_path.sum(dtype=u)
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_record(fp1buf, fp2buf, seed):
-        # Path-buffer scatter of the step's B fingerprints, plus the
-        # restart row-clear multiply — the two [B*L]-touching writes of a
-        # step. Buffers thread through the carry so iterations chain.
-        ib = jnp.arange(B, dtype=u)
-        h0 = _lane(B, 23)
-        restart0 = (_lane(B, 29) & u(15)) == u(0)  # ~6% restarts/step
-
-        def body(i, carry):
-            f1, f2, acc = carry
-            pos = ib * u(L) + ((acc + i.astype(u)) % u(max(1, L)))
-            h1 = h0 ^ (acc & u(1))
-            f1 = f1.at[pos].set(h1, mode="drop", unique_indices=True)
-            f2 = f2.at[pos].set(h1, mode="drop", unique_indices=True)
-            keep = ~restart0
-            f1 = (f1.reshape(B, L) * keep[:, None]).reshape(-1)
-            f2 = (f2.reshape(B, L) * keep[:, None]).reshape(-1)
-            return f1, f2, acc + f1[0]
-
-        _f1, _f2, acc = lax.fori_loop(
-            0, iters, body, (fp1buf, fp2buf, seed)
-        )
-        return acc
-
-    @jax.jit
-    def k_expand(fp1buf, fp2buf, seed):
-        # Successor generation + boundary masks + property evaluation
-        # (the loop evaluates them on the same rows in the same step).
-        rows0 = tuple(_lane(B, 31 + s) & u(7) for s in range(S))
-
-        def body(_i, acc):
-            rows = ((rows0[0] ^ (acc & u(1))) & u(7),) + rows0[1:]
-            succs, amask = tm.step_lanes(jnp, rows)
-            ne = jnp.zeros(B, dtype=u)
-            for a in range(A):
-                v = amask[a] & tm.within_boundary_lanes(jnp, succs[a])
-                ne = ne + v.astype(u)
-            for p in props:
-                ne = ne + p.check(jnp, rows).sum(dtype=u)
-            return acc + ne[0] + ne.sum(dtype=u)
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    @jax.jit
-    def k_choose(fp1buf, fp2buf, seed):
-        # Counter-PRNG pick + the A-round uniform successor select.
-        rows0 = tuple(_lane(B, 47 + s) for s in range(S))
-        succs0 = tuple(
-            tuple(_lane(B, 101 + a * S + s) for s in range(S))
-            for a in range(A)
-        )
-        valid0 = tuple((_lane(B, 211 + a) & u(1)) == u(0) for a in range(A))
-        ptr = _lane(B, 223) % u(max(1, L))
-
-        def prng(x):
-            x = (x ^ (x >> u(16))) * u(0x7FEB352D)
-            x = (x ^ (x >> u(15))) * u(0x846CA68B)
-            return x ^ (x >> u(16))
-
-        def body(_i, acc):
-            sd = _lane(B, 227) ^ acc
-            ne = jnp.zeros(B, dtype=u)
-            for a in range(A):
-                ne = ne + valid0[a].astype(u)
-            r = prng(sd ^ (ptr * u(0x9E3779B9)))
-            pick = jnp.where(ne > u(0), r % jnp.maximum(ne, u(1)), u(0))
-            cum = jnp.zeros(B, dtype=u)
-            new_rows = rows0
-            chosen_any = ne < u(0)
-            for a in range(A):
-                sel = valid0[a] & (cum == pick) & ~chosen_any
-                chosen_any = chosen_any | sel
-                new_rows = tuple(
-                    jnp.where(sel, succs0[a][s], new_rows[s])
-                    for s in range(S)
-                )
-                cum = cum + valid0[a].astype(u)
-            out = acc
-            for lane in new_rows:
-                out = out + lane[0]
-            return out
-
-        return lax.fori_loop(0, iters, body, seed)
-
-    kernels = {
-        "hash": k_hash,
-        "cycle": k_cycle,
-        "record": k_record,
-        "expand": k_expand,
-        "choose": k_choose,
-    }
-    _STAGE_KERNEL_CACHE[key] = (tm, kernels)
-    return kernels
 
 
 class TpuSimulationChecker(HostEngineBase):
@@ -778,8 +623,6 @@ class TpuSimulationChecker(HostEngineBase):
         self._metrics.set_gauge("walks", self._B)
         self._metrics.set_gauge("walk_cap", self._L)
         self._cov = self._coverage.enabled
-        self._stage_profile = bool(getattr(builder, "stage_profile_", False))
-        self._stage_iters = int(getattr(builder, "stage_profile_iters_", 32))
         self._sample_k = self._sampler.k if self._sampler is not None else 0
         self._loop, self._seed_run, self._n_init = _build_sim_loop(
             self.tm, self._tprops, self._B, self._L, self._cov,
@@ -988,38 +831,6 @@ class TpuSimulationChecker(HostEngineBase):
                 break
             if self._timed_out():
                 break
-
-        self._profile_stages(fp1buf, fp2buf)
-
-    def _profile_stages(self, fp1buf, fp2buf) -> None:
-        """Post-run per-stage attribution of device_era wall time
-        (CheckerBuilder.stage_profile(); obs/stageprof.py). A failure fails
-        the run: the caller asked for the breakdown."""
-        if not self._stage_profile:
-            return
-        import jax.numpy as jnp
-
-        from ..obs import stageprof
-
-        steps = int(self._metrics.get("steps"))
-        era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
-        if steps <= 0 or era_secs <= 0.0:
-            return
-        kernels = _build_sim_stage_kernels(
-            self.tm, self._tprops, self._B, self._L, self._stage_iters
-        )
-        seed = jnp.asarray(1, dtype=jnp.uint32)
-        with self._metrics.phase("profiler_overhead"):
-            timed = stageprof.measure_stage_kernels(
-                {
-                    name: (fn, (fp1buf, fp2buf, seed))
-                    for name, fn in kernels.items()
-                },
-                self._stage_iters,
-            )
-        stageprof.attribute_stages(
-            self._metrics, timed, era_secs, steps, self._stage_iters
-        )
 
     # -- accessors ----------------------------------------------------------
 

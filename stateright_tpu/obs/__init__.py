@@ -73,8 +73,16 @@ from .netobs import (
     flow_pairs,
     format_event,
 )
-from .spans import SpanRecorder, attach_phase_spans, new_span_id, new_trace_id
-from .stageprof import STAGE_ORDER, stage_rows
+from .spans import (
+    ENGINE_STAGES,
+    ERA_STAGES,
+    SpanRecorder,
+    attach_phase_spans,
+    new_span_id,
+    new_trace_id,
+    profiled_phase_totals,
+    reset_profiled_phase_totals,
+)
 from .trace import (
     ChromeTraceWriter,
     TraceWriter,
@@ -89,6 +97,8 @@ __all__ = [
     "DEFAULT_SAMPLE_K",
     "DEPTH_CAP",
     "DEVICE_STEP_CAP",
+    "ENGINE_STAGES",
+    "ERA_STAGES",
     "NO_ACTION",
     "SLAB_PAD",
     "ChromeTraceWriter",
@@ -109,7 +119,6 @@ __all__ = [
     "NETOBS_SERIES_LABELS",
     "NetObs",
     "SHARD_SERIES_LABELS",
-    "STAGE_ORDER",
     "SpanRecorder",
     "TraceWriter",
     "as_netobs",
@@ -128,9 +137,10 @@ __all__ = [
     "new_span_id",
     "new_trace_id",
     "plan",
+    "profiled_phase_totals",
     "recommend_engine",
     "render_prometheus",
-    "stage_rows",
+    "reset_profiled_phase_totals",
     "start_profile",
     "stop_profile",
 ]

@@ -22,22 +22,35 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from .spans import phase_annotation, record_profiled_phase
+
 
 class _PhaseTimer:
-    """Context manager accumulating wall time into one phase bucket."""
+    """Context manager accumulating wall time into one phase bucket.
 
-    __slots__ = ("_registry", "_name", "_t0")
+    While a profiler session records it is also a ``phase:<name>`` span
+    of the profile, and its seconds add to the process-wide profiled
+    totals (obs/spans.py)."""
+
+    __slots__ = ("_registry", "_name", "_t0", "_span")
 
     def __init__(self, registry: "MetricsRegistry", name: str):
         self._registry = registry
         self._name = name
 
     def __enter__(self) -> "_PhaseTimer":
+        self._span = phase_annotation(self._name)
+        if self._span is not None:
+            self._span.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._registry.add_phase(self._name, time.monotonic() - self._t0)
+        secs = time.monotonic() - self._t0
+        self._registry.add_phase(self._name, secs)
+        if self._span is not None:
+            self._span.__exit__(*exc)
+            record_profiled_phase(self._name, secs)
 
 
 def _log_bounds(start: float, factor: float, count: int) -> Tuple[float, ...]:

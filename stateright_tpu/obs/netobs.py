@@ -1,6 +1,6 @@
 """Network flight recorder: live deployment observability for real actor runs.
 
-The checker side has stageprof/flight/spans/memory; this module is the
+The checker side has flight/spans/memory; this module is the
 *deployment* side's equivalent lens — what actually happened on the wire
 when a system ran over loopback UDP under a seeded `FaultPlan`:
 

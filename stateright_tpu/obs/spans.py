@@ -34,6 +34,21 @@ Exports:
 engines' existing per-phase wall-time accounting) into one child span
 per phase, so an engine run shows up in a job's waterfall without the
 hot loops knowing anything about tracing.
+
+Profiler spans. While a `jax.profiler` session records (for example
+under `CheckerBuilder.profile(dir)`), every phase timer
+(`MetricsRegistry.phase`) also opens ``TraceAnnotation("phase:<name>")``:
+the span lands on the host plane of the profile, on the same clock as
+the device operations, and its seconds add to a process-wide total per
+phase (`profiled_phase_totals`). With no session recording a phase
+timer does nothing beyond its own ``phase_ms`` accounting.
+
+Device scopes. The compiled programs wrap each stage in
+``jax.named_scope`` (`stage_scope`): ``era/<stage>`` inside the era
+bodies (`ERA_STAGES`; `ENGINE_STAGES` says which engine carries which),
+`REHASH_SCOPE` around the visited set's growth and `SEED_SCOPE` around
+the run seeder. Scopes are HLO metadata only; a profile viewer groups
+the device operations of a `CheckerBuilder.profile(dir)` trace by them.
 """
 
 from __future__ import annotations
@@ -48,11 +63,116 @@ from collections import deque
 from typing import Any, Dict, Iterable, List, Optional
 
 __all__ = [
+    "ENGINE_STAGES",
+    "ERA_STAGES",
+    "REHASH_SCOPE",
+    "SEED_SCOPE",
     "SpanRecorder",
     "attach_phase_spans",
     "new_span_id",
     "new_trace_id",
+    "phase_annotation",
+    "profiled_phase_totals",
+    "record_profiled_phase",
+    "reset_profiled_phase_totals",
+    "stage_scope",
 ]
+
+#: Stage scopes of the era programs (``era/<stage>``), in display order.
+ERA_STAGES = (
+    "ring",
+    "expand",
+    "compact",
+    "hash",
+    "claim",
+    "probe",
+    "canon",
+    "exchange",
+    "cycle",
+    "record",
+    "choose",
+    "coverage",
+    "sample",
+    "epilogue",
+)
+
+#: The stages each device engine's era program carries: ``canon`` only
+#: under symmetry reduction, ``coverage`` and ``sample`` only while
+#: coverage and space sampling are on (both are by default).
+ENGINE_STAGES = {
+    "tpu_bfs": (
+        "ring", "expand", "compact", "hash", "claim", "probe", "canon",
+        "coverage", "sample", "epilogue",
+    ),
+    "sharded": (
+        "ring", "expand", "compact", "hash", "claim", "exchange", "probe",
+        "coverage", "sample", "epilogue",
+    ),
+    "tpu_simulation": (
+        "hash", "cycle", "record", "expand", "choose", "coverage", "sample",
+        "epilogue",
+    ),
+}
+
+#: The visited set's growth (`ops.visited_set.rehash`).
+REHASH_SCOPE = "table/rehash"
+#: The run seeder: table and ring built on the device, inits inserted.
+SEED_SCOPE = "seed"
+
+
+def stage_scope(stage: str):
+    """``jax.named_scope("era/<stage>")`` for one of `ERA_STAGES`."""
+    if stage not in ERA_STAGES:
+        raise ValueError(f"unknown era stage {stage!r}; known: {ERA_STAGES}")
+    import jax
+
+    return jax.named_scope("era/" + stage)
+
+
+_annotation_cls = None
+_profiled_lock = threading.Lock()
+_profiled: Dict[str, List[float]] = {}
+
+
+def phase_annotation(name: str):
+    """An unentered ``TraceAnnotation("phase:<name>")`` while a profiler
+    session records, else None (one `is_enabled()` call, nothing built)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        from jax.profiler import TraceAnnotation
+
+        cls = _annotation_cls = TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    return cls("phase:" + name)
+
+
+def record_profiled_phase(name: str, secs: float) -> None:
+    """Add one profiled span of phase `name` to the process-wide totals."""
+    with _profiled_lock:
+        total = _profiled.get(name)
+        if total is None:
+            _profiled[name] = [secs, 1]
+        else:
+            total[0] += secs
+            total[1] += 1
+
+
+def profiled_phase_totals() -> Dict[str, Dict[str, float]]:
+    """``{phase: {"secs": total seconds, "count": spans}}`` of every phase
+    timed while a profiler session recorded, over all checkers of this
+    process since the last `reset_profiled_phase_totals`."""
+    with _profiled_lock:
+        return {
+            name: {"secs": secs, "count": int(count)}
+            for name, (secs, count) in sorted(_profiled.items())
+        }
+
+
+def reset_profiled_phase_totals() -> None:
+    with _profiled_lock:
+        _profiled.clear()
 
 
 def new_trace_id() -> str:

@@ -450,17 +450,20 @@ def rehash(old_table, new_table):
     Runs entirely on device (table growth must not round-trip the table
     through the host). Returns (new_table, n_unresolved).
     """
-    occ = occupied_mask(old_table)
-    cap = table_capacity(old_table)
-    k1 = old_table[0][:cap]
-    k2 = old_table[0][cap:]
-    v1, v2 = old_table[1], old_table[2]
-    # A rehash inserts millions of rows at once; use a deeper primary phase
-    # so the fixed-size tail only sees genuine stragglers.
-    new_table, _is_new, unresolved, _ovf = insert(
-        new_table, k1, k2, v1, v2, occ, primary_rounds=REHASH_ROUNDS
-    )
-    return new_table, unresolved.sum(dtype=jnp.uint32)
+    from ..obs.spans import REHASH_SCOPE
+
+    with jax.named_scope(REHASH_SCOPE):
+        occ = occupied_mask(old_table)
+        cap = table_capacity(old_table)
+        k1 = old_table[0][:cap]
+        k2 = old_table[0][cap:]
+        v1, v2 = old_table[1], old_table[2]
+        # A rehash inserts millions of rows at once; use a deeper primary
+        # phase so the fixed-size tail only sees genuine stragglers.
+        new_table, _is_new, unresolved, _ovf = insert(
+            new_table, k1, k2, v1, v2, occ, primary_rounds=REHASH_ROUNDS
+        )
+        return new_table, unresolved.sum(dtype=jnp.uint32)
 
 
 # Host-callable jitted twins. Never call `insert`/`lookup_parent`/`rehash`

@@ -171,6 +171,7 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
     from ..engines.tpu_bfs import _vcap
     from ..fingerprint import hash_lanes_jnp
     from ..obs.coverage import DEPTH_CAP
+    from ..obs.spans import stage_scope
     from ..ops import frontier as fr
     from ..ops import visited_set as vs
     from ..ops.expand import build_expand_lean
@@ -325,114 +326,129 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
                     pred, jnp.minimum(jnp.minimum(count, u(chunk)), take_cap), u(0)
                 )
                 active = jnp.arange(chunk, dtype=u) < take
-                popped, _ = fr.ring_gather(queue, head, chunk)
+                with stage_scope("ring"):
+                    popped, _ = fr.ring_gather(queue, head, chunk)
                 rows = popped[:S]
                 ebits = popped[S]
                 depth = popped[S + 1]
                 # Recomputed on pop, elementwise (the ring no longer carries
                 # fingerprints — same round-5 redesign as engines/tpu_bfs.py).
-                row_h1, row_h2 = hash_lanes_jnp(rows)
+                with stage_scope("hash"):
+                    row_h1, row_h2 = hash_lanes_jnp(rows)
 
-                ex = expand_lean(rows, ebits, depth, active, depth_limit)
+                with stage_scope("expand"):
+                    ex = expand_lean(rows, ebits, depth, active, depth_limit)
 
                 # COMPACT EARLY: validity compaction is the only padded-width
                 # random-access op; hashing, dedup, bucketing, and the exchange
                 # all run at the compacted [vcap] width.
-                vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
-                cl = tuple(ex.flat[s][vids] for s in range(S))
-                ch1, ch2 = hash_lanes_jnp(cl)
-                src = vids % u(chunk)
-                cp1 = jnp.where(vvalid, row_h1[src], u(0))
-                cp2 = jnp.where(vvalid, row_h2[src], u(0))
-                cebits = ex.ebits[src]
-                cdepth = depth[src] + u(1)
+                with stage_scope("compact"):
+                    vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
+                    cl = tuple(ex.flat[s][vids] for s in range(S))
+                with stage_scope("hash"):
+                    ch1, ch2 = hash_lanes_jnp(cl)
+                with stage_scope("compact"):
+                    src = vids % u(chunk)
+                    cp1 = jnp.where(vvalid, row_h1[src], u(0))
+                    cp2 = jnp.where(vvalid, row_h2[src], u(0))
+                    cebits = ex.ebits[src]
+                    cdepth = depth[src] + u(1)
 
-                reps = fr.claim_dedup(ch1, ch2, vvalid, dedup_cap)
-                owner = ch1 % u(n_shards)
+                with stage_scope("claim"):
+                    reps = fr.claim_dedup(ch1, ch2, vvalid, dedup_cap)
 
-                # Bucket by owner with ONE rank computation (no per-destination
-                # Python loop — program size stays flat in n_shards): a
-                # [vcap, N] one-hot cumsum yields each candidate's rank within
-                # its owner bucket and the per-owner counts in one pass.
-                onehot = (
-                    owner[:, None] == jnp.arange(n_shards, dtype=u)[None, :]
-                ) & reps[:, None]
-                csum = jnp.cumsum(onehot.astype(u), axis=0)  # [vcap, N]
-                rank = (csum * onehot.astype(u)).sum(axis=1) - u(1)
-                counts_per_owner = csum[-1]  # [N]
-                n_ovf_total = (
-                    counts_per_owner
-                    - jnp.minimum(counts_per_owner, u(quota))
-                ).sum(dtype=u)
-                my = jnp.arange(vcap, dtype=u)
-                dest = jnp.where(
-                    reps & (rank < u(quota)),
-                    owner * u(quota) + rank,
-                    u(n_shards * quota) + my,  # distinct drop targets
-                )
-                send_cand = cl + (cp1, cp2, cebits, cdepth)
-                send = [
-                    jnp.zeros(n_shards * quota, dtype=u)
-                    .at[dest]
-                    .set(c, mode="drop", unique_indices=True)
-                    for c in send_cand
-                ]
+                with stage_scope("exchange"):
+                    owner = ch1 % u(n_shards)
+                    # Bucket by owner with ONE rank computation (no per-
+                    # destination Python loop — program size stays flat in
+                    # n_shards): a [vcap, N] one-hot cumsum yields each
+                    # candidate's rank within its owner bucket and the
+                    # per-owner counts in one pass.
+                    onehot = (
+                        owner[:, None] == jnp.arange(n_shards, dtype=u)[None, :]
+                    ) & reps[:, None]
+                    csum = jnp.cumsum(onehot.astype(u), axis=0)  # [vcap, N]
+                    rank = (csum * onehot.astype(u)).sum(axis=1) - u(1)
+                    counts_per_owner = csum[-1]  # [N]
+                    n_ovf_total = (
+                        counts_per_owner
+                        - jnp.minimum(counts_per_owner, u(quota))
+                    ).sum(dtype=u)
+                    my = jnp.arange(vcap, dtype=u)
+                    dest = jnp.where(
+                        reps & (rank < u(quota)),
+                        owner * u(quota) + rank,
+                        u(n_shards * quota) + my,  # distinct drop targets
+                    )
+                    send_cand = cl + (cp1, cp2, cebits, cdepth)
+                    send = [
+                        jnp.zeros(n_shards * quota, dtype=u)
+                        .at[dest]
+                        .set(c, mode="drop", unique_indices=True)
+                        for c in send_cand
+                    ]
 
-                # The ICI hop: one all_to_all per lane; each shard receives the
-                # buckets addressed to it from every shard.
-                recv = [
-                    lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
-                    for x in send
-                ]
+                    # The ICI hop: one all_to_all per lane; each shard
+                    # receives the buckets addressed to it from every shard.
+                    recv = [
+                        lax.all_to_all(
+                            x, axis, split_axis=0, concat_axis=0, tiled=True
+                        )
+                        for x in send
+                    ]
                 rstates = tuple(recv[t] for t in range(S))
                 rp1 = recv[S]
                 rp2 = recv[S + 1]
                 # Parent fingerprints are nonzero as a pair for every real
                 # candidate; an all-zero parent pair means "empty slot".
                 r_valid = (rp1 | rp2) != u(0)
-                rh1, rh2 = hash_lanes_jnp(rstates)  # owner-side recompute
+                with stage_scope("hash"):
+                    rh1, rh2 = hash_lanes_jnp(rstates)  # owner-side recompute
 
-                table, is_new, unresolved, _ovf_ins = vs.insert(
-                    table, rh1, rh2, rp1, rp2, r_valid
-                )
-                unres = unresolved.sum(dtype=u)
-                new_count = is_new.sum(dtype=u)
+                with stage_scope("probe"):
+                    table, is_new, unresolved, _ovf_ins = vs.insert(
+                        table, rh1, rh2, rp1, rp2, r_valid
+                    )
+                    unres = unresolved.sum(dtype=u)
+                    new_count = is_new.sum(dtype=u)
 
                 if sample_k:
-                    # Capture below-threshold inserts into this shard's slab.
-                    # `is_new` is exactly-once (retried partial-commit steps
-                    # re-deliver already-inserted rows, which are not new), so
-                    # no fingerprint is ever captured twice. Writes happen at
-                    # the full receive width R — never truncated; the trash
-                    # slot at index scap absorbs masked lanes.
-                    below = is_new & (
-                        (rh1 < st1) | ((rh1 == st1) & (rh2 < st2))
-                    )
-
-                    def _capture(sc):
-                        sfp1, sfp2, sdep, socc = sc
-                        cids, cvalid, n_c = vs._compact_ids(below, R)
-                        pos = socc + jnp.arange(R, dtype=u)
-                        ok_w = cvalid & (pos < u(scap))
-                        widx = jnp.where(ok_w, pos, u(scap))
-                        return (
-                            sfp1.at[widx].set(rh1[cids]),
-                            sfp2.at[widx].set(rh2[cids]),
-                            sdep.at[widx].set(recv[S + 3][cids]),
-                            socc + n_c,
+                    with stage_scope("sample"):
+                        # Capture below-threshold inserts into this shard's slab.
+                        # `is_new` is exactly-once (retried partial-commit steps
+                        # re-deliver already-inserted rows, which are not new), so
+                        # no fingerprint is ever captured twice. Writes happen at
+                        # the full receive width R — never truncated; the trash
+                        # slot at index scap absorbs masked lanes.
+                        below = is_new & (
+                            (rh1 < st1) | ((rh1 == st1) & (rh2 < st2))
                         )
 
-                    # Tight-threshold steps capture nothing almost always;
-                    # the cond skips the compaction and slab scatters then.
-                    # Per-shard predicate — shards diverge, which is fine:
-                    # nothing inside the branch communicates.
-                    sampc = lax.cond(
-                        below.any(), _capture, lambda sc: sc, sampc
-                    )
+                        def _capture(sc):
+                            sfp1, sfp2, sdep, socc = sc
+                            cids, cvalid, n_c = vs._compact_ids(below, R)
+                            pos = socc + jnp.arange(R, dtype=u)
+                            ok_w = cvalid & (pos < u(scap))
+                            widx = jnp.where(ok_w, pos, u(scap))
+                            return (
+                                sfp1.at[widx].set(rh1[cids]),
+                                sfp2.at[widx].set(rh2[cids]),
+                                sdep.at[widx].set(recv[S + 3][cids]),
+                                socc + n_c,
+                            )
 
-                qrows = rstates + (recv[S + 2], recv[S + 3])
-                tail = (head + count) & u(qmask)
-                queue = fr.ring_scatter(queue, tail, qrows, is_new)
+                        # Tight-threshold steps capture nothing almost always;
+                        # the cond skips the compaction and slab scatters then.
+                        # Per-shard predicate — shards diverge, which is fine:
+                        # nothing inside the branch communicates.
+                        sampc = lax.cond(
+                            below.any(), _capture, lambda sc: sc, sampc
+                        )
+
+                with stage_scope("ring"):
+                    qrows = rstates + (recv[S + 2], recv[S + 3])
+                    tail = (head + count) & u(qmask)
+                    queue = fr.ring_scatter(queue, tail, qrows, is_new)
 
                 # Partial-commit overflow protocol (see module docstring).
                 # Probe-tail overflow (unresolved candidates at the OWNER) is
@@ -464,20 +480,21 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
                 )
 
                 if cov:
-                    # Shard-local coverage (obs/coverage.py): action counts at
-                    # the SENDER (where expansion attributes candidates to
-                    # their action slot; ovf-gated like `gen`), the consumed
-                    # row count, and the depth histogram at the OWNER (where
-                    # inserts happen; unconditional like `unique`). Shards
-                    # psum these once in the block epilogue.
-                    act, covp, expanded, dhist = covc
-                    pa = ex.valid.astype(u).reshape(A, chunk).sum(axis=1)
-                    act = act + jnp.where(ovf, u(0), pa)
-                    expanded = expanded + consumed
-                    dhist = dhist.at[
-                        jnp.minimum(recv[S + 3], u(DEPTH_CAP - 1))
-                    ].add(is_new.astype(u))
-                    covc = (act, covp, expanded, dhist)
+                    with stage_scope("coverage"):
+                        # Shard-local coverage (obs/coverage.py): action counts at
+                        # the SENDER (where expansion attributes candidates to
+                        # their action slot; ovf-gated like `gen`), the consumed
+                        # row count, and the depth histogram at the OWNER (where
+                        # inserts happen; unconditional like `unique`). Shards
+                        # psum these once in the block epilogue.
+                        act, covp, expanded, dhist = covc
+                        pa = ex.valid.astype(u).reshape(A, chunk).sum(axis=1)
+                        act = act + jnp.where(ovf, u(0), pa)
+                        expanded = expanded + consumed
+                        dhist = dhist.at[
+                            jnp.minimum(recv[S + 3], u(DEPTH_CAP - 1))
+                        ].add(is_new.astype(u))
+                        covc = (act, covp, expanded, dhist)
 
                 if NP_:
                     hseen_n, facc1_n, facc2_n, faccd_n, covp_n = [], [], [], [], []
@@ -557,82 +574,83 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
             # LATER block than a deeper one (the reference's multithreaded
             # BFS has the same benign race, bfs.rs:243-244; tracking min
             # depth host-side makes us strictly better, not just equal).
-            if NP_:
-                ef1, ef2, edd = [], [], []
+            with stage_scope("epilogue"):
+                if NP_:
+                    ef1, ef2, edd = [], [], []
+                    for pi in range(NP_):
+                        found = jnp.any(hseen[pi])
+                        sel = jnp.argmin(
+                            jnp.where(hseen[pi], faccd[pi], u(0xFFFFFFFF))
+                        )
+                        ef1.append(jnp.where(found, facc1[pi][sel], u(0)))
+                        ef2.append(jnp.where(found, facc2[pi][sel], u(0)))
+                        edd.append(
+                            jnp.where(found, faccd[pi][sel], u(0xFFFFFFFF))
+                        )
+                    era_fp1 = jnp.stack(ef1)
+                    era_fp2 = jnp.stack(ef2)
+                    era_dd = jnp.stack(edd)
+                else:
+                    era_fp1 = jnp.zeros(0, dtype=u) + vzero
+                    era_fp2 = jnp.zeros(0, dtype=u) + vzero
+                    era_dd = jnp.zeros(0, dtype=u) + vzero
+                maxd = jnp.where(
+                    steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
+                )
+                # Adaptive era budget (device-side emission, mirroring
+                # engines/tpu_bfs.py): every input to the formula is globally
+                # uniform (one epilogue psum for pressure/err/work/global rec
+                # bits; `its_out` runs lockstep), so every shard emits the SAME
+                # next budget and a chained dispatch stays uniform too. The
+                # cost is one collective per ERA, not per step — and the same
+                # psum carries the fusion gate (`budget_only`, slab occupancy),
+                # so chaining eras on device adds no extra collectives.
+                glocal = [
+                    ((count > high_water) | (unique > grow_limit)).astype(u),
+                    (err_cnt > u(0)).astype(u),
+                    (count > u(0)).astype(u),
+                ] + [
+                    jnp.minimum(hseen[pi].sum(dtype=u), u(1))
+                    for pi in range(NP_)
+                ]
+                if sample_k and fuse > 1:
+                    socc_out = sampc_out[3]
+                    glocal.append((socc_out > u(s_high)).astype(u))
+                gb = lax.psum(jnp.stack(glocal), axis)
+                g_pressure = gb[0] > u(0)
+                g_err = gb[1] > u(0)
+                g_work = gb[2] > u(0)
+                rec_all = rec_bits
                 for pi in range(NP_):
-                    found = jnp.any(hseen[pi])
-                    sel = jnp.argmin(
-                        jnp.where(hseen[pi], faccd[pi], u(0xFFFFFFFF))
-                    )
-                    ef1.append(jnp.where(found, facc1[pi][sel], u(0)))
-                    ef2.append(jnp.where(found, facc2[pi][sel], u(0)))
-                    edd.append(
-                        jnp.where(found, faccd[pi][sel], u(0xFFFFFFFF))
-                    )
-                era_fp1 = jnp.stack(ef1)
-                era_fp2 = jnp.stack(ef2)
-                era_dd = jnp.stack(edd)
-            else:
-                era_fp1 = jnp.zeros(0, dtype=u) + vzero
-                era_fp2 = jnp.zeros(0, dtype=u) + vzero
-                era_dd = jnp.zeros(0, dtype=u) + vzero
-            maxd = jnp.where(
-                steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
-            )
-            # Adaptive era budget (device-side emission, mirroring
-            # engines/tpu_bfs.py): every input to the formula is globally
-            # uniform (one epilogue psum for pressure/err/work/global rec
-            # bits; `its_out` runs lockstep), so every shard emits the SAME
-            # next budget and a chained dispatch stays uniform too. The
-            # cost is one collective per ERA, not per step — and the same
-            # psum carries the fusion gate (`budget_only`, slab occupancy),
-            # so chaining eras on device adds no extra collectives.
-            glocal = [
-                ((count > high_water) | (unique > grow_limit)).astype(u),
-                (err_cnt > u(0)).astype(u),
-                (count > u(0)).astype(u),
-            ] + [
-                jnp.minimum(hseen[pi].sum(dtype=u), u(1))
-                for pi in range(NP_)
-            ]
-            if sample_k and fuse > 1:
-                socc_out = sampc_out[3]
-                glocal.append((socc_out > u(s_high)).astype(u))
-            gb = lax.psum(jnp.stack(glocal), axis)
-            g_pressure = gb[0] > u(0)
-            g_err = gb[1] > u(0)
-            g_work = gb[2] > u(0)
-            rec_all = rec_bits
-            for pi in range(NP_):
-                rec_all = rec_all | (jnp.minimum(gb[3 + pi], u(1)) << u(pi))
-            fin_hit_final = ((rec_all & fin_any) != u(0)) | (
-                (fin_all_en != u(0)) & ((rec_all & fin_all) == fin_all)
-            )
-            budget_only = (
-                (its_out >= max_steps)
-                & g_work
-                & ~g_pressure
-                & ~g_err
-                & ~fin_hit_final
-            )
-            g_slab_full = (
-                gb[3 + NP_] > u(0) if (sample_k and fuse > 1) else None
-            )
-            grown = jnp.minimum(
-                jnp.maximum(max_steps, u(1)) * u(2), budget_cap
-            )
-            shrunk = jnp.maximum(
-                jnp.minimum(max_steps, budget_cap) >> u(1),
-                u(64),  # BUDGET_MIN (engines/tpu_bfs.py)
-            )
-            next_budget = jnp.where(
-                budget_cap == u(0),
-                max_steps,
-                jnp.where(
-                    g_pressure, shrunk,
-                    jnp.where(budget_only, grown, max_steps),
-                ),
-            )
+                    rec_all = rec_all | (jnp.minimum(gb[3 + pi], u(1)) << u(pi))
+                fin_hit_final = ((rec_all & fin_any) != u(0)) | (
+                    (fin_all_en != u(0)) & ((rec_all & fin_all) == fin_all)
+                )
+                budget_only = (
+                    (its_out >= max_steps)
+                    & g_work
+                    & ~g_pressure
+                    & ~g_err
+                    & ~fin_hit_final
+                )
+                g_slab_full = (
+                    gb[3 + NP_] > u(0) if (sample_k and fuse > 1) else None
+                )
+                grown = jnp.minimum(
+                    jnp.maximum(max_steps, u(1)) * u(2), budget_cap
+                )
+                shrunk = jnp.maximum(
+                    jnp.minimum(max_steps, budget_cap) >> u(1),
+                    u(64),  # BUDGET_MIN (engines/tpu_bfs.py)
+                )
+                next_budget = jnp.where(
+                    budget_cap == u(0),
+                    max_steps,
+                    jnp.where(
+                        g_pressure, shrunk,
+                        jnp.where(budget_only, grown, max_steps),
+                    ),
+                )
             return (
                 table, queue, head, count, unique, rec_all, err_cnt,
                 take_cap_out, covc_out, sampc_out, era_fp1, era_fp2,
@@ -760,55 +778,56 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
         # shard-local bits would make the finish gate non-uniform across
         # shards and deadlock the lockstep collectives. Per-shard
         # discovery attribution rides disc_depth, not this word.
-        parts = [
-            jnp.stack(
-                [
-                    head, count, unique, rec_all, depth_limit,
-                    grow_limit, high_water, next_budget, gen, maxd, steps,
-                    (err_cnt > 0).astype(u), take_cap_out,
-                    fin_any, fin_all, fin_all_en, budget_cap,
-                ]
-            )
-        ]
-        if cov:
-            # Coverage tail, psum'd across the mesh so every shard's row
-            # carries the GLOBAL histograms (the host reads row 0):
-            # act[A] | prop_hits[NP_] | expanded[1] | depth[DEPTH_CAP].
-            act, covp, expanded, dhist = covc_out
-            covp_vec = (
-                jnp.stack(list(covp)) if NP_ else jnp.zeros(0, dtype=u) + vzero
-            )
-            parts.append(
-                lax.psum(
-                    jnp.concatenate([act, covp_vec, expanded[None], dhist]),
-                    axis,
+        with stage_scope("epilogue"):
+            parts = [
+                jnp.stack(
+                    [
+                        head, count, unique, rec_all, depth_limit,
+                        grow_limit, high_water, next_budget, gen, maxd, steps,
+                        (err_cnt > 0).astype(u), take_cap_out,
+                        fin_any, fin_all, fin_all_en, budget_cap,
+                    ]
                 )
-            )
-        if sample_k:
-            # Per-shard sample tail, deliberately UN-psum'd (fingerprints
-            # don't reduce): [T1, T2, occupied, 0] + the sk2 smallest slab
-            # entries by h1 (fp1 | fp2 | depth | ok). One top_k in the
-            # once-per-era epilogue; the ok lane disambiguates padding
-            # from a real fp1 of 0xFFFFFFFF; the host applies the exact
-            # 64-bit tie cut (obs/sample.py) and unions the shards.
-            sfp1, sfp2, sdep, socc = sampc_out
-            used = jnp.arange(scap, dtype=u) < socc
-            skey = jnp.where(used, ~sfp1[:scap], u(0))
-            _topv, topi = lax.top_k(skey, sk2)
-            parts += [
-                jnp.stack([st1, st2, socc, vzero]),
-                sfp1[:scap][topi],
-                sfp2[:scap][topi],
-                sdep[:scap][topi],
-                used[topi].astype(u),
             ]
-        # Fusion tail (fuse > 1 only): [fuse_lim (pass-through), n_inner]
-        # + per-inner-era steps | generated | unique-delta | frontier
-        # lanes + per-property best-discovery era index. One readback
-        # then reconstructs n_inner exact flight records and the serial
-        # discovery tie-break.
-        parts += ftail
-        params_out = jnp.concatenate(parts)
+            if cov:
+                # Coverage tail, psum'd across the mesh so every shard's row
+                # carries the GLOBAL histograms (the host reads row 0):
+                # act[A] | prop_hits[NP_] | expanded[1] | depth[DEPTH_CAP].
+                act, covp, expanded, dhist = covc_out
+                covp_vec = (
+                    jnp.stack(list(covp)) if NP_ else jnp.zeros(0, dtype=u) + vzero
+                )
+                parts.append(
+                    lax.psum(
+                        jnp.concatenate([act, covp_vec, expanded[None], dhist]),
+                        axis,
+                    )
+                )
+            if sample_k:
+                # Per-shard sample tail, deliberately UN-psum'd (fingerprints
+                # don't reduce): [T1, T2, occupied, 0] + the sk2 smallest slab
+                # entries by h1 (fp1 | fp2 | depth | ok). One top_k in the
+                # once-per-era epilogue; the ok lane disambiguates padding
+                # from a real fp1 of 0xFFFFFFFF; the host applies the exact
+                # 64-bit tie cut (obs/sample.py) and unions the shards.
+                sfp1, sfp2, sdep, socc = sampc_out
+                used = jnp.arange(scap, dtype=u) < socc
+                skey = jnp.where(used, ~sfp1[:scap], u(0))
+                _topv, topi = lax.top_k(skey, sk2)
+                parts += [
+                    jnp.stack([st1, st2, socc, vzero]),
+                    sfp1[:scap][topi],
+                    sfp2[:scap][topi],
+                    sdep[:scap][topi],
+                    used[topi].astype(u),
+                ]
+            # Fusion tail (fuse > 1 only): [fuse_lim (pass-through), n_inner]
+            # + per-inner-era steps | generated | unique-delta | frontier
+            # lanes + per-property best-discovery era index. One readback
+            # then reconstructs n_inner exact flight records and the serial
+            # discovery tie-break.
+            parts += ftail
+            params_out = jnp.concatenate(parts)
 
         def exp(x):
             return jnp.expand_dims(x, 0)
@@ -848,240 +867,6 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
     program = BlockProgram(serial, chain)
     _LOOP_CACHE[key] = (tm, program)
     return program
-
-
-# Stage-profiler kernels (obs/stageprof.py): one shard_map'd jitted
-# microbench per era-loop stage, signature (table, queue, seed[N]) ->
-# psummed uint32 per shard. All shards run each stage in lockstep (the
-# final psum couples them), so the dispatch wall time measured by the host
-# IS the global per-stage time — the mesh twin of the single-device
-# engine's `_build_stage_kernels` (engines/tpu_bfs.py), plus `exchange`
-# for the owner-bucketing + all_to_all hop this engine alone has.
-_STAGE_KERNEL_CACHE: Dict[Tuple, Tuple[TensorModel, Dict[str, Any]]] = {}
-
-
-def _build_mesh_stage_kernels(tm: TensorModel, props, chunk: int, qcap: int,
-                              n_shards: int, quota: int, mesh, axis: str,
-                              iters: int) -> Dict[str, Any]:
-    key = (
-        id(tm), chunk, qcap, n_shards, quota, len(props), iters,
-        tuple(id(d) for d in mesh.devices.flat),
-    )
-    cached = _STAGE_KERNEL_CACHE.get(key)
-    if cached is not None and cached[0] is tm:
-        return cached[1]
-    while len(_STAGE_KERNEL_CACHE) >= 8:
-        _STAGE_KERNEL_CACHE.pop(next(iter(_STAGE_KERNEL_CACHE)))
-
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import PartitionSpec
-
-    from ..engines.tpu_bfs import _vcap
-    from ..fingerprint import hash_lanes_jnp
-    from ..ops import frontier as fr
-    from ..ops import visited_set as vs
-    from ..ops.expand import build_expand_lean
-
-    S = tm.state_width
-    A = tm.max_actions
-    W = S + 2
-    X = S + 4
-    u = jnp.uint32
-    expand_lean = build_expand_lean(tm, props, chunk)
-    qmask = qcap - 1
-    vcap = _vcap(A, chunk)
-    dedup_cap = 1 << max(1, (2 * vcap - 1).bit_length())
-    rwidth = n_shards * quota  # exchange receive / insert / append width
-
-    def _mix(x):
-        x = x ^ (x >> 16)
-        x = x * u(0x7FEB352D)
-        x = x ^ (x >> 15)
-        x = x * u(0x846CA68B)
-        return x ^ (x >> 16)
-
-    def _lane(n, salt):
-        return _mix(jnp.arange(n, dtype=u) * u(0x9E3779B1) + u(salt))
-
-    def _wrap(stage_body):
-        def per_device(table, queue, seed):
-            table = tuple(t[0] for t in table)
-            queue = tuple(q[0] for q in queue)
-            acc = stage_body(table, queue, seed[0])
-            # One final psum couples the shards, so the host-observed
-            # dispatch time is gated by the slowest shard (lockstep, like
-            # the real era loop's per-step global gates).
-            return jnp.expand_dims(lax.psum(acc, axis), 0)
-
-        spec = PartitionSpec(axis)
-        return jax.jit(
-            jax.shard_map(
-                per_device, mesh=mesh, in_specs=(spec,) * 3,
-                out_specs=spec,
-            )
-        )
-
-    def b_expand(table, queue, s0):
-        rows0 = tuple(queue[s][:chunk] for s in range(S))
-        ebits0 = queue[S][:chunk]
-        depth0 = queue[S + 1][:chunk]
-        active = jnp.ones(chunk, dtype=bool)
-
-        def body(_i, acc):
-            rows = (rows0[0] ^ (acc & u(1)),) + rows0[1:]
-            ex = expand_lean(rows, ebits0, depth0, active, u(0xFFFFFFFF))
-            return acc + ex.generated
-
-        return lax.fori_loop(0, iters, body, s0)
-
-    def b_hash(table, queue, s0):
-        rows0 = tuple(queue[s][:chunk] for s in range(S))
-        cl0 = tuple(_lane(vcap, 11 + s) for s in range(S))
-
-        def body(_i, acc):
-            r = (rows0[0] ^ (acc & u(1)),) + rows0[1:]
-            h1, h2 = hash_lanes_jnp(r)
-            c = (cl0[0] ^ (acc & u(1)),) + cl0[1:]
-            g1, g2 = hash_lanes_jnp(c)
-            return acc + h1[0] + h2[0] + g1[0] + g2[0]
-
-        return lax.fori_loop(0, iters, body, s0)
-
-    def b_compact(table, queue, s0):
-        # The single validity compaction [C*A] -> vcap plus the dependent
-        # gathers to the compacted width (state lanes from the padded
-        # batch, parent/ebits/depth lanes from the popped rows).
-        flat0 = tuple(_lane(chunk * A, 41 + s) for s in range(S))
-        r1 = _lane(chunk * A, 53)
-        rowls = tuple(queue[t][:chunk] for t in range(min(4, W)))
-
-        def body(_i, acc):
-            m1 = ((r1 ^ acc) & u(3)) == u(0)
-            vids, _vv, n1 = vs._compact_ids(m1, vcap)
-            src = vids % u(chunk)
-            acc = acc + n1
-            for lane in flat0:
-                acc = acc + lane[vids].sum(dtype=u)
-            for lane in rowls:
-                acc = acc + lane[src].sum(dtype=u)
-            return acc
-
-        return lax.fori_loop(0, iters, body, s0)
-
-    def b_claim(table, queue, s0):
-        p1 = _lane(vcap, 31)
-        p2 = _lane(vcap, 37)
-        valid = jnp.ones(vcap, dtype=bool)
-
-        def body(_i, acc):
-            h1 = p1 ^ (acc & u(1))
-            reps = fr.claim_dedup(h1, p2, valid, dedup_cap)
-            return acc + reps.sum(dtype=u)
-
-        return lax.fori_loop(0, iters, body, s0)
-
-    def b_exchange(table, queue, s0):
-        # Owner bucketing (the [vcap, N] one-hot cumsum rank), the send
-        # scatters, and the all_to_all ICI hop for all X lanes.
-        ch0 = _lane(vcap, 61)
-        iota_v = jnp.arange(vcap, dtype=u)
-        lanes0 = tuple(_lane(vcap, 67 + x) for x in range(X))
-
-        def body(_i, acc):
-            ch1 = ch0 ^ (acc & u(1))
-            reps = ((ch1 >> u(4)) & u(3)) != u(3)  # ~75% survive dedup
-            owner = ch1 % u(n_shards)
-            onehot = (
-                owner[:, None] == jnp.arange(n_shards, dtype=u)[None, :]
-            ) & reps[:, None]
-            csum = jnp.cumsum(onehot.astype(u), axis=0)
-            rank = (csum * onehot.astype(u)).sum(axis=1) - u(1)
-            dest = jnp.where(
-                reps & (rank < u(quota)),
-                owner * u(quota) + rank,
-                u(rwidth) + iota_v,
-            )
-            send = [
-                jnp.zeros(rwidth, dtype=u)
-                .at[dest]
-                .set(c ^ acc, mode="drop", unique_indices=True)
-                for c in lanes0
-            ]
-            recv = [
-                lax.all_to_all(
-                    x, axis, split_axis=0, concat_axis=0, tiled=True
-                )
-                for x in send
-            ]
-            for rl in recv:
-                acc = acc + rl.sum(dtype=u)
-            return acc
-
-        return lax.fori_loop(0, iters, body, s0)
-
-    def b_probe(table, queue, s0):
-        # Owner-side insert at the receive width, against the run's real
-        # table shard (copy-on-write fork in the carry; two alternating
-        # key pools bound the fork's extra load at 2*rwidth keys).
-        me = lax.axis_index(axis).astype(u)
-        pool1 = _mix(
-            jnp.arange(rwidth, dtype=u) * u(0x9E3779B1)
-            + me * u(0x85EBCA77) + u(21)
-        )
-        pool2 = _mix(pool1 ^ u(0x6C62272E))
-        # The insert's probe carry turns per-shard once it meets the table
-        # shard, so its all-true mask must start device-varying too.
-        ones = lax.pcast(jnp.ones(rwidth, dtype=bool), (axis,), to="varying")
-
-        def body(_i, carry):
-            tbl, acc = carry
-            flip = acc & u(1)
-            dh1 = pool1 ^ flip
-            dh2 = pool2 ^ flip
-            tbl, c_new, _un, _ov = vs.insert(tbl, dh1, dh2, dh1, dh2, ones)
-            return tbl, acc + c_new.sum(dtype=u)
-
-        tbl, acc = lax.fori_loop(0, iters, body, (table, s0))
-        return acc + (tbl[0][0] & u(1))
-
-    def b_ring(table, queue, s0):
-        base = jnp.arange(rwidth, dtype=u)
-
-        def body(_i, carry):
-            q, head, acc = carry
-            popped, _idx = fr.ring_gather(q, head, chunk)
-            cand = tuple(
-                _mix(
-                    base * u(2654435761)
-                    + popped[w].sum(dtype=u) + u(w * 17)
-                )
-                for w in range(W)
-            )
-            valid = jnp.ones(rwidth, dtype=bool)
-            q = fr.ring_scatter(
-                q, (head + u(chunk)) & u(qmask), cand, valid
-            )
-            return q, (head + u(chunk)) & u(qmask), acc + cand[0][0]
-
-        _q, _h, acc = lax.fori_loop(0, iters, body, (queue, s0, s0))
-        return acc
-
-    kernels = {
-        name: _wrap(body_fn)
-        for name, body_fn in (
-            ("expand", b_expand),
-            ("hash", b_hash),
-            ("compact", b_compact),
-            ("claim", b_claim),
-            ("exchange", b_exchange),
-            ("probe", b_probe),
-            ("ring", b_ring),
-        )
-    }
-    _STAGE_KERNEL_CACHE[key] = (tm, kernels)
-    return kernels
 
 
 _GROW_CACHE: Dict[Tuple, Any] = {}
@@ -1167,129 +952,137 @@ class ShardedBfsChecker(HostEngineBase):
         import jax
         from jax.sharding import Mesh
 
-        model = builder.model
-        if isinstance(model, TensorModel):
-            model = TensorModelAdapter(model)
-        if not isinstance(model, TensorModelAdapter):
-            raise TypeError(
-                "spawn_sharded_bfs requires a TensorModel (or its adapter)"
+        from ..obs.metrics import MetricsRegistry
+
+        # The constructor's own time, in the caller's thread (the base
+        # class adopts the registry).
+        metrics = MetricsRegistry()
+        with metrics.phase("engine_init"):
+            model = builder.model
+            if isinstance(model, TensorModel):
+                model = TensorModelAdapter(model)
+            if not isinstance(model, TensorModelAdapter):
+                raise TypeError(
+                    "spawn_sharded_bfs requires a TensorModel (or its adapter)"
+                )
+            super().__init__(builder, model=model, metrics=metrics)
+            if self._visitor is not None:
+                raise ValueError("the sharded engine does not support visitors")
+
+            self.tm: TensorModel = model.tm
+            self._tprops = self.tm.tensor_properties()
+            if len(self._tprops) > 32:
+                raise ValueError("at most 32 tensor properties supported")
+            devices = devices if devices is not None else jax.devices()
+            self.n_shards = len(devices)
+            self.mesh = Mesh(np.array(devices), ("shards",))
+            if queue_capacity_per_shard & (queue_capacity_per_shard - 1):
+                raise ValueError("queue capacity must be a power of two")
+            A = max(1, self.tm.max_actions)
+            self._chunk = min(chunk_size, queue_capacity_per_shard // (2 * A))
+            if self._chunk == 0:
+                raise ValueError("queue capacity too small for this model's fanout")
+            self._qcap = queue_capacity_per_shard
+            self._tcap = table_capacity_per_shard
+            self._max_sync_steps = sync_steps
+            # Per-destination exchange quota: the receive width is
+            # n_shards * quota, so this also caps per-step inserts per shard.
+            self._quota = max(64, (self._chunk * A) // (4 * self.n_shards))
+            if self._qcap < 4 * self.n_shards * self._quota:
+                raise ValueError(
+                    "queue_capacity_per_shard must be at least 4 * n_shards * "
+                    f"quota (= {4 * self.n_shards * self._quota}); got "
+                    f"{self._qcap}. Raise the queue capacity or lower chunk_size."
+                )
+            self._cov = self._coverage.enabled
+            self._sample_k = self._sampler.k if self._sampler is not None else 0
+            # Speculative era pipelining (CheckerBuilder.pipeline(), default
+            # on) — see _run_loop and engines/tpu_bfs.py for the soundness
+            # argument.
+            self._pipeline = bool(getattr(builder, "pipeline_", True))
+            # K-deep speculative chain (CheckerBuilder.pipeline(depth=K)) and
+            # on-device multi-era fusion (fuse=N): both amortize host
+            # bookkeeping over dispatches; defaults keep a depth of 2 (auto)
+            # and one era per dispatch.
+            depth = getattr(builder, "pipeline_depth_", None)
+            self._chain_depth = max(1, int(depth)) if depth is not None else 2
+            self._chain_max = 0
+            self._fuse = max(1, int(getattr(builder, "fuse_eras_", None) or 1))
+            self._block = _build_block(
+                self.tm, self._tprops, self._chunk, self._qcap, self.n_shards,
+                self._quota, self.mesh, "shards", self._cov,
+                sample_k=self._sample_k, fuse=self._fuse,
             )
-        super().__init__(builder, model=model)
-        if self._visitor is not None:
-            raise ValueError("the sharded engine does not support visitors")
 
-        self.tm: TensorModel = model.tm
-        self._tprops = self.tm.tensor_properties()
-        if len(self._tprops) > 32:
-            raise ValueError("at most 32 tensor properties supported")
-        devices = devices if devices is not None else jax.devices()
-        self.n_shards = len(devices)
-        self.mesh = Mesh(np.array(devices), ("shards",))
-        if queue_capacity_per_shard & (queue_capacity_per_shard - 1):
-            raise ValueError("queue capacity must be a power of two")
-        A = max(1, self.tm.max_actions)
-        self._chunk = min(chunk_size, queue_capacity_per_shard // (2 * A))
-        if self._chunk == 0:
-            raise ValueError("queue capacity too small for this model's fanout")
-        self._qcap = queue_capacity_per_shard
-        self._tcap = table_capacity_per_shard
-        self._max_sync_steps = sync_steps
-        # Per-destination exchange quota: the receive width is
-        # n_shards * quota, so this also caps per-step inserts per shard.
-        self._quota = max(64, (self._chunk * A) // (4 * self.n_shards))
-        if self._qcap < 4 * self.n_shards * self._quota:
-            raise ValueError(
-                "queue_capacity_per_shard must be at least 4 * n_shards * "
-                f"quota (= {4 * self.n_shards * self._quota}); got "
-                f"{self._qcap}. Raise the queue capacity or lower chunk_size."
+            self._unique = 0
+            self._discovery_fps: Dict[str, int] = {}
+            # Tiered spill staging (ops/tiering.py): one budgeted host-RAM
+            # LIFO per shard with an npz disk tier below; the host budget is
+            # split evenly across shards. Unbudgeted (env unset) each store
+            # is a plain in-RAM stack, byte-for-byte the old list behavior.
+            from ..ops.tiering import TieredSpillStore, spill_host_budget_bytes
+
+            _budget = spill_host_budget_bytes()
+            if _budget is not None:
+                _budget = max(1, _budget // self.n_shards)
+            self._spill: List[TieredSpillStore] = [
+                TieredSpillStore(
+                    host_budget_bytes=_budget,
+                    on_tier=self._on_spill_tier,
+                    label=f"spill-s{s}",
+                )
+                for s in range(self.n_shards)
+            ]
+            # Delta-checkpoint chain state (engines/common.py
+            # save_checkpoint_tiered): None = next save is a full base.
+            self._ckpt_delta = None
+            # Era of the last proactive reshard (one doubling per forecast).
+            self._reshard_last_era = -1
+            # Sharded checkpoint/resume: per-shard tables, rings, spill lists,
+            # take_caps and counters serialize to one .npz at block boundaries
+            # (all arrays are host-visible there). Writes are crash-atomic with
+            # rolling generations and a content digest (engines/common.py);
+            # checkpoint_every is wall-clock seconds, polled at era boundaries.
+            from ..engines.common import (
+                register_signal_checkpoint_flush,
+                validate_checkpoint_cadence,
             )
-        self._cov = self._coverage.enabled
-        self._sample_k = self._sampler.k if self._sampler is not None else 0
-        self._stage_profile = bool(getattr(builder, "stage_profile_", False))
-        self._stage_iters = int(getattr(builder, "stage_profile_iters_", 32))
-        # Speculative era pipelining (CheckerBuilder.pipeline(), default
-        # on) — see _run_loop and engines/tpu_bfs.py for the soundness
-        # argument.
-        self._pipeline = bool(getattr(builder, "pipeline_", True))
-        # K-deep speculative chain (CheckerBuilder.pipeline(depth=K)) and
-        # on-device multi-era fusion (fuse=N): both amortize host
-        # bookkeeping over dispatches; defaults keep the PR-14 behaviour
-        # (depth auto=2) and one era per dispatch.
-        depth = getattr(builder, "pipeline_depth_", None)
-        self._chain_depth = max(1, int(depth)) if depth is not None else 2
-        self._chain_max = 0
-        self._fuse = max(1, int(getattr(builder, "fuse_eras_", None) or 1))
-        self._block = _build_block(
-            self.tm, self._tprops, self._chunk, self._qcap, self.n_shards,
-            self._quota, self.mesh, "shards", self._cov,
-            sample_k=self._sample_k, fuse=self._fuse,
-        )
 
-        self._unique = 0
-        self._discovery_fps: Dict[str, int] = {}
-        # Tiered spill staging (ops/tiering.py): one budgeted host-RAM
-        # LIFO per shard with an npz disk tier below; the host budget is
-        # split evenly across shards. Unbudgeted (env unset) each store
-        # is a plain in-RAM stack, byte-for-byte the old list behavior.
-        from ..ops.tiering import TieredSpillStore, spill_host_budget_bytes
-
-        _budget = spill_host_budget_bytes()
-        if _budget is not None:
-            _budget = max(1, _budget // self.n_shards)
-        self._spill: List[TieredSpillStore] = [
-            TieredSpillStore(
-                host_budget_bytes=_budget,
-                on_tier=self._on_spill_tier,
-                label=f"spill-s{s}",
+            validate_checkpoint_cadence(
+                checkpoint_every, checkpoint_path, keep_checkpoints
             )
-            for s in range(self.n_shards)
-        ]
-        # Delta-checkpoint chain state (engines/common.py
-        # save_checkpoint_tiered): None = next save is a full base.
-        self._ckpt_delta = None
-        # Era of the last proactive reshard (one doubling per forecast).
-        self._reshard_last_era = -1
-        # Sharded checkpoint/resume: per-shard tables, rings, spill lists,
-        # take_caps and counters serialize to one .npz at block boundaries
-        # (all arrays are host-visible there). Writes are crash-atomic with
-        # rolling generations and a content digest (engines/common.py);
-        # checkpoint_every is wall-clock seconds, polled at era boundaries.
-        from ..engines.common import (
-            register_signal_checkpoint_flush,
-            validate_checkpoint_cadence,
-        )
+            self._ckpt_path = checkpoint_path
+            self._ckpt_every = checkpoint_every
+            self._ckpt_keep = keep_checkpoints
+            self._resume_from = resume_from
+            import time as _time
 
-        validate_checkpoint_cadence(
-            checkpoint_every, checkpoint_path, keep_checkpoints
-        )
-        self._ckpt_path = checkpoint_path
-        self._ckpt_every = checkpoint_every
-        self._ckpt_keep = keep_checkpoints
-        self._resume_from = resume_from
-        import time as _time
-
-        self._last_ckpt = _time.monotonic()
-        # Chaos-injection hook (tests/test_durability_chaos.py): fake a
-        # probe-budget exhaustion at this era count to exercise the
-        # degraded-regrow recovery.
-        self._chaos_probe_error_era: Optional[int] = None
-        if checkpoint_path is not None:
-            register_signal_checkpoint_flush(self)
-        self._init_ebits = 0
-        e = 0
-        for p in self._tprops:
-            if p.expectation == Expectation.EVENTUALLY:
-                self._init_ebits |= 1 << e
-                e += 1
+            self._last_ckpt = _time.monotonic()
+            # Chaos-injection hook (tests/test_durability_chaos.py): fake a
+            # probe-budget exhaustion at this era count to exercise the
+            # degraded-regrow recovery.
+            self._chaos_probe_error_era: Optional[int] = None
+            if checkpoint_path is not None:
+                register_signal_checkpoint_flush(self)
+            self._init_ebits = 0
+            e = 0
+            for p in self._tprops:
+                if p.expectation == Expectation.EVENTUALLY:
+                    self._init_ebits |= 1 << e
+                    e += 1
         self._start()
 
     # -- engine body --------------------------------------------------------
 
     def _run(self) -> None:
-        import jax.numpy as jnp
+        with self._metrics.phase("run_setup"):
+            start = self._setup_run()
+        if start is not None:
+            self._run_loop(*start)
 
-        from ..ops import visited_set as vs
-
+    def _setup_run(self):
+        """Host seeding (or a checkpoint load) up to the first dispatch:
+        the `_run_loop` arguments, or None when there is nothing to run."""
         tm = self.tm
         S = tm.state_width
         A = tm.max_actions
@@ -1316,7 +1109,7 @@ class ShardedBfsChecker(HostEngineBase):
                 if self._target_max_depth is not None
                 else 0xFFFFFFFF
             )
-            return self._run_loop(
+            return (
                 table, queue, heads, counts, rec_bits, rec_fp1, rec_fp2,
                 take_caps, disc_depth_best, per_shard_unique, depth_limit,
                 self._qcap - N * self._quota, W,
@@ -1328,7 +1121,7 @@ class ShardedBfsChecker(HostEngineBase):
         inits = inits[inb]
         self._state_count = len(inits)
         if len(inits) == 0:
-            return
+            return None
         h1, h2 = hash_words_np(inits)
 
         # Route init states to their owners; seed tables host-side with the
@@ -1396,7 +1189,7 @@ class ShardedBfsChecker(HostEngineBase):
         take_caps = [self._chunk] * N
         disc_depth_best: Dict[str, int] = {}
         per_shard_unique = self._per_shard_uniques(table_np)
-        return self._run_loop(
+        return (
             table, queue, heads, counts, rec_bits, rec_fp1, rec_fp2,
             take_caps, disc_depth_best, per_shard_unique, depth_limit,
             high_water, W,
@@ -1610,8 +1403,12 @@ class ShardedBfsChecker(HostEngineBase):
             # shows the dispatch gap already amortized.
             return self._fuse_auto_n(self._fuse)
 
-        def consume(vals, fp1_dev, fp2_dev, dd_dev, era_wall, era_budget,
-                    spec_in_flight=False):
+        def consume(*args, **kwargs):
+            with self._metrics.phase("era_host"):
+                return consume_block(*args, **kwargs)
+
+        def consume_block(vals, fp1_dev, fp2_dev, dd_dev, era_wall,
+                          era_budget, spec_in_flight=False):
             """Consume one block result: error recovery, counters,
             discoveries, spill drain, telemetry, checkpoint cadence, and
             stop conditions. Returns False when the era was discarded
@@ -2054,12 +1851,15 @@ class ShardedBfsChecker(HostEngineBase):
                 last_thresh = (t1, t2)
             if nfuse:
                 params_np[:, f_base] = _fuse_lim_now()
+            with self._metrics.phase("upload"):
+                params_in = self._put(params_np)
             _era_w0 = _time.monotonic()
-            table, queue, rec_fp1, rec_fp2, params, disc_depth = (
-                self._block.serial(
-                    table, queue, rec_fp1, rec_fp2, self._put(params_np)
+            with self._metrics.phase("era_dispatch"):
+                table, queue, rec_fp1, rec_fp2, params, disc_depth = (
+                    self._block.serial(
+                        table, queue, rec_fp1, rec_fp2, params_in
+                    )
                 )
-            )
             self._metrics.inc("dispatches")
             if self._memory is not None:
                 self._memory.attach("packed_params", params)
@@ -2105,9 +1905,12 @@ class ShardedBfsChecker(HostEngineBase):
                     except AttributeError:
                         pass  # CPU backend: the copy below is free anyway
                     t0 = _time.monotonic()
-                    table, queue, c_f1, c_f2, c_p, c_dd = self._block.chain(
-                        table, queue, src_f1, src_f2, src_p
-                    )
+                    with self._metrics.phase("era_dispatch"):
+                        table, queue, c_f1, c_f2, c_p, c_dd = (
+                            self._block.chain(
+                                table, queue, src_f1, src_f2, src_p
+                            )
+                        )
                     self._metrics.inc("dispatches")
                     self._metrics.inc("spec_dispatch")
                     chain.append((c_p, c_f1, c_f2, c_dd, t0))
@@ -2224,7 +2027,6 @@ class ShardedBfsChecker(HostEngineBase):
             "fused_eras_per_dispatch",
             round(self._metrics.get("eras") / n_disp, 3),
         )
-        self._profile_stages(table, queue)
         self._table_dev = table
         if self._memory is not None:
             # Final era's live buffers, for the post-run nbytes parity.
@@ -2233,41 +2035,6 @@ class ShardedBfsChecker(HostEngineBase):
             led.attach("frontier_queue", queue)
             led.attach("record_fps", (rec_fp1, rec_fp2))
         return
-
-    def _profile_stages(self, table, queue) -> None:
-        """Post-run per-stage attribution of device_era wall time across
-        the mesh (CheckerBuilder.stage_profile(); obs/stageprof.py). The
-        kernels run every shard in lockstep, so the attributed `stage_*`
-        phases are GLOBAL times; `steps` is normalized to lockstep era
-        iterations (total steps / n_shards). A failure fails the run: the
-        caller asked for the breakdown."""
-        if not self._stage_profile:
-            return
-        import jax.numpy as jnp
-
-        from ..obs import stageprof
-
-        steps = int(self._metrics.get("steps")) // max(1, self.n_shards)
-        era_secs = self._metrics.phase_ms().get("device_era", 0.0) / 1e3
-        if steps <= 0 or era_secs <= 0.0:
-            return
-        kernels = _build_mesh_stage_kernels(
-            self.tm, self._tprops, self._chunk, self._qcap,
-            self.n_shards, self._quota, self.mesh, "shards",
-            self._stage_iters,
-        )
-        seeds = jnp.arange(1, self.n_shards + 1, dtype=jnp.uint32)
-        with self._metrics.phase("profiler_overhead"):
-            timed = stageprof.measure_stage_kernels(
-                {
-                    name: (fn, (table, queue, seeds))
-                    for name, fn in kernels.items()
-                },
-                self._stage_iters,
-            )
-        stageprof.attribute_stages(
-            self._metrics, timed, era_secs, steps, self._stage_iters
-        )
 
     # -- checkpoint/resume --------------------------------------------------
 
@@ -2487,32 +2254,35 @@ class ShardedBfsChecker(HostEngineBase):
         if not hasattr(self, "_table_np"):
             # Split the packed per-shard key buffer into the four flat
             # lanes lookup_parent_np walks (views over one download each).
-            keys = np.asarray(self._table_dev[0])
-            cap = keys.shape[1] // 2
-            self._table_np = [
-                keys[:, :cap],
-                keys[:, cap:],
-                np.asarray(self._table_dev[1]),
-                np.asarray(self._table_dev[2]),
-            ]
-        chain = [fp64]
-        cur = fp64
-        for _ in range(10_000_000):
-            h1, h2 = split64(cur)
-            s = h1 % self.n_shards
-            shard = tuple(self._table_np[t][s] for t in range(4))
-            found, p1, p2 = vs.lookup_parent_np(shard, h1, h2)
-            if not found:
-                raise RuntimeError(
-                    f"fingerprint {cur} missing from shard {s} during "
-                    "path reconstruction"
-                )
-            if p1 == 0 and p2 == 0:
-                break
-            cur = combine64(p1, p2)
-            chain.append(cur)
-        chain.reverse()
-        return Path.from_fingerprints(self._model, chain)
+            with self._metrics.phase("path_download"):
+                keys = np.asarray(self._table_dev[0])
+                cap = keys.shape[1] // 2
+                self._table_np = [
+                    keys[:, :cap],
+                    keys[:, cap:],
+                    np.asarray(self._table_dev[1]),
+                    np.asarray(self._table_dev[2]),
+                ]
+        with self._metrics.phase("path_walk"):
+            chain = [fp64]
+            cur = fp64
+            for _ in range(10_000_000):
+                h1, h2 = split64(cur)
+                s = h1 % self.n_shards
+                shard = tuple(self._table_np[t][s] for t in range(4))
+                found, p1, p2 = vs.lookup_parent_np(shard, h1, h2)
+                if not found:
+                    raise RuntimeError(
+                        f"fingerprint {cur} missing from shard {s} during "
+                        "path reconstruction"
+                    )
+                if p1 == 0 and p2 == 0:
+                    break
+                cur = combine64(p1, p2)
+                chain.append(cur)
+            chain.reverse()
+        with self._metrics.phase("path_replay"):
+            return Path.from_fingerprints(self._model, chain)
 
 
 # Back-compat style helper mirroring the original prototype's interface.

@@ -361,6 +361,23 @@ def test_mixed_signatures_rejected():
 # ---------------------------------------------------------------------------
 
 
+def test_small_workload_hint_fires(capsys):
+    # 2pc-3 explores 288 states, far below the ~10k crossover where the
+    # device engine's dispatch overhead stops paying for itself.
+    c = (
+        TensorModelAdapter(TwoPhaseTensor(3))
+        .checker()
+        .spawn_tpu_bfs(chunk_size=64, queue_capacity=1 << 10, table_capacity=1 << 10)
+        .join()
+    )
+    assert c.telemetry().get("small_workload_hint") == 288
+    err = capsys.readouterr().err
+    assert "spawn_bfs() on the host" in err
+    # One line only, even though both the spawn-time and run-end checks see
+    # a small number.
+    assert err.count("small workload") == 1
+
+
 def test_small_workload_hint_suppressed_for_multiplexed_lane(capsys):
     checker = (
         TensorModelAdapter(TwoPhaseTensor(3))
@@ -371,7 +388,7 @@ def test_small_workload_hint_suppressed_for_multiplexed_lane(capsys):
         )
         .join()
     )
-    # Same 288-state run that fires the hint in test_stage_profile.py —
+    # Same 288-state run that fires the hint above —
     # flagged as a multiplexed lane it must stay silent.
     assert checker.unique_state_count() == 288
     assert "small_workload_hint" not in checker.telemetry()
