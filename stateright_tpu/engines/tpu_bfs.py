@@ -1145,6 +1145,51 @@ def _build_seed(S: int, qcap: int, tcap: int):
     return seed
 
 
+# Parent lookups one walk program makes per chain; a deeper chain walks on
+# from its last fingerprint in another dispatch of the same program.
+WALK_HOPS = 64
+
+
+def walk_parent_chains(table, fps, width: int, metrics):
+    """Fingerprint chains from an init state to each of `fps`, walked
+    inside the device `table` (`vs.walk_chains`), `width` rows a dispatch:
+    only the chains cross to the host, not the table. `path_walk` times
+    the dispatch, `path_download` the readback that waits for it. Raises
+    RuntimeError for a fingerprint missing from the table."""
+    import jax
+
+    from ..ops import visited_set as vs
+
+    chains = [[fp] for fp in fps]
+    pending = list(range(len(chains)))
+    while pending:
+        batch, pending = pending[:width], pending[width:]
+        h1 = np.zeros(width, dtype=np.uint32)
+        h2 = np.zeros(width, dtype=np.uint32)
+        for row, i in enumerate(batch):
+            h1[row], h2[row] = split64(chains[i][-1])
+        with metrics.phase("path_walk"):
+            out = vs.walk_chains_jit(table, h1, h2, hops=WALK_HOPS)
+            metrics.inc("path_walk_dispatches")
+        with metrics.phase("path_download"):
+            c1, c2, count, status = jax.device_get(out)
+        for row, i in enumerate(batch):
+            n = int(count[row])
+            chains[i].extend(
+                combine64(a, b) for a, b in zip(c1[row, :n], c2[row, :n])
+            )
+            if status[row] == vs.WALK_MISSING:
+                raise RuntimeError(
+                    f"fingerprint {chains[i][-1]} missing from visited table "
+                    "during path reconstruction"
+                )
+            if status[row] == vs.WALK_EXHAUSTED:
+                pending.append(i)
+    for chain in chains:
+        chain.reverse()
+    return chains
+
+
 # Below roughly this many unique states, the host engine's per-state cost
 # beats the device engine's fixed per-dispatch round-trips and compile time
 # (set before any measurement on the v5e; see the README "Host/device
@@ -2188,7 +2233,7 @@ class TpuBfsChecker(HostEngineBase):
             round(self._metrics.get("eras") / n_disp, 3),
         )
 
-        # Retained (on device) for path reconstruction; downloaded lazily.
+        # Retained (on device) for path reconstruction, which walks it there.
         self._table_dev = table
         if self._memory is not None:
             # Re-point the ledger at the final era's live buffers (shapes
@@ -2450,10 +2495,8 @@ class TpuBfsChecker(HostEngineBase):
 
     def discoveries(self) -> Dict[str, Path]:
         self.join()
-        return {
-            name: self._reconstruct(fp)
-            for name, fp in list(self._discovery_fps.items())
-        }
+        fps = dict(self._discovery_fps)
+        return dict(zip(fps, self._reconstruct_all(list(fps.values()))))
 
     def _sample_resolver(self):
         # Device slabs drain fingerprint-only; sample rows are resolved
@@ -2462,41 +2505,17 @@ class TpuBfsChecker(HostEngineBase):
         return self._path_sample_resolver(self._reconstruct)
 
     def _reconstruct(self, fp64: int) -> Path:
-        """Walk table parent pointers, then re-execute the model along the
-        fingerprint chain (reference bfs.rs:380-409). The table is downloaded
-        once (`path_download`); chains are walked in numpy (`path_walk`; per-
-        node device lookups would cost a host round-trip each) and replayed
-        through the model (`path_replay`)."""
-        from ..ops import visited_set as vs
+        return self._reconstruct_all([fp64])[0]
 
-        if not hasattr(self, "_table_np"):
-            import jax.numpy as jnp
-
-            # Concatenate on device, download ONCE (not one transfer per
-            # lane), then split into the four flat lanes lookup_parent_np
-            # walks.
-            with self._metrics.phase("path_download"):
-                flat = np.asarray(jnp.concatenate(self._table_dev))
-            cap = flat.shape[0] // 4
-            self._table_np = tuple(
-                flat[t * cap:(t + 1) * cap] for t in range(4)
-            )
-        with self._metrics.phase("path_walk"):
-            chain = [fp64]
-            cur = fp64
-            for _ in range(10_000_000):
-                h1, h2 = split64(cur)
-                found, p1, p2 = vs.lookup_parent_np(self._table_np, h1, h2)
-                if not found:
-                    raise RuntimeError(
-                        f"fingerprint {cur} missing from visited table "
-                        "during path reconstruction"
-                    )
-                if p1 == 0 and p2 == 0:
-                    break
-                cur = combine64(p1, p2)
-                chain.append(cur)
-            chain.reverse()
+    def _reconstruct_all(self, fps: List[int]) -> List[Path]:
+        """Walk table parent pointers on the device, then re-execute the
+        model along each fingerprint chain (reference bfs.rs:380-409). One
+        walk program serves every chain (`walk_parent_chains`); it is
+        padded to the property count, so every check of a model, and every
+        sampled fingerprint, reuses one compiled program."""
+        chains = walk_parent_chains(
+            self._table_dev, fps, max(1, len(self._tprops)), self._metrics
+        )
         model = self._model
         if self._canon:
             # The table stores representative fingerprints; match raw
@@ -2504,5 +2523,8 @@ class TpuBfsChecker(HostEngineBase):
             from ..tensor import CanonicalTensorAdapter
 
             model = CanonicalTensorAdapter(self.tm)
-        with self._metrics.phase("path_replay"):
-            return Path.from_fingerprints(model, chain)
+        paths = []
+        for chain in chains:
+            with self._metrics.phase("path_replay"):
+                paths.append(Path.from_fingerprints(model, chain))
+        return paths

@@ -399,29 +399,30 @@ def insert(table, h1, h2, p1, p2, active, rcap: int | None = None,
     return table, is_new, unresolved, n_overflow
 
 
-def lookup_parent(table, h1, h2):
-    """Probe for fingerprints; returns (found, parent_h1, parent_h2).
-
-    Same double-hashing sequence as `insert`. One device call per query:
-    use only for rare host-side queries (prefer `lookup_parent_np` on a
-    downloaded table for chain walks).
-    """
+def _probe_parents(table, h1, h2, live):
+    """Probe for fingerprints (h1, h2) where `live`; returns (found,
+    parent_h1, parent_h2). Same double-hashing sequence as `insert`: an
+    empty slot ends a probe, and at most MAX_PROBES slots are read. The
+    rounds stop as soon as every live row is resolved, so a lookup at
+    load <= MAX_LOAD pays the one or two rounds its chain needs, not the
+    whole budget."""
     keys, v1, v2 = table
     capacity = v1.shape[0]
     u = jnp.uint32
     mask = u(capacity - 1)
     hcap = u(capacity)
+    n = h1.shape[0]
     stride = h2 | u(1)
-    idx = h1 & mask
-    done = jnp.zeros(h1.shape, dtype=bool)
-    found = jnp.zeros(h1.shape, dtype=bool)
-    par1 = jnp.zeros_like(h1)
-    par2 = jnp.zeros_like(h2)
 
-    def body(_r, carry):
-        idx, done, found, par1, par2 = carry
-        rk1 = keys[idx]
-        rk2 = keys[hcap + idx]
+    def cond(carry):
+        r, _idx, done, _found, _par1, _par2 = carry
+        return (r < MAX_PROBES) & ~jnp.all(done)
+
+    def body(carry):
+        r, idx, done, found, par1, par2 = carry
+        rk = keys[jnp.concatenate([idx, hcap + idx])]
+        rk1 = rk[:n]
+        rk2 = rk[n:]
         slot_empty = (rk1 == 0) & (rk2 == 0)
         slot_match = (rk1 == h1) & (rk2 == h2)
         hit = ~done & slot_match
@@ -430,12 +431,94 @@ def lookup_parent(table, h1, h2):
         found = found | hit
         done = done | slot_match | slot_empty  # empty slot ends the chain
         idx = jnp.where(done, idx, (idx + stride) & mask)
-        return idx, done, found, par1, par2
+        return r + 1, idx, done, found, par1, par2
 
-    _idx, _done, found, par1, par2 = lax.fori_loop(
-        0, MAX_PROBES, body, (idx, done, found, par1, par2)
+    _r, _idx, _done, found, par1, par2 = lax.while_loop(
+        cond,
+        body,
+        (
+            jnp.int32(0),
+            jnp.where(live, h1 & mask, u(0)),
+            ~live,
+            jnp.zeros(h1.shape, dtype=bool),
+            jnp.zeros_like(h1),
+            jnp.zeros_like(h2),
+        ),
     )
     return found, par1, par2
+
+
+def lookup_parent(table, h1, h2):
+    """Probe for fingerprints; returns (found, parent_h1, parent_h2).
+
+    Same double-hashing sequence as `insert`. One device call per query;
+    to follow parent chains use `walk_chains`, which walks every hop of
+    a batch of chains in one call.
+    """
+    return _probe_parents(table, h1, h2, jnp.ones(h1.shape, dtype=bool))
+
+
+# `walk_chains` row status: the chain reached an init state (a parent of
+# (0, 0)), a fingerprint on it is missing from the table, or the hop
+# budget ran out before either.
+WALK_ROOT, WALK_MISSING, WALK_EXHAUSTED = 0, 1, 2
+
+
+def walk_chains(table, h1, h2, hops: int):
+    """Follow parent pointers from [n] start fingerprints, up to `hops`
+    lookups each, inside the device table.
+
+    Returns (c1, c2, count, status):
+      c1, c2 [n, hops] — column j holds the parent of the chain's j-th
+                         node (node 0 is the start), so the walked chain
+                         is the start followed by c[:count];
+      count [n]        — parents recorded (init-state parents excluded);
+      status [n]       — WALK_ROOT, WALK_MISSING (the chain's last node is
+                         not in the table) or WALK_EXHAUSTED (count ==
+                         hops; walk on from c[hops - 1] in another call).
+    A (0, 0) start is padding and reads WALK_ROOT with count 0. Each hop
+    is one `lookup_parent` probe of the rows still walking; the hops stop
+    once every row is resolved.
+    """
+    u = jnp.uint32
+    n = h1.shape[0]
+    live0 = (h1 != 0) | (h2 != 0)
+
+    def cond(carry):
+        j, _c1, _c2, _cur1, _cur2, _count, _status, done = carry
+        return (j < hops) & ~jnp.all(done)
+
+    def body(carry):
+        j, c1, c2, cur1, cur2, count, status, done = carry
+        live = ~done
+        found, p1, p2 = _probe_parents(table, cur1, cur2, live)
+        missing = live & ~found
+        root = live & found & (p1 == 0) & (p2 == 0)
+        step = live & found & ~root
+        c1 = c1.at[:, j].set(jnp.where(step, p1, u(0)))
+        c2 = c2.at[:, j].set(jnp.where(step, p2, u(0)))
+        count = count + step.astype(jnp.int32)
+        status = jnp.where(missing, WALK_MISSING, status)
+        status = jnp.where(root, WALK_ROOT, status)
+        cur1 = jnp.where(step, p1, cur1)
+        cur2 = jnp.where(step, p2, cur2)
+        return j + 1, c1, c2, cur1, cur2, count, status, done | missing | root
+
+    _j, c1, c2, _cur1, _cur2, count, status, _done = lax.while_loop(
+        cond,
+        body,
+        (
+            jnp.int32(0),
+            jnp.zeros((n, hops), dtype=u),
+            jnp.zeros((n, hops), dtype=u),
+            h1,
+            h2,
+            jnp.zeros(n, dtype=jnp.int32),
+            jnp.where(live0, WALK_EXHAUSTED, WALK_ROOT).astype(jnp.int32),
+            ~live0,
+        ),
+    )
+    return c1, c2, count, status
 
 
 def occupied_mask(table):
@@ -475,16 +558,19 @@ from ..compat import donate_argnums_safe as _donate
 
 insert_jit = jax.jit(insert, donate_argnums=_donate(0))
 lookup_parent_jit = jax.jit(lookup_parent)
+# No donation: path reconstruction reads the table it leaves live.
+walk_chains_jit = jax.jit(walk_chains, static_argnames=("hops",))
 rehash_jit = jax.jit(rehash, donate_argnums=_donate(1))
 
 
 def lookup_parent_np(table_np, h1: int, h2: int):
     """Pure-numpy probe over a host copy of the table lanes.
 
-    Path reconstruction walks one parent per step; doing that on-device
-    would cost a host round-trip per node, so the table is downloaded once
-    and chains are walked here. Same double-hashing sequence as `insert`.
-    Returns (found, parent_h1, parent_h2).
+    For tables already on the host: the sharded engine's per-shard
+    downloads, the lane engine's tables and checkpoints. A table that
+    lives on the device is walked there (`walk_chains`), which downloads
+    the chains and not the table. Same double-hashing sequence as
+    `insert`. Returns (found, parent_h1, parent_h2).
     """
     k1, k2, v1, v2 = table_np
     cap = len(k1)

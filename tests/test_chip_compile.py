@@ -147,6 +147,22 @@ def test_visited_rehash_compiles_into_2pc10_table(one_chip):
     assert mem.alias_size_in_bytes >= 4 * 4 * tcap, mem
 
 
+def test_parent_walk_compiles_at_2pc10_table(one_chip):
+    from stateright_tpu.engines.tpu_bfs import WALK_HOPS
+
+    tcap = 1 << 28
+    n = len(TwoPhaseTensor(10).tensor_properties())
+    start = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    compiled = (
+        jax.jit(vs.walk_chains, static_argnames=("hops",))
+        .lower(_placed(vs.abstract_table(tcap), one_chip), start, start, hops=WALK_HOPS)
+        .compile()
+    )
+    mem = _fits(compiled)
+    # The table is read in place: the walk's own buffers are the chains.
+    assert mem.output_size_in_bytes < 4096 * n, mem
+
+
 @pytest.mark.parametrize("spec", sorted(SPECS))
 def test_sharded_era_program_compiles_for_four_v5e(spec, topo, donating):
     from stateright_tpu.analysis.program import _sharded_geometry

@@ -15,6 +15,7 @@ correct but not canonical, so it is not used for the identity test.)
 """
 
 import collections
+import fcntl
 import json
 import time
 from dataclasses import dataclass
@@ -187,13 +188,19 @@ def engine_runs(tmp_path_factory):
     second python run for run-to-run determinism."""
     tmp = tmp_path_factory.mktemp("netobs")
     runs = {}
-    for tag, engine in [("python", "python"), ("python2", "python")] + [
-        (e, e) for e in _engines() if e != "python"
-    ]:
-        path = tmp / f"{tag}.jsonl"
-        snapshot = _record_pingpong(path, engine)
-        meta, events = load_trace(str(path))
-        runs[tag] = (str(path), meta, events, snapshot)
+    # Every run binds PORT, and pytest-xdist workers that share this module
+    # each build the fixture: a lock in the session's shared temp root keeps
+    # their runs apart.
+    lock_path = tmp_path_factory.getbasetemp().parent / "netobs-port.lock"
+    with open(lock_path, "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        for tag, engine in [("python", "python"), ("python2", "python")] + [
+            (e, e) for e in _engines() if e != "python"
+        ]:
+            path = tmp / f"{tag}.jsonl"
+            snapshot = _record_pingpong(path, engine)
+            meta, events = load_trace(str(path))
+            runs[tag] = (str(path), meta, events, snapshot)
     return runs
 
 

@@ -183,14 +183,19 @@ def test_counterexample_path_phases(engine, tmp_path):
         )
         c = _single_copy(engine, builder).join()
         assert list(c._discovery_fps) == ["linearizable"]
-        return c.discovery("linearizable").into_actions()
+        return c, c.discovery("linearizable").into_actions()
 
-    plain = check()
+    _c, plain = check()
     reset_profiled_phase_totals()
     with jax.profiler.trace(str(tmp_path)):
-        traced = check()
+        c, traced = check()
     totals = profiled_phase_totals()
     assert traced == plain and plain
     for phase in ("path_download", "path_walk", "path_replay"):
         assert totals[phase]["count"] == 1, (phase, totals)
     assert {"phase:path_download", "phase:path_replay"} <= _host_span_names(tmp_path)
+    if engine == "tpu_bfs":
+        # The chain was walked on the device: one walk program, and no
+        # download of the table.
+        assert c.telemetry()["path_walk_dispatches"] == 1
+        assert not hasattr(c, "_table_np")
