@@ -186,10 +186,11 @@ def fuse_tail_len(fuse: int) -> int:
 
 
 def params_len(A: int, P: int, cov: bool, sample_k: int,
-               fuse: int = 1) -> int:
+               fuse: int = 1, canon: bool = False) -> int:
     """Length of the packed uint32 params vector the era loop carries:
     scalars + rec_fp tail + optional coverage tail + optional sampling
-    tail + optional multi-era fusion tail. This is THE layout contract —
+    tail + optional multi-era fusion tail + optional symmetry word (the
+    dispatch's `canon_rewrites`, last). This is THE layout contract —
     the engine, the checkpoint codec, and the STR6xx program lint all
     size their buffers from it."""
     n = P_LEN + 2 * P
@@ -199,12 +200,12 @@ def params_len(A: int, P: int, cov: bool, sample_k: int,
         from ..obs.sample import slab_entries
 
         n += 4 + 5 * slab_entries(sample_k)
-    return n + fuse_tail_len(fuse)
+    return n + fuse_tail_len(fuse) + int(canon)
 
 
 def loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
                        tcap: int, cov: bool, sample_k: int,
-                       fuse: int = 1):
+                       fuse: int = 1, canon: bool = False):
     """`jax.ShapeDtypeStruct` pytree matching `_build_loop`'s signature
     `(table, queue, rec_fp1, rec_fp2, params)` — lets the STR6xx program
     lint (analysis/program.py) trace/lower the era loop WITHOUT
@@ -217,7 +218,7 @@ def loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
     sds = jax.ShapeDtypeStruct
     table = (sds((2 * tcap,), u32), sds((tcap,), u32), sds((tcap,), u32))
     queue = tuple(sds((qcap,), u32) for _ in range(S + 2))
-    plen = params_len(A, P, cov, sample_k, fuse)
+    plen = params_len(A, P, cov, sample_k, fuse, canon)
     return (table, queue, sds((P,), u32), sds((P,), u32), sds((plen,), u32))
 
 
@@ -290,6 +291,13 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
     Coverage and the sampling slab accumulate ACROSS inner eras (both
     are additive deltas drained once per readback), so one fused
     readback is indistinguishable from the sum of its serial eras.
+
+    With ``canon`` (symmetry reduction) every compacted candidate is
+    replaced by its representative, and the params end in one more word:
+    the dispatch's count of valid candidates the representative changed
+    (``canon_rewrites``), gated like the generated count so retried
+    partial steps never count twice. Without it the carry and the
+    layout are those of a build that never heard of the counter.
 
     Non-raw builds return an `EraProgram(serial, chain)` pair: the same
     traced program jitted twice — ``serial`` donates the full operand
@@ -404,7 +412,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             (
                 _table, _queue, _head, count, unique, _gen, steps,
                 err_cnt, _take_cap, rec_acc, _hseen, _f1, _f2, _fd, _covc,
-                sampc, max_steps,
+                sampc, _canc, max_steps,
             ) = carry
             fin_hit = ((rec_acc & fin_any) != u(0)) | (
                 (fin_all_en != u(0)) & ((rec_acc & fin_all) == fin_all)
@@ -443,6 +451,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 faccd,
                 covc,
                 sampc,
+                canc,
                 max_steps,
             ) = carry
             take = jnp.minimum(jnp.minimum(count, u(chunk)), take_cap)
@@ -483,7 +492,12 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 # before fingerprinting — the ring and table then only
                 # ever see representatives.
                 with stage_scope("canon"):
-                    cl = tm.representative_lanes(jnp, cl)
+                    rep = tm.representative_lanes(jnp, cl)
+                    moved = rep[0] != cl[0]
+                    for s in range(1, S):
+                        moved = moved | (rep[s] != cl[s])
+                    rewrites = (moved & vvalid).sum(dtype=jnp.uint32)
+                    cl = rep
             with stage_scope("hash"):
                 ch1, ch2 = hash_lanes_jnp(cl)
             # Stage-2 compaction: in-batch dedup (claim-arbitrated,
@@ -582,6 +596,8 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             count = count - consumed + new_count
             unique = unique + new_count
             gen = gen + jnp.where(ovf, u(0), ex.generated)
+            if canon:
+                canc = (canc[0] + jnp.where(ovf, u(0), rewrites),)
             steps = steps + (~ovf).astype(jnp.uint32)
             # Regrow at chunk/16 per clean step (was chunk/64): after an
             # overflow halves the cap, the old creep needed ~64 steps per
@@ -712,6 +728,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 faccd,
                 covc,
                 sampc,
+                canc,
                 max_steps,
             )
 
@@ -740,8 +757,10 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             if sample_k
             else ()
         )
+        canc0 = (u(0),) if canon else ()  # canon_rewrites of the dispatch
+
         def run_era(table, queue, head0, count0, unique0, rec_bits,
-                    max_steps, err0, take_cap0, covc, sampc,
+                    max_steps, err0, take_cap0, covc, sampc, canc,
                     rec_fp1, rec_fp2):
             # ONE era: the data-dependent while_loop plus its once-per-era
             # epilogue. Factored so the multi-era fusion path below can
@@ -770,6 +789,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 tuple(zero_lane for _ in range(P)),
                 covc,
                 sampc,
+                canc,
                 max_steps,
             )
             (
@@ -789,6 +809,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 faccd,
                 covc_out,
                 sampc_out,
+                canc_out,
                 _ms,
             ) = lax.while_loop(cond, body, init)
 
@@ -862,7 +883,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                     ),
                 )
             return (table, queue, head, count, unique, rec_bits_out,
-                    err_cnt, take_cap_out, covc_out, sampc_out,
+                    err_cnt, take_cap_out, covc_out, sampc_out, canc_out,
                     rec_fp1, rec_fp2, steps, gen, maxd, next_budget,
                     budget_only)
 
@@ -871,12 +892,12 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             # bit-identical lowering to the pre-fusion build.
             (
                 table, queue, head, count, unique, rec_bits_out, err_cnt,
-                take_cap_out, covc_out, sampc_out, rec_fp1, rec_fp2,
-                steps, gen, maxd, next_budget, _budget_only,
+                take_cap_out, covc_out, sampc_out, canc_out, rec_fp1,
+                rec_fp2, steps, gen, maxd, next_budget, _budget_only,
             ) = run_era(
                 table, queue, head0, count0, unique0, rec_bits0,
                 max_steps0, params[P_ERR], params[P_TAKE_CAP],
-                covc0, sampc0, rec_fp1, rec_fp2,
+                covc0, sampc0, canc0, rec_fp1, rec_fp2,
             )
             ftail = []
         else:
@@ -908,16 +929,16 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                     k, _cont, steps_acc, gen_acc, maxd_acc,
                     fsteps, fgen, funiq, fcount,
                     table, queue, head, count, unique, rec_bits, ms,
-                    err, tc, covc, sampc, rfp1, rfp2,
+                    err, tc, covc, sampc, canc, rfp1, rfp2,
                 ) = oc
                 uniq_in = unique
                 (
                     table, queue, head, count, unique, rec_bits, err, tc,
-                    covc, sampc, rfp1, rfp2, steps, gen, maxd,
+                    covc, sampc, canc, rfp1, rfp2, steps, gen, maxd,
                     next_budget, budget_only,
                 ) = run_era(
                     table, queue, head, count, unique, rec_bits, ms,
-                    err, tc, covc, sampc, rfp1, rfp2,
+                    err, tc, covc, sampc, canc, rfp1, rfp2,
                 )
                 cont = budget_only.astype(u)
                 if sample_k:
@@ -932,7 +953,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                     funiq.at[k].set(unique - uniq_in),
                     fcount.at[k].set(count),
                     table, queue, head, count, unique, rec_bits,
-                    next_budget, err, tc, covc, sampc, rfp1, rfp2,
+                    next_budget, err, tc, covc, sampc, canc, rfp1, rfp2,
                 )
 
             oinit = (
@@ -940,14 +961,14 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 fzero, fzero, fzero, fzero,
                 table, queue, head0, count0, unique0, rec_bits0,
                 max_steps0, params[P_ERR], params[P_TAKE_CAP],
-                covc0, sampc0, rec_fp1, rec_fp2,
+                covc0, sampc0, canc0, rec_fp1, rec_fp2,
             )
             (
                 k_out, _cont, steps, gen, maxd,
                 fsteps, fgen, funiq, fcount,
                 table, queue, head, count, unique, rec_bits_out,
                 next_budget, err_cnt, take_cap_out, covc_out, sampc_out,
-                rec_fp1, rec_fp2,
+                canc_out, rec_fp1, rec_fp2,
             ) = lax.while_loop(ocond, obody, oinit)
             # Fusion tail: [fuse_lim (pass-through), n_inner] +
             # steps[fuse] | generated[fuse] | unique[fuse] |
@@ -1017,6 +1038,8 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                     used[topi].astype(u),
                 ]
             parts += ftail
+            if canon:
+                parts.append(canc_out[0][None])  # canon_rewrites, last
             params_out = jnp.concatenate(parts)
         return table, queue, rec_fp1, rec_fp2, params_out
 
@@ -1471,6 +1494,9 @@ class TpuBfsChecker(HostEngineBase):
         nfuse = fuse_tail_len(self._fuse)
         fb = self._fbase
         last_fuse_lim = None
+        # Symmetry word (canon_rewrites), last in the params.
+        ncanon = int(self._canon)
+        c_off = fb + nfuse
 
         def _fuse_lim_now() -> int:
             if self._fuse <= 1:
@@ -1569,7 +1595,8 @@ class TpuBfsChecker(HostEngineBase):
                         1, min(max_steps0, 1 + remaining // max(1, C * A))
                     )
                 template = np.zeros(
-                    P_LEN + 2 * P + ncov + nsamp + nfuse, dtype=np.uint32
+                    P_LEN + 2 * P + ncov + nsamp + nfuse + ncanon,
+                    dtype=np.uint32,
                 )
                 if nfuse:
                     last_fuse_lim = _fuse_lim_now()
@@ -1718,6 +1745,8 @@ class TpuBfsChecker(HostEngineBase):
             self._metrics.inc("eras", n_inner)
             self._metrics.inc("steps", int(vals[10]))
             self._metrics.inc("states_generated", int(vals[8]))
+            if ncanon:
+                self._metrics.inc("canon_rewrites", int(vals[c_off]))
             self._metrics.set_gauge("take_cap", take_cap)
             self._unique = int(vals[2])
             self._state_count += int(vals[8])
@@ -2016,7 +2045,8 @@ class TpuBfsChecker(HostEngineBase):
 
             if host_dirty:
                 arr = np.zeros(
-                    P_LEN + 2 * P + ncov + nsamp + nfuse, dtype=np.uint32
+                    P_LEN + 2 * P + ncov + nsamp + nfuse + ncanon,
+                    dtype=np.uint32,
                 )
                 if nfuse:
                     arr[fb] = fuse_lim
@@ -2320,6 +2350,7 @@ class TpuBfsChecker(HostEngineBase):
             coverage=self._cov,
             sample_k=self._sample_k,
             fuse=self._fuse,
+            canon=self._canon,
         )
         arrays = {
             "visited_table": table,
@@ -2331,6 +2362,8 @@ class TpuBfsChecker(HostEngineBase):
         }
         if self._fuse > 1:
             arrays["fusion_tail"] = params_dev
+        if self._canon:
+            arrays["canon_tail"] = params_dev
         rec.register_components(sizes, arrays=arrays)
         rec.set_geometry(
             rows=self._tcap,

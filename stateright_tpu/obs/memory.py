@@ -151,6 +151,7 @@ def bfs_component_sizes(
     coverage: bool = True,
     sample_k: int = 64,
     fuse: int = 1,
+    canon: bool = False,
 ) -> Dict[str, Dict[str, Any]]:
     """Device buffers of the solo BFS engine (engines/tpu_bfs.py).
 
@@ -164,7 +165,9 @@ def bfs_component_sizes(
     slab_entries(k) words (``sample_k=0`` = sampling off). With
     multi-era fusion (``fuse > 1``) the params additionally carry the
     fusion tail ([fuse_lim, n_inner] + 4 per-inner-era lanes) — carved
-    out as its own component so the nbytes parity stays exact.
+    out as its own component so the nbytes parity stays exact. Under
+    symmetry reduction (``canon``) one last word carries the dispatch's
+    ``canon_rewrites``.
     """
     from ..engines.tpu_bfs import P_LEN, _cov_len, fuse_tail_len
 
@@ -186,6 +189,8 @@ def bfs_component_sizes(
         sizes["sample_slab"] = _entry((4 + 5 * slab_entries(int(sample_k)),))
     if int(fuse) > 1:
         sizes["fusion_tail"] = _entry((fuse_tail_len(int(fuse)),))
+    if canon:
+        sizes["canon_tail"] = _entry((1,))
     return sizes
 
 

@@ -118,6 +118,29 @@ def test_solo_era_program_compiles_for_v5e(spec, variant, one_chip, donating):
     assert mem.alias_size_in_bytes >= table_bytes, mem
 
 
+def test_symmetric_era_program_compiles_for_v5e(one_chip, donating):
+    """2pc-10 under symmetry reduction (the canon stage and its
+    `canon_rewrites` word) at default options, as `.symmetry()` runs it."""
+    tm = TwoPhaseTensor(10)
+    props = tm.tensor_properties()
+    g = era_geometry(tm)
+    raw = _build_loop(
+        tm, props, g["chunk"], g["qcap"], True, g["cov"], raw=True,
+        sample_k=g["sample_k"],
+    )
+    args = loop_abstract_args(
+        tm, props, g["chunk"], g["qcap"], g["tcap"], g["cov"], g["sample_k"],
+        canon=True,
+    )
+    compiled = (
+        jax.jit(raw, donate_argnums=donate_argnums_pinned((0, 1, 2, 3, 4)))
+        .lower(*_placed(args, one_chip))
+        .compile()
+    )
+    mem = _fits(compiled)
+    assert mem.alias_size_in_bytes >= 4 * (4 * g["tcap"]), mem
+
+
 def test_visited_insert_compiles_at_2pc10_table(one_chip):
     tm = TwoPhaseTensor(10)
     tcap = 1 << 28  # 61.5M states at MAX_LOAD: the table 2pc-10 ends at

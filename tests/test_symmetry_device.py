@@ -18,6 +18,7 @@ with identical property verdicts.
 import numpy as np
 import pytest
 
+from benchmark.reference import two_phase_commit_sym as closure
 from stateright_tpu import TensorModelAdapter
 from stateright_tpu.models import TwoPhaseTensor
 
@@ -106,3 +107,49 @@ def test_symmetry_without_canonicalizer_raises():
 
     with pytest.raises(ValueError, match="representative_lanes"):
         TensorModelAdapter(IncrementTensor(2)).checker().symmetry().spawn_tpu_bfs()
+
+
+@pytest.mark.parametrize("n", [3, 4, 5, 6, 7])
+def test_device_closure_matches_the_plain_closure(n):
+    """Count, successors generated and `canon_rewrites` against the plain
+    closure BFS (benchmark/reference/two_phase_commit_sym.py), and every
+    example path a chain of representatives ending in an example."""
+    sym = _spawn(TwoPhaseTensor(n), symmetry=True)
+    tel = sym.telemetry()
+    assert (sym.unique_state_count(), sorted(sym.discoveries())) == closure.explore_sym(n)
+    assert tel["states_generated"] == closure.generated(n)
+    assert tel["canon_rewrites"] == closure.rewrites(n)
+    for name, path in sym.discoveries().items():
+        r = closure.judge_path(path.into_states(), n, name)
+        assert r["invalid_steps"] == 0 and r["met"], (name, r)
+    assert {
+        name: len(path.into_states()) for name, path in sym.discoveries().items()
+    } == {"abort agreement": n + 1, "commit agreement": 3 * n + 2}
+
+
+def test_canon_rewrites_under_fused_pipelined_eras():
+    """Fused and chained eras carry the counter across inner eras and
+    retried partial steps without counting twice."""
+    sym = (
+        TensorModelAdapter(TwoPhaseTensor(5))
+        .checker()
+        .symmetry()
+        .pipeline(depth=4, fuse=4)
+        .spawn_tpu_bfs(
+            chunk_size=64, queue_capacity=1 << 13, table_capacity=1 << 14,
+            sync_steps=4,
+        )
+        .join()
+    )
+    tel = sym.telemetry()
+    assert tel["dispatches"] < tel["eras"]
+    assert sym.unique_state_count() == TPC5_SYM_CLOSURE
+    assert tel["canon_rewrites"] == closure.rewrites(5) == 1_560
+    # The params' last word is the counter; the memory ledger carves it.
+    snap = tel["memory"]
+    assert snap["components"]["canon_tail"]["bytes"] == 4
+    assert snap["total_bytes"] == sym._memory.ledger.live_nbytes()
+
+
+def test_no_canon_rewrites_without_symmetry():
+    assert "canon_rewrites" not in _spawn(TwoPhaseTensor(4), symmetry=False).telemetry()
