@@ -2394,8 +2394,11 @@ class TpuBfsChecker(HostEngineBase):
         from ..ops import visited_set as vs
 
         new_cap = self._tcap * 2
-        new_table, n_unresolved = vs.rehash_jit(table, vs.empty_table(new_cap))
-        if int(n_unresolved) != 0:
+        new_table, counts = vs.rehash_jit(table, vs.empty_table(new_cap))
+        counts = np.asarray(counts)
+        self._metrics.inc("rehash_rows", counts[vs.REHASH_ROWS])
+        self._metrics.inc("rehash_stragglers", counts[vs.REHASH_STRAGGLERS])
+        if counts[vs.REHASH_UNRESOLVED] != 0:
             raise RuntimeError("rehash failed; table pathologically full")
         return new_table, new_cap
 

@@ -59,6 +59,15 @@ full-width phase. The candidates that no phase resolves are reported
 `unresolved`; callers must grow the table and keep load <= MAX_LOAD so
 that outcome stays (measurably) one-in-millions — and fail loudly if it
 happens.
+
+Growth (`rehash`) probes only the occupied rows. It compacts them once
+into a batch of W = MAX_LOAD × old capacity rows and runs the same
+phases at that width, not at the old table's, so the ~3/4 of the slots
+that are empty cost one cumsum instead of every probe round. W is safe
+because every caller grows before the load passes MAX_LOAD (growth,
+proactive reshard, degraded regrow from a checkpoint taken under the same
+rule); a table that breaks that rule has rows past W, and they count as
+unresolved, so the growth fails loudly instead of dropping rows.
 """
 
 from __future__ import annotations
@@ -75,6 +84,12 @@ PRIMARY_ROUNDS = 3  # primary full-width probe rounds
 # 4096-wide tail and force-engaging every later stage on every step, the
 # stage-profiled cause of the 2pc-10 per-step cliff).
 REHASH_ROUNDS = 8  # deeper primary phase for whole-table rehashes
+# A rehash probes the compacted occupied rows (W = MAX_LOAD × old
+# capacity) into a table twice the size, so it ends at load <= MAX_LOAD/2.
+# Eight rounds there leave ~0 stragglers for the tail stages: at W = 2^20
+# uniform keys, ~1.4K remain after four rounds, ~165 after five, ~2 after
+# seven (CPU, seeds 0-1). MAX_PROBES, which checkpoint meta records,
+# derives from it.
 # Tail stages: (rounds, width) pairs at GEOMETRICALLY NARROWING widths.
 # Each stage re-compacts the candidates still unresolved at that point
 # into its own [width] batch, so late stages probe at the width of the
@@ -264,22 +279,24 @@ def _compact_ids(mask, cap: int):
 
 def _probe_all(table, claim, h1, h2, p1, p2, idx, done, is_new, rounds):
     """Primary probe rounds, then a cascade of gated straggler stages at
-    narrowing widths. Returns (table, claim, done, is_new)."""
+    narrowing widths. Returns (table, claim, done, is_new, stragglers),
+    `stragglers` the candidates the primary rounds left unresolved."""
     u = jnp.uint32
     n = h1.shape[0]
 
     table, claim, idx, done, is_new = _probe_rounds(
         table, claim, h1, h2, p1, p2, idx, done, is_new, rounds
     )
+    stragglers = (~done).sum(dtype=u)
 
-    for stage_rounds, stage_cap in TAIL_STAGES:
+    for stage, (stage_rounds, stage_cap) in enumerate(TAIL_STAGES):
         # Gate each stage — INCLUDING its compaction — on the live
         # straggler count: with nothing left the stage reduces to one
         # scalar sum + a branch instead of a full-width cumsum, gathers,
         # and stage_rounds probe rounds. Candidates that overflow a
         # stage's width stay un-done and fall through to the next stage
         # (or out, reported unresolved by the caller).
-        pending = (~done).sum(dtype=u)
+        pending = (~done).sum(dtype=u) if stage else stragglers
 
         def run_stage(op, stage_rounds=stage_rounds, stage_cap=stage_cap):
             table, claim, idx, done, is_new = op
@@ -320,11 +337,27 @@ def _probe_all(table, claim, h1, h2, p1, p2, idx, done, is_new, rounds):
             (table, claim, idx, done, is_new),
         )
 
-    return table, claim, done, is_new
+    return table, claim, done, is_new, stragglers
 
 
-def insert(table, h1, h2, p1, p2, active, rcap: int | None = None,
-           primary_rounds: int = PRIMARY_ROUNDS):
+def _claim_scratch(capacity: int, h1):
+    """The claim protocol's scratch for a table of `capacity` slots.
+
+    Stale values are harmless — a winner check only reads slots that were
+    written earlier in the same round. Seeded from a varying input (h1) so
+    the carry type stays consistent under shard_map (a constant-zeros init
+    would be unvarying on the mesh axis). Capped at 2^22 entries:
+    table-width up to there (a tightly hashed claim measured SLOWER in situ
+    at these sizes), hashed beyond — giant tables (2pc-10 needs 2^28 slots)
+    must not pay a 1GB memset plus table-width claim traffic per call.
+    Aliased claim slots only cost a harmless retry round (see
+    _probe_rounds).
+    """
+    u = jnp.uint32
+    return jnp.zeros(min(capacity, 1 << 22), dtype=u) + (h1[0] & u(0))
+
+
+def insert(table, h1, h2, p1, p2, active, rcap: int | None = None):
     """Insert fingerprints (h1,h2) with parents (p1,p2) where `active`.
 
     Returns (table, is_new, unresolved, n_overflow):
@@ -349,25 +382,15 @@ def insert(table, h1, h2, p1, p2, active, rcap: int | None = None,
     u = jnp.uint32
     mask = u(capacity - 1)
     n = h1.shape[0]
-    # Claim scratch: stale values are harmless — a winner check only reads
-    # slots that were written earlier in the same round. Seeded from a
-    # varying input (h1) so the carry type stays consistent under shard_map
-    # (a constant-zeros init would be unvarying on the mesh axis).
-    # Capped at 2^22 entries: table-width up to there (a tightly hashed
-    # claim measured SLOWER in situ at these sizes), hashed beyond —
-    # giant tables (2pc-10 needs 2^28 slots) must not pay a 1GB memset
-    # plus table-width claim traffic per insert call. Aliased claim slots
-    # only cost a harmless retry round (see _probe_rounds).
-    claim_cap = min(capacity, 1 << 22)
-    claim = jnp.zeros(claim_cap, dtype=u) + (h1[0] & u(0))
+    claim = _claim_scratch(capacity, h1)
 
     if rcap is None:
         # Inactive candidates start pinned at slot 0 (coalesced masked
         # gathers); see the pinning note in _probe_rounds.
         idx = jnp.where(active, h1 & mask, u(0))
-        table, _claim, done, is_new = _probe_all(
+        table, _claim, done, is_new, _stragglers = _probe_all(
             table, claim, h1, h2, p1, p2, idx, ~active,
-            jnp.zeros_like(active), primary_rounds,
+            jnp.zeros_like(active), PRIMARY_ROUNDS,
         )
         return table, is_new, active & ~done, u(0)
 
@@ -378,9 +401,9 @@ def insert(table, h1, h2, p1, p2, active, rcap: int | None = None,
     cp1 = p1[cids]
     cp2 = p2[cids]
     c_idx = jnp.where(cvalid, ch1 & mask, u(0))
-    table, _claim, c_done, c_new = _probe_all(
+    table, _claim, c_done, c_new, _stragglers = _probe_all(
         table, claim, ch1, ch2, cp1, cp2, c_idx, ~cvalid,
-        cvalid & ~cvalid, primary_rounds,
+        cvalid & ~cvalid, PRIMARY_ROUNDS,
     )
     # Scatter results back to the full-width domain.
     c_my = jnp.arange(rcap, dtype=u)
@@ -527,26 +550,46 @@ def occupied_mask(table):
     return (table[0][:cap] != 0) | (table[0][cap:] != 0)
 
 
+# `rehash` count vector: rows left unplaced (a failed rehash), occupied
+# rows moved, and rows the primary rounds left to the tail stages.
+REHASH_UNRESOLVED, REHASH_ROWS, REHASH_STRAGGLERS = 0, 1, 2
+
+
+def rehash_width(capacity: int) -> int:
+    """Rows a rehash of a `capacity`-slot table probes: the most a table
+    kept at load <= MAX_LOAD can hold."""
+    return max(1, int(MAX_LOAD * capacity))
+
+
 def rehash(old_table, new_table):
     """Re-insert every occupied row of `old_table` into `new_table`.
 
     Runs entirely on device (table growth must not round-trip the table
-    through the host). Returns (new_table, n_unresolved).
+    through the host). The occupied rows are compacted once into a
+    `rehash_width(old capacity)` batch, and only that batch is probed.
+    Returns (new_table, counts), counts a uint32 vector indexed by
+    REHASH_UNRESOLVED, REHASH_ROWS and REHASH_STRAGGLERS; rows past the
+    batch width count as unresolved, so an overfull table fails loudly.
     """
     from ..obs.spans import REHASH_SCOPE
 
+    u = jnp.uint32
     with jax.named_scope(REHASH_SCOPE):
-        occ = occupied_mask(old_table)
         cap = table_capacity(old_table)
-        k1 = old_table[0][:cap]
-        k2 = old_table[0][cap:]
-        v1, v2 = old_table[1], old_table[2]
-        # A rehash inserts millions of rows at once; use a deeper primary
-        # phase so the fixed-size tail only sees genuine stragglers.
-        new_table, _is_new, unresolved, _ovf = insert(
-            new_table, k1, k2, v1, v2, occ, primary_rounds=REHASH_ROUNDS
+        width = rehash_width(cap)
+        ids, valid, n_rows = _compact_ids(occupied_mask(old_table), width)
+        keys, v1, v2 = old_table
+        rk = keys[jnp.concatenate([ids, u(cap) + ids])]
+        h1, h2 = rk[:width], rk[width:]
+        new_cap = table_capacity(new_table)
+        idx = jnp.where(valid, h1 & u(new_cap - 1), u(0))
+        new_table, _claim, done, _is_new, stragglers = _probe_all(
+            new_table, _claim_scratch(new_cap, h1), h1, h2, v1[ids], v2[ids],
+            idx, ~valid, valid & ~valid, REHASH_ROUNDS,
         )
-        return new_table, unresolved.sum(dtype=jnp.uint32)
+        overflow = n_rows - jnp.minimum(n_rows, u(width))
+        unresolved = (~done).sum(dtype=u) + overflow
+        return new_table, jnp.stack([unresolved, n_rows, stragglers])
 
 
 # Host-callable jitted twins. Never call `insert`/`lookup_parent`/`rehash`

@@ -876,7 +876,8 @@ def _build_grow(old_cap: int, new_cap: int, mesh, axis: str):
     """Compile a shard_map'd per-shard rehash old_cap -> new_cap.
 
     Runs entirely on device: each shard re-inserts its occupied rows into
-    a fresh table created in-program. Returns (new_table, unresolved[N]).
+    a fresh table created in-program. Returns (new_table, counts[N, 3]),
+    each shard's `vs.rehash` count vector.
     """
     key = (old_cap, new_cap, tuple(id(d) for d in mesh.devices.flat))
     cached = _GROW_CACHE.get(key)
@@ -900,10 +901,10 @@ def _build_grow(old_cap: int, new_cap: int, mesh, axis: str):
         # fail the rehash loop's carry typing).
         vz = shard[0][0] & jnp.uint32(0)
         empty = tuple(l + vz for l in vs.empty_table(new_cap))
-        new_table, unres = vs.rehash(shard, empty)
+        new_table, counts = vs.rehash(shard, empty)
         return (
             tuple(jnp.expand_dims(l, 0) for l in new_table),
-            jnp.expand_dims(unres, 0),
+            jnp.expand_dims(counts, 0),
         )
 
     spec = PartitionSpec(axis)
@@ -917,12 +918,8 @@ def _build_grow(old_cap: int, new_cap: int, mesh, axis: str):
         donate_argnums=donate_argnums_safe(0),
     )
 
-    def run(table):
-        new_table, unres = grow(table)
-        return new_table, unres
-
-    _GROW_CACHE[key] = run
-    return run
+    _GROW_CACHE[key] = grow
+    return grow
 
 
 class ShardedBfsChecker(HostEngineBase):
@@ -2209,10 +2206,15 @@ class ShardedBfsChecker(HostEngineBase):
         rehash — the table never round-trips through the host (round 5;
         the old implementation downloaded, rehashed, and re-uploaded every
         shard, a multi-GB host bounce at real table sizes)."""
+        from ..ops import visited_set as vs
+
         new_cap = self._tcap * 2
         grow = _build_grow(self._tcap, new_cap, self.mesh, "shards")
-        table, unres = grow(table)
-        if int(np.asarray(unres).sum()) != 0:
+        table, counts = grow(table)
+        counts = np.asarray(counts).sum(axis=0)
+        self._metrics.inc("rehash_rows", counts[vs.REHASH_ROWS])
+        self._metrics.inc("rehash_stragglers", counts[vs.REHASH_STRAGGLERS])
+        if counts[vs.REHASH_UNRESOLVED] != 0:
             raise RuntimeError("rehash failed; table pathologically full")
         self._tcap = new_cap
         return table

@@ -65,8 +65,8 @@ def case_forest(rng):
 
 def case_grown(rng):
     fps, parents = _forest(rng, 1 << 10, 240, roots=3)
-    table, unresolved = vs.rehash_jit(_fill(1 << 10, fps, parents), vs.empty_table(1 << 11))
-    assert int(unresolved) == 0
+    table, counts = vs.rehash_jit(_fill(1 << 10, fps, parents), vs.empty_table(1 << 11))
+    assert int(counts[vs.REHASH_UNRESOLVED]) == 0
     return table, fps[-5:], 4, 2
 
 
