@@ -21,8 +21,9 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-import threading
 import time
+
+from benchmark.harness.clock import CompileClock
 
 TPC7_GOLDEN = 296_448
 PAXOS3_GOLDEN = 1_194_428
@@ -30,49 +31,6 @@ SINGLE_COPY4_GOLDEN = 400_233
 TPC10_GOLDEN = 61_515_776
 # increment:2 / 2pc:3 / 2pc:5 through the serve path's multiplex lanes.
 SERVE_GOLDENS = {"increment:2": 13, "2pc:3": 288, "2pc:5": 8_832}
-
-_COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
-
-
-class CompileClock:
-    """Wall seconds in which JAX was tracing, lowering or compiling (its
-    own monitoring events), so a phase's set-up can be reported apart.
-    Nested traces overlap, so the clock counts the union of the intervals."""
-
-    def __init__(self):
-        import jax
-
-        self._spans = []
-        self._lock = threading.Lock()
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_kw):
-        if event in _COMPILE_EVENTS:
-            end = time.perf_counter()
-            with self._lock:
-                self._spans.append((end - duration, end))
-
-    def secs_between(self, t0: float, t1: float) -> float:
-        with self._lock:
-            spans = sorted(
-                (max(a, t0), min(b, t1)) for a, b in self._spans if b > t0 and a < t1
-            )
-        total, cur_a, cur_b = 0.0, None, None
-        for a, b in spans:
-            if cur_b is None or a > cur_b:
-                if cur_b is not None:
-                    total += cur_b - cur_a
-                cur_a, cur_b = a, b
-            else:
-                cur_b = max(cur_b, b)
-        if cur_b is not None:
-            total += cur_b - cur_a
-        return total
-
 
 def _platforms(arrays) -> list:
     return sorted({d.platform for a in arrays for d in a.devices()})
@@ -220,9 +178,9 @@ def one_chip_phases(full: bool):
         ("serve", serve_phase),
     ]
     if full:
-        # Sized up front as bench.py sizes it (the table 61.5M states end
-        # in, 2^28 slots = 4 GiB), so the run neither rehashes six times nor
-        # spills its frontier to the host; every other option is default.
+        # Sized up front (the table 61.5M states end in, 2^28 slots =
+        # 4 GiB), so the run neither rehashes six times nor spills its
+        # frontier to the host; every other option is default.
         def solo_2pc10(b):
             return b.spawn_tpu_bfs(
                 chunk_size=12288, queue_capacity=1 << 24, table_capacity=1 << 28

@@ -3,7 +3,7 @@
 #
 #   scripts/ci.sh
 #
-# Sixteen stages, fail-fast:
+# Fourteen stages, fail-fast:
 #   1. ruff over the repo (mechanical lint scope; see ruff.toml) — a hard
 #      failure when $CI is set, a loud skip on dev machines without it,
 #   2. the speclint dogfood — every bundled model must analyze with zero
@@ -38,34 +38,26 @@
 #      over the /events SSE stream, histogram _bucket series in
 #      /metrics.prom, and a Chrome-trace export that JSON-parses with
 #      matching B/E pairs,
-#  10. a perf-gate smoke: `bench.py --smoke` (tiny 2pc-5 device run)
-#      seeds a throwaway history, a parity rerun must pass the gate,
-#      and a BENCH_PERTURB_SLEEP-degraded rerun must trip it — proving
-#      `bench.py --gate` actually fails CI on a real regression,
-#  11. a pipelining smoke: a tiny run with speculative era dispatch
+#  10. a pipelining smoke: a tiny run with speculative era dispatch
 #      forced ON (many short eras) must golden-match the serial driver
 #      bit-for-bit and report a flight summary with `host_gap_pct`,
-#  12. a mega-dispatch smoke: the same workload with the speculative
-#      chain at depth 4 AND 4 eras fused per compiled dispatch must
-#      golden-match the serial driver bit-for-bit, report strictly
-#      fewer dispatches than eras,
-#  13. a memory smoke: the capacity planner predicts a small run's
+#  11. a memory smoke: the capacity planner predicts a small run's
 #      footprint before dispatch, the run's memory ledger must match
 #      the live buffers' nbytes EXACTLY and the planner's prediction,
 #      and the `memory_bytes{component=...}` series must render in the
 #      Prometheus exposition,
-#  14. a space smoke: the deterministic bottom-k state sample from a
+#  12. a space smoke: the deterministic bottom-k state sample from a
 #      pipelined device run must equal the host oracle's sample
 #      EXACTLY, the profile must carry field sketches, and the
 #      `space_*` gauges must render in the Prometheus exposition,
-#  15. an out-of-core smoke: 2pc-5 under a device byte cap AND a spill
+#  13. an out-of-core smoke: 2pc-5 under a device byte cap AND a spill
 #      host-RAM budget small enough to force the frontier onto the disk
 #      tier, with delta checkpoints at a tight cadence — must match the
 #      8,832 golden bit-for-bit while having tiered spill to disk (and
 #      refilled every row back), fired >= 1 forecast-triggered proactive
 #      reshard, written >= 2 delta checkpoint generations, and kept the
 #      mean delta save strictly smaller than the mean full save,
-#  16. the tier-1 pytest line from ROADMAP.md (host/CPU; the device
+#  14. the tier-1 pytest line from ROADMAP.md (host/CPU; the device
 #      goldens run under JAX_PLATFORMS=cpu like the test suite does).
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -431,22 +423,6 @@ print(
 )
 PY
 
-echo "== perf-gate smoke =="
-gate_tmp="$(mktemp -d /tmp/_gate_smoke.XXXXXX)"
-hist="$gate_tmp/history.jsonl"
-# Seed run: empty history passes the gate and writes the baseline row.
-JAX_PLATFORMS=cpu python bench.py --smoke --gate "$hist" --history "$hist"
-# Parity rerun of the same workload must stay within budget.
-JAX_PLATFORMS=cpu python bench.py --smoke --gate "$hist" --history "$hist"
-# A sleep injected INSIDE the timing window must trip the gate.
-if JAX_PLATFORMS=cpu BENCH_PERTURB_SLEEP=2.5 \
-   python bench.py --smoke --gate "$hist"; then
-  echo "perf-gate smoke FAILED: degraded run passed the gate" >&2
-  exit 1
-fi
-rm -rf "$gate_tmp"
-echo "perf-gate smoke OK: parity passed, degraded run tripped the gate"
-
 echo "== pipelining smoke =="
 JAX_PLATFORMS=cpu python - <<'PY'
 from stateright_tpu import TensorModelAdapter
@@ -488,53 +464,6 @@ print(
     f"{tel['spec_dispatch']} speculative dispatches "
     f"({tel.get('spec_wasted', 0)} wasted), "
     f"host_gap_pct={fsum['host_gap_pct']}"
-)
-PY
-
-echo "== mega-dispatch smoke =="
-JAX_PLATFORMS=cpu python - <<'PY'
-from stateright_tpu import TensorModelAdapter
-from stateright_tpu.models import TwoPhaseTensor
-
-# Many short eras (sync_steps=4) so the K-deep chain fills and the
-# fused inner loop actually runs several eras per compiled dispatch.
-opts = dict(
-    chunk_size=64, queue_capacity=1 << 12, table_capacity=1 << 11,
-    sync_steps=4,
-)
-
-
-def fingerprint(c):
-    cov = c.coverage()
-    return (
-        c.unique_state_count(), c.state_count(), c.max_depth(),
-        dict(c._discovery_fps), cov["actions"], cov["depths"],
-        tuple(c._sampler.fingerprints()),
-    )
-
-
-def run(builder_fn):
-    b = TensorModelAdapter(TwoPhaseTensor(5)).checker().coverage().sample(k=32)
-    return builder_fn(b).spawn_tpu_bfs(**opts).join()
-
-
-serial = run(lambda b: b.pipeline(False))
-mega = run(lambda b: b.pipeline(depth=4, fuse=4))
-assert fingerprint(serial) == fingerprint(mega), (
-    "mega-dispatch run diverged from the serial driver"
-)
-assert mega.unique_state_count() == 8832, mega.unique_state_count()
-tel = mega.telemetry()
-eras, dispatches = tel["eras"], tel["dispatches"]
-assert dispatches < eras, (dispatches, eras)
-assert tel.get("fused_eras_per_dispatch", 0.0) > 1.0, tel
-assert tel.get("spec_chain_depth", 0) >= 1, tel
-
-print(
-    f"mega-dispatch smoke OK: 8832 uniques golden-match serial in "
-    f"{dispatches} dispatches over {eras} eras "
-    f"(chain depth {tel['spec_chain_depth']}, "
-    f"{tel['fused_eras_per_dispatch']} eras/dispatch)"
 )
 PY
 

@@ -34,12 +34,12 @@ them:
   STR606  static cost model: XLA `cost_analysis()` flops + bytes-accessed
           per era step yield a memory-bound predicted roofline st/s,
           surfaced against the flight recorder's measured rate as an
-          attribution ratio (`telemetry()["program"]`, bench JSON, the
-          WriteReporter recap)
+          attribution ratio (`telemetry()["program"]`, the WriteReporter
+          recap)
 
 Tiers: the default lint pass (``Checker.lint()`` / ``strict()`` / serve
 admission) traces the SOLO ERA LOOP only (~1s, cached per
-`model_signature`). The deep pass (``--program`` on the CLI, bench)
+`model_signature`). The deep pass (``--program`` on the CLI)
 additionally lowers the seed loop, visited-set insert/rehash, the mux
 lane program, and the sharded block, and compiles the era loop for the
 STR606 cost model (seconds — kept off the admission path).
@@ -101,8 +101,7 @@ TRANSFER_PRIMITIVES = frozenset(
 WIDE_DTYPES = frozenset({"int64", "uint64", "float64"})
 
 #: Peak HBM bandwidth (GB/s) by `jax.Device.device_kind`, for the STR606
-#: predicted rate and bench.py's roofline. A kind not listed gets no
-#: prediction, never a default.
+#: predicted rate. A kind not listed gets no prediction, never a default.
 HBM_GBPS_BY_KIND = {
     # Google Cloud documentation, "TPU v5e": 16 GB of HBM at 819 GB/s.
     "TPU v5 lite": 819.0,
@@ -179,18 +178,7 @@ def _trace(fn, args):
 # -- program lowering --------------------------------------------------------
 
 
-#: Representative multi-era fusion factor linted and budgeted alongside
-#: the classic single-era programs: a fused program is a DIFFERENT
-#: compiled artifact (inner while_loop + fusion tail), so it gets its own
-#: budget row — the engine key grows a ``+f{N}`` suffix (e.g.
-#: ``tpu_bfs+f4``) and two fusion factors never share a ratchet.
-FUSED_LINT_FACTOR = 4
-
-GEOMETRY_KEYS = ("chunk", "qcap", "tcap", "cov", "sample_k", "fuse")
-
-
-def _engine_key(base: str, fuse: int) -> str:
-    return base if int(fuse) <= 1 else f"{base}+f{int(fuse)}"
+GEOMETRY_KEYS = ("chunk", "qcap", "tcap", "cov", "sample_k")
 
 
 def _era_geometry(tm: TensorModel) -> Dict[str, Any]:
@@ -222,7 +210,6 @@ def _sharded_geometry(
         "quota": quota,
         "cov": True,
         "sample_k": DEFAULT_SAMPLE_K,
-        "fuse": 1,
     }
 
 
@@ -230,14 +217,12 @@ def _lower_era(tm: TensorModel, g: Dict[str, Any]):
     from ..engines.tpu_bfs import _build_loop, loop_abstract_args
 
     props = tm.tensor_properties()
-    fuse = int(g.get("fuse", 1))
     loop = _build_loop(
         tm, props, g["chunk"], g["qcap"], False, g["cov"],
-        sample_k=g["sample_k"], fuse=fuse,
+        sample_k=g["sample_k"],
     ).serial
     args = loop_abstract_args(
-        tm, props, g["chunk"], g["qcap"], g["tcap"], g["cov"], g["sample_k"],
-        fuse=fuse,
+        tm, props, g["chunk"], g["qcap"], g["tcap"], g["cov"], g["sample_k"]
     )
     return loop, args
 
@@ -246,14 +231,13 @@ def _lower_seed_loop(tm: TensorModel, g: Dict[str, Any]):
     from ..engines.tpu_bfs import _build_seed_loop, seed_loop_abstract_args
 
     props = tm.tensor_properties()
-    fuse = int(g.get("fuse", 1))
     fn = _build_seed_loop(
         tm, props, g["chunk"], g["qcap"], g["tcap"], False, g["cov"],
-        sample_k=g["sample_k"], fuse=fuse,
+        sample_k=g["sample_k"],
     )
     args = seed_loop_abstract_args(
         tm, props, g["chunk"], g["qcap"], g["tcap"], g["cov"],
-        g["sample_k"], g["n_init"], fuse=fuse,
+        g["sample_k"], g["n_init"],
     )
     return fn, args
 
@@ -323,14 +307,13 @@ def _lower_sharded(tm: TensorModel, g: Dict[str, Any]):
 
     props = tm.tensor_properties()
     mesh = Mesh(np.array(jax.devices()), ("shards",))
-    fuse = int(g.get("fuse", 1))
     fn = _build_block(
         tm, props, g["chunk"], g["qcap"], g["n_shards"], g["quota"], mesh,
-        "shards", cov=g["cov"], sample_k=g["sample_k"], fuse=fuse,
+        "shards", cov=g["cov"], sample_k=g["sample_k"],
     ).serial
     args = block_abstract_args(
         tm, props, g["qcap"], g["tcap"], g["n_shards"], g["cov"],
-        g["sample_k"], fuse=fuse,
+        g["sample_k"],
     )
     return fn, args
 
@@ -668,7 +651,7 @@ def _analyze_programs(
     device_kind: str,
 ) -> Dict[str, Any]:
     """Trace, scan, and budget-gate the device programs; returns the
-    summary dict that `cached_summary` later serves to telemetry/bench."""
+    summary dict that `cached_summary` later serves to telemetry."""
     import jax
 
     from ..compat import donate_argnums_safe
@@ -701,7 +684,7 @@ def _analyze_programs(
         _check_transfers(tm, "era_loop", prims, report)
         _check_dtypes(tm, "era_loop", dtypes, report)
         _check_budget(
-            tm, _engine_key("tpu_bfs", g["fuse"]), sig,
+            tm, "tpu_bfs", sig,
             int(sum(prims.values())), summary["geometry"], budgets, report,
         )
         # Lowering to StableHLO text is the expensive half of this pass;
@@ -739,26 +722,6 @@ def _analyze_programs(
                 _check_dtypes(tm, name, dtypes, report)
             except Exception as exc:  # noqa: BLE001
                 _trace_failed(tm, name, exc, report)
-        # The FUSED era loop (mega-dispatch, engines/tpu_bfs.py): a
-        # different compiled artifact with its own budget row keyed
-        # `tpu_bfs+f{N}`.
-        gf = dict(g, fuse=FUSED_LINT_FACTOR)
-        try:
-            loop, args = _lower_era(tm, gf)
-            closed, _ = _trace(loop, args)
-            prims, dtypes = count_ops(closed)
-            summary["programs"]["era_loop_fused"] = _prog_summary(
-                prims, dtypes
-            )
-            _check_transfers(tm, "era_loop_fused", prims, report)
-            _check_dtypes(tm, "era_loop_fused", dtypes, report)
-            _check_budget(
-                tm, _engine_key("tpu_bfs", gf["fuse"]), sig,
-                int(sum(prims.values())),
-                {k: gf[k] for k in GEOMETRY_KEYS}, budgets, report,
-            )
-        except Exception as exc:  # noqa: BLE001
-            _trace_failed(tm, "era_loop_fused", exc, report)
         # The sharded block, with its own geometry and budget line. Its
         # serial variant donates table + queue + params (rec_fps stay
         # live for the host discovery reads).
@@ -766,35 +729,28 @@ def _analyze_programs(
             3 + (tm.state_width + 2) + 1 if donated_leaves > 0 else 0
         )
         sg = _sharded_geometry(tm)
-        for prog_name, geo in (
-            ("sharded_era", sg),
-            ("sharded_era_fused", dict(sg, fuse=FUSED_LINT_FACTOR)),
-        ):
-            try:
-                fn, fargs = _lower_sharded(tm, geo)
-                closed, straced = _trace(fn, fargs)
-                prims, dtypes = count_ops(closed)
-                summary["programs"][prog_name] = _prog_summary(prims, dtypes)
-                if prog_name == "sharded_era":
-                    summary["sharded_geometry"] = dict(geo)
-                _check_transfers(tm, prog_name, prims, report)
-                _check_dtypes(tm, prog_name, dtypes, report)
-                _check_budget(
-                    tm, _engine_key("sharded", geo["fuse"]), sig,
-                    int(sum(prims.values())), dict(geo), budgets, report,
+        prog_name = "sharded_era"
+        try:
+            fn, fargs = _lower_sharded(tm, sg)
+            closed, straced = _trace(fn, fargs)
+            prims, dtypes = count_ops(closed)
+            summary["programs"][prog_name] = _prog_summary(prims, dtypes)
+            summary["sharded_geometry"] = dict(sg)
+            _check_transfers(tm, prog_name, prims, report)
+            _check_dtypes(tm, prog_name, dtypes, report)
+            _check_budget(
+                tm, "sharded", sig, int(sum(prims.values())), dict(sg),
+                budgets, report,
+            )
+            if sharded_donated > 0:
+                slow = straced.lower()
+                check_donation_text(
+                    tm, prog_name, slow.as_text(), sharded_donated, report
                 )
-                if sharded_donated > 0:
-                    slow = straced.lower()
-                    check_donation_text(
-                        tm, prog_name, slow.as_text(), sharded_donated,
-                        report,
-                    )
-                else:
-                    check_donation_text(
-                        tm, prog_name, "", sharded_donated, report
-                    )
-            except Exception as exc:  # noqa: BLE001
-                _trace_failed(tm, prog_name, exc, report)
+            else:
+                check_donation_text(tm, prog_name, "", sharded_donated, report)
+        except Exception as exc:  # noqa: BLE001
+            _trace_failed(tm, prog_name, exc, report)
 
         if lowered is not None:
             _cost_model(tm, g, lowered, summary, report, device_kind)
@@ -874,7 +830,7 @@ def run(
 
     ``cost=False`` (the default lint/strict/serve tier) probes signature
     stability, step_lanes dtypes, and the solo era loop. ``cost=True``
-    (CLI ``--program``, bench) adds the remaining device programs, the
+    (CLI ``--program``) adds the remaining device programs, the
     sharded budget gate, and the STR606 compile + cost model, whose
     predicted rate is for `device_kind` (default: the first device's)."""
     report.families_run.append("program")
@@ -919,7 +875,7 @@ def program_summary(
     """The program-lint summary for `tm` (ops per program, geometry, and
     — with ``cost=True`` — the STR606 flops/bytes/predicted roofline for
     `device_kind`, default the first device's), computing and caching it
-    if absent. bench.py's static section."""
+    if absent."""
     report = AnalysisReport(type(tm).__name__)
     device_kind = device_kind or _default_device_kind()
     run(
@@ -971,30 +927,25 @@ def write_budgets(
     g = _era_geometry(tm)
     sg = _sharded_geometry(tm)
     written = {}
-    # One row per (engine, fusion factor): the fused programs are
-    # distinct compiled artifacts, so each carries its own ratchet.
-    for fuse in (1, FUSED_LINT_FACTOR):
-        gf = dict(g, fuse=fuse)
-        loop, args = _lower_era(tm, gf)
-        closed, _ = _trace(loop, args)
-        prims, _dt = count_ops(closed)
-        written[f"{_engine_key('tpu_bfs', fuse)}|{sig}"] = {
-            "model": label,
-            "ops": int(sum(prims.values())),
-            "geometry": {k: gf[k] for k in GEOMETRY_KEYS},
-            "jax": jax.__version__,
-        }
+    loop, args = _lower_era(tm, g)
+    closed, _ = _trace(loop, args)
+    prims, _dt = count_ops(closed)
+    written[f"tpu_bfs|{sig}"] = {
+        "model": label,
+        "ops": int(sum(prims.values())),
+        "geometry": {k: g[k] for k in GEOMETRY_KEYS},
+        "jax": jax.__version__,
+    }
 
-        sgf = dict(sg, fuse=fuse)
-        fn, fargs = _lower_sharded(tm, sgf)
-        closed, _ = _trace(fn, fargs)
-        prims, _dt = count_ops(closed)
-        written[f"{_engine_key('sharded', fuse)}|{sig}"] = {
-            "model": label,
-            "ops": int(sum(prims.values())),
-            "geometry": dict(sgf),
-            "jax": jax.__version__,
-        }
+    fn, fargs = _lower_sharded(tm, sg)
+    closed, _ = _trace(fn, fargs)
+    prims, _dt = count_ops(closed)
+    written[f"sharded|{sig}"] = {
+        "model": label,
+        "ops": int(sum(prims.values())),
+        "geometry": dict(sg),
+        "jax": jax.__version__,
+    }
 
     entries.update(written)
     doc["entries"] = dict(sorted(entries.items()))

@@ -64,8 +64,6 @@ class CheckerBuilder:
         self.flight_format_: str = "jsonl"
         self.memory_: bool = True
         self.pipeline_: bool = True
-        self.pipeline_depth_: Optional[int] = None  # None = auto (2)
-        self.fuse_eras_: Optional[int] = None  # None/1 = no multi-era fusion
         self.sample_: bool = True
         self.sample_k_: int = 64  # obs/sample.py DEFAULT_SAMPLE_K
 
@@ -140,7 +138,7 @@ class CheckerBuilder:
         evaluation/hit counts, and dead-action detection, surfaced via
         `Checker.coverage()`. On by default; device engines fold the
         histograms into their era loops, so disabling buys back only a
-        few percent of throughput (bench.py records both numbers)."""
+        few percent of throughput."""
         self.coverage_ = enable
         return self
 
@@ -155,10 +153,10 @@ class CheckerBuilder:
         a bounded ring of per-era records — wall time split into
         ``device_era`` vs ``host_gap`` (the dispatch gap), states/frontier/
         table counters — populated from the packed-params readback the
-        device engines already do once per era (zero extra round-trips;
-        <2% overhead, asserted by bench.py). On by default with a
-        `capacity`-record ring; `Checker.flight()` returns the records
-        and ``telemetry()["flight"]`` the summary. `path` additionally
+        device engines already do once per era (zero extra round-trips).
+        On by default with a `capacity`-record ring; `Checker.flight()`
+        returns the records and ``telemetry()["flight"]`` the summary.
+        `path` additionally
         exports the recording at run end — JSONL (`format="jsonl"`) or a
         standalone Chrome counter-track trace (`format="chrome"`); a run
         traced with ``.trace(p, format="chrome")`` also gets the counter
@@ -181,9 +179,8 @@ class CheckerBuilder:
         staging) plus the per-era growth forecaster that projects
         eras-to-grow / eras-to-exhaustion and fires a one-shot pressure
         warning. On by default; the records ride the flight recorder's
-        existing once-per-era readback (zero extra device round-trips,
-        <1% overhead asserted by bench.py). Surfaced via
-        ``telemetry()["memory"]``, ``memory_bytes{component=...}``
+        existing once-per-era readback (zero extra device round-trips).
+        Surfaced via ``telemetry()["memory"]``, ``memory_bytes{component=...}``
         Prometheus gauges, and the Explorer's ``GET /memory``."""
         self.memory_ = enable
         return self
@@ -195,9 +192,8 @@ class CheckerBuilder:
         smallest seen, so the sample set is a pure function of the
         explored set — identical across engines (host bfs == tpu_bfs ==
         sharded mesh, locked by tests), visitation orders, shard
-        layouts, and pipelining. On by default at small k (<2% overhead
-        on the device engines, asserted by bench.py; candidates ride
-        the existing once-per-era packed-params readback). Surfaced via
+        layouts, and pipelining. On by default at small k (candidates
+        ride the existing once-per-era packed-params readback). Surfaced via
         `Checker.space_profile()` (field-distribution sketches, depth/
         action exemplars, packing-saturation warnings),
         ``telemetry()["space"]``, flat ``space_*`` gauges, and the
@@ -246,12 +242,7 @@ class CheckerBuilder:
         self.span_parent_id_ = parent_id
         return self
 
-    def pipeline(
-        self,
-        enable: bool = True,
-        depth: Optional[int] = None,
-        fuse: Optional[int] = None,
-    ) -> "CheckerBuilder":
+    def pipeline(self, enable: bool = True) -> "CheckerBuilder":
         """Speculative era pipelining on the device engines (default ON).
 
         While era N's packed-params readback is still in flight, the
@@ -264,32 +255,12 @@ class CheckerBuilder:
         serial dispatch -> readback -> dispatch driver (useful when
         bisecting timing-sensitive telemetry).
 
-        ``depth`` bounds the speculative in-flight chain: up to that many
-        era dispatches are kept queued beyond the one being consumed,
-        each with a non-blocking readback queued behind it (``None`` =
-        auto, currently 2; ``1`` reproduces the original depth-1
-        speculation). The host consumes readbacks strictly in order and
-        peeks ``P_STEPS`` to tell consumed work from wasted speculation.
-
-        ``fuse`` rolls that many eras into ONE compiled device program
-        (an inner loop around the era body that continues only on pure
-        budget exits), so one dispatch+readback can retire up to ``fuse``
-        eras. ``None``/``1`` = no fusion. The packed params grow
-        per-inner-era flight-record lanes, and the driver auto-degrades
-        a dispatch to one era whenever per-era host work is pending
-        (spill backlog, checkpoint cadence nearly due, state-count
-        targets, timeouts)."""
+        The in-flight chain holds up to two era dispatches beyond the one
+        being consumed (``CHAIN_DEPTH`` in each driver), each with a
+        non-blocking readback queued behind it. The host consumes
+        readbacks strictly in order and peeks ``P_STEPS`` to tell
+        consumed work from wasted speculation."""
         self.pipeline_ = bool(enable)
-        if depth is not None:
-            depth = int(depth)
-            if depth < 1:
-                raise ValueError(f"pipeline depth must be >= 1, got {depth}")
-        self.pipeline_depth_ = depth
-        if fuse is not None:
-            fuse = int(fuse)
-            if fuse < 1:
-                raise ValueError(f"pipeline fuse must be >= 1, got {fuse}")
-        self.fuse_eras_ = fuse
         return self
 
     # -- static analysis (speclint; stateright_tpu.analysis) -----------------

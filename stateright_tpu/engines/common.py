@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 from ..checker import Checker, CheckerBuilder
 from ..core import Expectation
@@ -24,14 +24,6 @@ from ..obs.metrics import MetricsRegistry
 from ..obs.trace import make_trace_writer, start_profile, stop_profile
 
 BLOCK_SIZE = 1500  # states per finish_when re-check; reference bfs.rs:130
-
-# Auto-N fusion (ISSUE 20): `_fuse_auto_n` needs this much flight history
-# before trusting host_gap_pct, re-evaluates at this era cadence, and
-# halves the factor only when the gap is already below this share of the
-# wall clock (fusion has nothing left to amortize there).
-FUSE_AUTO_MIN_ERAS = 8
-FUSE_AUTO_RECHECK_ERAS = 8
-FUSE_AUTO_LOW_GAP_PCT = 2.0
 
 _log = get_logger("engines.common")
 
@@ -481,7 +473,6 @@ class HostEngineBase(Checker):
         spill_rows: int = 0,
         shards: Optional[Dict[str, Any]] = None,
         grow_rows: Optional[int] = None,
-        inner: Optional[List[Dict[str, Any]]] = None,
     ) -> None:
         """Append one era to the flight recording (no-op when disabled).
         Registry counters that move off the hot path (refill/grow/
@@ -489,11 +480,7 @@ class HostEngineBase(Checker):
         don't have to thread per-era volumes through their loops.
         ``grow_rows`` is what the engine's table-grow trigger compares
         (max per-shard unique on the mesh); the memory forecaster fits
-        its growth curve to it, defaulting to ``unique``. ``inner`` is
-        the per-inner-era attribution of a FUSED dispatch (fields per
-        FlightRecorder.record_fused): the one readback then appends
-        len(inner) records, with the once-per-dispatch counters on the
-        last."""
+        its growth curve to it, defaulting to ``unique``."""
         mem = None
         if self._memory is not None:
             mem = self._memory.on_era(
@@ -508,77 +495,31 @@ class HostEngineBase(Checker):
         }
         prev = self._flight_prev_counters
         self._flight_prev_counters = cur
-        if inner is not None and len(inner) > 1:
-            rec = fr.record_fused(
-                device_era_secs=device_era_secs,
-                inner=inner,
-                take_cap=take_cap,
-                spill_rows=spill_rows,
-                refill_rows=cur["refill_rows"] - prev.get("refill_rows", 0),
-                table_growths=(
-                    cur["table_growths"] - prev.get("table_growths", 0)
-                ),
-                checkpoint_saves=(
-                    cur["checkpoint_saves"]
-                    - prev.get("checkpoint_saves", 0)
-                ),
-                shards=shards,
-                memory=mem,
-            )
-        else:
-            rec = fr.record(
-                device_era_secs=device_era_secs,
-                steps=steps,
-                generated=generated,
-                unique=unique,
-                frontier=frontier,
-                load_factor=load_factor,
-                take_cap=take_cap,
-                spill_rows=spill_rows,
-                refill_rows=cur["refill_rows"] - prev.get("refill_rows", 0),
-                table_growths=(
-                    cur["table_growths"] - prev.get("table_growths", 0)
-                ),
-                checkpoint_saves=(
-                    cur["checkpoint_saves"]
-                    - prev.get("checkpoint_saves", 0)
-                ),
-                shards=shards,
-                memory=mem,
-            )
+        rec = fr.record(
+            device_era_secs=device_era_secs,
+            steps=steps,
+            generated=generated,
+            unique=unique,
+            frontier=frontier,
+            load_factor=load_factor,
+            take_cap=take_cap,
+            spill_rows=spill_rows,
+            refill_rows=cur["refill_rows"] - prev.get("refill_rows", 0),
+            table_growths=(
+                cur["table_growths"] - prev.get("table_growths", 0)
+            ),
+            checkpoint_saves=(
+                cur["checkpoint_saves"] - prev.get("checkpoint_saves", 0)
+            ),
+            shards=shards,
+            memory=mem,
+        )
         # Flat twins of the latest record for Prometheus (nested dicts are
         # skipped by render_prometheus) and the SSE metrics deltas.
         m = self._metrics
         m.set_gauge("flight_eras", rec["era"])
         m.set_gauge("flight_device_era_secs", rec["device_era_secs"])
         m.set_gauge("flight_host_gap_secs", rec["host_gap_secs"])
-
-    def _fuse_auto_n(self, fuse: int) -> int:
-        """Auto-N fusion pick (ISSUE 20 satellite of ROADMAP item 1a):
-        instead of pinning the compiled maximum, choose the inner-era cap
-        from recent flight history. A high host gap means the dispatch
-        gap dominates — run the full factor; a near-zero gap means fusion
-        has little left to amortize, so halve the exposure to mid-dispatch
-        overshoot (never below 2: one compiled program serves every N and
-        a degrade-to-1 already has its own triggers in `_fuse_lim_now`).
-        Recomputed every FUSE_AUTO_RECHECK_ERAS eras — `summary()` walks
-        the whole recording. The chosen N lands on the `fuse_auto_n`
-        gauge (gate-tracked in bench history)."""
-        eras = self._metrics.get("eras")
-        cached = getattr(self, "_fuse_auto_cache", None)
-        if cached is not None and eras - cached[0] < FUSE_AUTO_RECHECK_ERAS:
-            return cached[1]
-        n = fuse
-        fr = self._flight
-        if fr is not None:
-            s = fr.summary()
-            if s.get("eras", 0) >= FUSE_AUTO_MIN_ERAS:
-                gap = float(s.get("host_gap_pct") or 0.0)
-                if gap < FUSE_AUTO_LOW_GAP_PCT:
-                    n = max(2, fuse // 2)
-        self._fuse_auto_cache = (eras, n)
-        self._metrics.set_gauge("fuse_auto_n", n)
-        return n
 
     def _action_label(self, action: Any) -> str:
         """Memoized model.format_action — hot-loop action attribution must

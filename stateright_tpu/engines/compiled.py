@@ -134,7 +134,6 @@ def era_geometry(model: Any, options: Optional[Dict[str, Any]] = None) -> Dict[s
     )
     cov = bool(options.get("coverage", True))
     sample_k = int(options.get("sample_k", DEFAULT_SAMPLE_K))
-    fuse = max(1, int(options.get("fuse_eras", 1)))
     n_init = len(tm.init_states_array())
     vcap = _vcap(tm.max_actions, chunk)
     while n_init + vcap > vs.MAX_LOAD * tcap:
@@ -145,7 +144,6 @@ def era_geometry(model: Any, options: Optional[Dict[str, Any]] = None) -> Dict[s
         "tcap": tcap,
         "cov": cov,
         "sample_k": sample_k,
-        "fuse": fuse,
         "n_init": n_init,
     }
 
@@ -188,14 +186,10 @@ class CompiledCheck:
             # run actually compiles.
             g = era_geometry(tm, self.options)
             chunk, qcap, tcap = g["chunk"], g["qcap"], g["tcap"]
-            cov, sample_k, fuse = g["cov"], g["sample_k"], g["fuse"]
-            _build_loop(
-                tm, props, chunk, qcap, False, cov, sample_k=sample_k,
-                fuse=fuse,
-            )
+            cov, sample_k = g["cov"], g["sample_k"]
+            _build_loop(tm, props, chunk, qcap, False, cov, sample_k=sample_k)
             _build_seed_loop(
-                tm, props, chunk, qcap, tcap, False, cov, sample_k=sample_k,
-                fuse=fuse,
+                tm, props, chunk, qcap, tcap, False, cov, sample_k=sample_k
             )
         elif self.engine == "multiplex":
             from .multiplex import warm_lane_program

@@ -152,6 +152,17 @@ _COV_LO = 8
 # seed the host begins wall-clock-polled runs at.
 BUDGET_MIN = 64
 
+# Speculative chain depth: one extra era of overlap beyond a single
+# chained era covers the readback+bookkeeping gap; deeper chains only pay
+# off when host work per era exceeds a full device era (rare), while every
+# extra in-flight era grows the wasted-work window on host-intervention
+# exits.
+CHAIN_DEPTH = 2
+
+# Divisor of the compacted candidate width (`_vcap`): one step keeps
+# C*A/VCAP_DIV valid candidates, floored at 128*A.
+VCAP_DIV = 3
+
 
 def _cov_len(A: int, P: int) -> int:
     from ..obs.coverage import DEPTH_CAP
@@ -171,26 +182,15 @@ def _vcap(A: int, chunk: int) -> int:
     grow_limit / pre-growth headroom from it — all sites must use this
     one definition.
     """
-    div = int(os.environ.get("STPU_VCAP_DIV", "3"))
-    return min(chunk * A, max(128 * A, (chunk * A) // div))
-
-
-def fuse_tail_len(fuse: int) -> int:
-    """Words of the multi-era fusion tail appended to the packed params
-    when ``fuse > 1``: ``[fuse_lim, n_inner]`` followed by the
-    per-inner-era flight-record lanes ``steps[fuse] | gen[fuse] |
-    unique[fuse] | frontier[fuse]``. ``fuse <= 1`` compiles the classic
-    single-era program with NO tail, so every existing layout consumer
-    (checkpoint codec, lint, multiplex) is untouched by default."""
-    return 2 + 4 * fuse if fuse > 1 else 0
+    return min(chunk * A, max(128 * A, (chunk * A) // VCAP_DIV))
 
 
 def params_len(A: int, P: int, cov: bool, sample_k: int,
-               fuse: int = 1, canon: bool = False) -> int:
+               canon: bool = False) -> int:
     """Length of the packed uint32 params vector the era loop carries:
     scalars + rec_fp tail + optional coverage tail + optional sampling
-    tail + optional multi-era fusion tail + optional symmetry word (the
-    dispatch's `canon_rewrites`, last). This is THE layout contract —
+    tail + optional symmetry word (the dispatch's `canon_rewrites`,
+    last). This is THE layout contract —
     the engine, the checkpoint codec, and the STR6xx program lint all
     size their buffers from it."""
     n = P_LEN + 2 * P
@@ -200,12 +200,12 @@ def params_len(A: int, P: int, cov: bool, sample_k: int,
         from ..obs.sample import slab_entries
 
         n += 4 + 5 * slab_entries(sample_k)
-    return n + fuse_tail_len(fuse) + int(canon)
+    return n + int(canon)
 
 
 def loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
                        tcap: int, cov: bool, sample_k: int,
-                       fuse: int = 1, canon: bool = False):
+                       canon: bool = False):
     """`jax.ShapeDtypeStruct` pytree matching `_build_loop`'s signature
     `(table, queue, rec_fp1, rec_fp2, params)` — lets the STR6xx program
     lint (analysis/program.py) trace/lower the era loop WITHOUT
@@ -218,13 +218,13 @@ def loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
     sds = jax.ShapeDtypeStruct
     table = (sds((2 * tcap,), u32), sds((tcap,), u32), sds((tcap,), u32))
     queue = tuple(sds((qcap,), u32) for _ in range(S + 2))
-    plen = params_len(A, P, cov, sample_k, fuse, canon)
+    plen = params_len(A, P, cov, sample_k, canon)
     return (table, queue, sds((P,), u32), sds((P,), u32), sds((plen,), u32))
 
 
 def seed_loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
                             tcap: int, cov: bool, sample_k: int,
-                            n_init: int, fuse: int = 1):
+                            n_init: int):
     """Abstract args for `_build_seed_loop`'s fused
     `seed_run(qinit, h1, h2, params, rec_fp1, rec_fp2)` dispatch."""
     import jax
@@ -234,7 +234,7 @@ def seed_loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
     u32 = jnp.uint32
     sds = jax.ShapeDtypeStruct
     n_init = max(1, n_init)
-    plen = params_len(A, P, cov, sample_k, fuse)
+    plen = params_len(A, P, cov, sample_k)
     return (
         sds((S + 2, n_init), u32),
         sds((n_init,), u32),
@@ -246,8 +246,7 @@ def seed_loop_abstract_args(tm: TensorModel, props, chunk: int, qcap: int,
 
 
 def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = False,
-                cov: bool = True, raw: bool = False, sample_k: int = 0,
-                fuse: int = 1):
+                cov: bool = True, raw: bool = False, sample_k: int = 0):
     """Compile the BFS device "era" loop.
 
     Returns a jitted function
@@ -278,20 +277,6 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
     tail, so the drain rides the existing once-per-era readback with
     ZERO extra round-trips. The host applies the exact 64-bit tie cut.
 
-    With ``fuse > 1`` up to that many ERAS run inside one compiled
-    program: an outer `lax.while_loop` re-enters the era body while the
-    previous inner era exited on a PURE step-budget boundary (every
-    device-visible trigger — spill high-water, grow limit, finish
-    policy, probe error, empty frontier, sample-slab high-water — ends
-    the fused dispatch so the host can act). The runtime fusion limit
-    rides the params fusion tail (``fuse_lim``, pass-through), so one
-    compiled program serves every degraded value down to 1, and the
-    tail reports which inner era tripped plus per-inner-era
-    steps/generated/unique/frontier lanes for exact flight records.
-    Coverage and the sampling slab accumulate ACROSS inner eras (both
-    are additive deltas drained once per readback), so one fused
-    readback is indistinguishable from the sum of its serial eras.
-
     With ``canon`` (symmetry reduction) every compacted candidate is
     replaced by its representative, and the params end in one more word:
     the dispatch's count of valid candidates the representative changed
@@ -307,8 +292,7 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
     speculative chained dispatches. On CPU both donation sets resolve
     empty and ONE jitted object serves both slots (no double compile).
     """
-    fuse = max(1, int(fuse))
-    key = (id(tm), chunk, qcap, len(props), canon, cov, raw, sample_k, fuse)
+    key = (id(tm), chunk, qcap, len(props), canon, cov, raw, sample_k)
     cached = _LOOP_CACHE.get(key)
     if cached is not None and cached[0] is tm:
         return cached[1]
@@ -363,8 +347,6 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
     # harmlessly retain duplicates) stay rare, and the scratch stays small
     # enough to be cache-hot.
     dedup_cap = 1 << max(1, (4 * vcap - 1).bit_length())
-    # Absolute offset of the fusion tail (== the fuse-free params length).
-    f_base = params_len(A, P, cov, sample_k)
 
     def loop(table, queue, rec_fp1, rec_fp2, params):
         u = jnp.uint32
@@ -759,227 +741,121 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
         )
         canc0 = (u(0),) if canon else ()  # canon_rewrites of the dispatch
 
-        def run_era(table, queue, head0, count0, unique0, rec_bits,
-                    max_steps, err0, take_cap0, covc, sampc, canc,
-                    rec_fp1, rec_fp2):
-            # ONE era: the data-dependent while_loop plus its once-per-era
-            # epilogue. Factored so the multi-era fusion path below can
-            # chain N of these inside an outer device loop — coverage and
-            # the sampling slab THREAD through (both are additive deltas /
-            # persistent occupancy, drained once per readback), while the
-            # fingerprint snapshot lanes reset per era (rec_bits threading
-            # keeps first-discovery-wins across eras, matching the host
-            # ordering of the serial driver).
-            init = (
-                table,
-                queue,
-                head0,
-                count0,
-                unique0,
-                u(0),  # generated delta
-                u(0),  # steps executed
-                err0,  # unresolved-insert count (gates the era closed;
-                # nonzero input = a seeding-time error surfacing on first
-                # read)
-                jnp.minimum(jnp.maximum(take_cap0, u(1)), u(chunk)),
-                rec_bits,  # scalar discovery bits accumulated for fin gate
-                tuple(false_lane for _ in range(P)),
-                tuple(zero_lane for _ in range(P)),
-                tuple(zero_lane for _ in range(P)),
-                tuple(zero_lane for _ in range(P)),
-                covc,
-                sampc,
-                canc,
-                max_steps,
-            )
-            (
-                table,
-                queue,
-                head,
-                count,
-                unique,
-                gen,
-                steps,
-                err_cnt,
-                take_cap_out,
-                _rec_acc,
-                hseen,
-                facc1,
-                facc2,
-                faccd,
-                covc_out,
-                sampc_out,
-                canc_out,
-                _ms,
-            ) = lax.while_loop(cond, body, init)
+        # The era: the data-dependent while_loop plus its once-per-era
+        # epilogue. The fingerprint snapshot lanes start empty; rec_bits
+        # keeps first-discovery-wins across eras, matching the host
+        # ordering of the serial driver.
+        err0 = params[P_ERR]
+        take_cap0 = params[P_TAKE_CAP]
+        init = (
+            table,
+            queue,
+            head0,
+            count0,
+            unique0,
+            u(0),  # generated delta
+            u(0),  # steps executed
+            err0,  # unresolved-insert count (gates the era closed;
+            # nonzero input = a seeding-time error surfacing on first
+            # read)
+            jnp.minimum(jnp.maximum(take_cap0, u(1)), u(chunk)),
+            rec_bits0,  # scalar discovery bits accumulated for fin gate
+            tuple(false_lane for _ in range(P)),
+            tuple(zero_lane for _ in range(P)),
+            tuple(zero_lane for _ in range(P)),
+            tuple(zero_lane for _ in range(P)),
+            covc0,
+            sampc0,
+            canc0,
+            max_steps0,
+        )
+        (
+            table,
+            queue,
+            head,
+            count,
+            unique,
+            gen,
+            steps,
+            err_cnt,
+            take_cap_out,
+            _rec_acc,
+            hseen,
+            facc1,
+            facc2,
+            faccd,
+            covc_out,
+            sampc_out,
+            canc_out,
+            _ms,
+        ) = lax.while_loop(cond, body, init)
 
-            # Era-level epilogue (runs ONCE per era, outside the step loop,
-            # where argmax / dynamic gathers are cheap): extract discovery
-            # fingerprints from the snapshots and the max depth from the
-            # ring. Depth along the ring is non-decreasing, so the deepest
-            # state visited is the last one popped, at ring slot head-1.
-            # Under fusion this executes per INNER era — still bounded by
-            # the fusion factor, not the step count, so the platform rule
-            # (reductions only at era granularity) holds.
-            with stage_scope("epilogue"):
-                rec_bits_out = rec_bits
-                for i in range(P):
-                    found = jnp.any(hseen[i])
-                    # Select the SHALLOWEST snapshot hit, not an arbitrary
-                    # one: BFS must report a shortest counterexample even when
-                    # later, deeper iterations hit the property at other chunk
-                    # positions.
-                    sel = jnp.argmin(
-                        jnp.where(hseen[i], faccd[i], u(0xFFFFFFFF))
-                    )
-                    take_new = found & (((rec_bits_out >> u(i)) & u(1)) == u(0))
-                    rec_fp1 = rec_fp1.at[i].set(
-                        jnp.where(take_new, facc1[i][sel], rec_fp1[i])
-                    )
-                    rec_fp2 = rec_fp2.at[i].set(
-                        jnp.where(take_new, facc2[i][sel], rec_fp2[i])
-                    )
-                    rec_bits_out = rec_bits_out | (found.astype(u) << u(i))
-                maxd = jnp.where(
-                    steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
-                )
-                # Adaptive era budget (device-side emission): the NEXT era's
-                # step budget rides the P_MAX_STEPS output slot, so a chained
-                # (speculative) dispatch — or the next INNER era of a fused
-                # dispatch — follows the exact deterministic schedule the
-                # serial driver would. TCP-slow-start shape: double after an
-                # era that exhausted its budget with no other exit reason
-                # pending, halve under spill/grow pressure, floor at
-                # BUDGET_MIN, clamp at budget_cap. budget_cap == 0 turns the
-                # emission off (pure pass-through — free-running and
-                # target-bounded runs keep their fixed budgets). The host's
-                # wall-clock cap keeps checkpoint cadence and reporter updates
-                # honest (see the engine driver).
-                fin_hit_final = ((rec_bits_out & fin_any) != u(0)) | (
-                    (fin_all_en != u(0)) & ((rec_bits_out & fin_all) == fin_all)
-                )
-                pressure = (count > high_water) | (unique > grow_limit)
-                budget_only = (
-                    (steps >= max_steps)
-                    & (count > u(0))
-                    & ~pressure
-                    & (err_cnt == u(0))
-                    & ~fin_hit_final
-                )
-                # In adaptive mode max_steps <= budget_cap <= 2^30 always
-                # (host clamp), so the doubling cannot overflow uint32.
-                grown = jnp.minimum(
-                    jnp.maximum(max_steps, u(1)) * u(2), budget_cap
-                )
-                shrunk = jnp.maximum(
-                    jnp.minimum(max_steps, budget_cap) >> u(1), u(BUDGET_MIN)
-                )
-                next_budget = jnp.where(
-                    budget_cap == u(0),
-                    max_steps,
-                    jnp.where(
-                        pressure, shrunk,
-                        jnp.where(budget_only, grown, max_steps),
-                    ),
-                )
-            return (table, queue, head, count, unique, rec_bits_out,
-                    err_cnt, take_cap_out, covc_out, sampc_out, canc_out,
-                    rec_fp1, rec_fp2, steps, gen, maxd, next_budget,
-                    budget_only)
-
-        if fuse == 1:
-            # Classic single-era program: no outer loop, no fusion tail —
-            # bit-identical lowering to the pre-fusion build.
-            (
-                table, queue, head, count, unique, rec_bits_out, err_cnt,
-                take_cap_out, covc_out, sampc_out, canc_out, rec_fp1,
-                rec_fp2, steps, gen, maxd, next_budget, _budget_only,
-            ) = run_era(
-                table, queue, head0, count0, unique0, rec_bits0,
-                max_steps0, params[P_ERR], params[P_TAKE_CAP],
-                covc0, sampc0, canc0, rec_fp1, rec_fp2,
-            )
-            ftail = []
-        else:
-            # Multi-era fusion: chain up to fuse_lim eras inside ONE
-            # compiled program. The continuation gate re-derives the
-            # serial driver's re-dispatch decision ON DEVICE: an inner
-            # era chains iff its ONLY exit reason was budget exhaustion
-            # with work remaining (budget_only — no spill/grow pressure,
-            # no error, no finish hit, frontier nonempty) and the sample
-            # slab still has a full era of headroom. Every other exit
-            # needs host work, so the loop stops and the readback reports
-            # which inner era tripped (n_inner) plus per-inner-era
-            # steps/generated/unique/frontier lanes for exact flight
-            # records. fuse_lim rides the params tail (clamped to
-            # [1, fuse]), so the host degrades fusion at dispatch time —
-            # checkpoint cadence due, spill backlog, targets — without a
-            # recompile.
-            fuse_lim = jnp.minimum(
-                jnp.maximum(params[f_base], u(1)), u(fuse)
-            )
-            fzero = jnp.zeros(fuse, dtype=jnp.uint32)
-
-            def ocond(oc):
-                k, cont = oc[0], oc[1]
-                return (k < fuse_lim) & (cont != u(0))
-
-            def obody(oc):
-                (
-                    k, _cont, steps_acc, gen_acc, maxd_acc,
-                    fsteps, fgen, funiq, fcount,
-                    table, queue, head, count, unique, rec_bits, ms,
-                    err, tc, covc, sampc, canc, rfp1, rfp2,
-                ) = oc
-                uniq_in = unique
-                (
-                    table, queue, head, count, unique, rec_bits, err, tc,
-                    covc, sampc, canc, rfp1, rfp2, steps, gen, maxd,
-                    next_budget, budget_only,
-                ) = run_era(
-                    table, queue, head, count, unique, rec_bits, ms,
-                    err, tc, covc, sampc, canc, rfp1, rfp2,
-                )
-                cont = budget_only.astype(u)
-                if sample_k:
-                    # One more era adds at most an era's worth of slab
-                    # entries; stop while the host-drain high-water mark
-                    # still guarantees no overflow.
-                    cont = cont & (sampc[4] <= u(s_high)).astype(u)
-                return (
-                    k + u(1), cont, steps_acc + steps, gen_acc + gen,
-                    jnp.maximum(maxd_acc, maxd),
-                    fsteps.at[k].set(steps), fgen.at[k].set(gen),
-                    funiq.at[k].set(unique - uniq_in),
-                    fcount.at[k].set(count),
-                    table, queue, head, count, unique, rec_bits,
-                    next_budget, err, tc, covc, sampc, canc, rfp1, rfp2,
-                )
-
-            oinit = (
-                u(0), u(1), u(0), u(0), u(0),
-                fzero, fzero, fzero, fzero,
-                table, queue, head0, count0, unique0, rec_bits0,
-                max_steps0, params[P_ERR], params[P_TAKE_CAP],
-                covc0, sampc0, canc0, rec_fp1, rec_fp2,
-            )
-            (
-                k_out, _cont, steps, gen, maxd,
-                fsteps, fgen, funiq, fcount,
-                table, queue, head, count, unique, rec_bits_out,
-                next_budget, err_cnt, take_cap_out, covc_out, sampc_out,
-                canc_out, rec_fp1, rec_fp2,
-            ) = lax.while_loop(ocond, obody, oinit)
-            # Fusion tail: [fuse_lim (pass-through), n_inner] +
-            # steps[fuse] | generated[fuse] | unique[fuse] |
-            # frontier[fuse] — the host splits the one readback into
-            # n_inner exact flight records.
-            ftail = [
-                jnp.stack([fuse_lim, k_out]),
-                fsteps, fgen, funiq, fcount,
-            ]
-
+        # Era-level epilogue (runs ONCE per era, outside the step loop,
+        # where argmax / dynamic gathers are cheap): extract discovery
+        # fingerprints from the snapshots and the max depth from the
+        # ring. Depth along the ring is non-decreasing, so the deepest
+        # state visited is the last one popped, at ring slot head-1.
         with stage_scope("epilogue"):
+            rec_bits_out = rec_bits0
+            for i in range(P):
+                found = jnp.any(hseen[i])
+                # Select the SHALLOWEST snapshot hit, not an arbitrary
+                # one: BFS must report a shortest counterexample even when
+                # later, deeper iterations hit the property at other chunk
+                # positions.
+                sel = jnp.argmin(
+                    jnp.where(hseen[i], faccd[i], u(0xFFFFFFFF))
+                )
+                take_new = found & (((rec_bits_out >> u(i)) & u(1)) == u(0))
+                rec_fp1 = rec_fp1.at[i].set(
+                    jnp.where(take_new, facc1[i][sel], rec_fp1[i])
+                )
+                rec_fp2 = rec_fp2.at[i].set(
+                    jnp.where(take_new, facc2[i][sel], rec_fp2[i])
+                )
+                rec_bits_out = rec_bits_out | (found.astype(u) << u(i))
+            maxd = jnp.where(
+                steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
+            )
+            # Adaptive era budget (device-side emission): the NEXT era's
+            # step budget rides the P_MAX_STEPS output slot, so a chained
+            # (speculative) dispatch follows the exact deterministic
+            # schedule the serial driver would. TCP-slow-start shape:
+            # double after an era that exhausted its budget with no other
+            # exit reason pending, halve under spill/grow pressure, floor at
+            # BUDGET_MIN, clamp at budget_cap. budget_cap == 0 turns the
+            # emission off (pure pass-through — free-running and
+            # target-bounded runs keep their fixed budgets). The host's
+            # wall-clock cap keeps checkpoint cadence and reporter updates
+            # honest (see the engine driver).
+            fin_hit_final = ((rec_bits_out & fin_any) != u(0)) | (
+                (fin_all_en != u(0)) & ((rec_bits_out & fin_all) == fin_all)
+            )
+            pressure = (count > high_water) | (unique > grow_limit)
+            budget_only = (
+                (steps >= max_steps0)
+                & (count > u(0))
+                & ~pressure
+                & (err_cnt == u(0))
+                & ~fin_hit_final
+            )
+            # In adaptive mode max_steps <= budget_cap <= 2^30 always
+            # (host clamp), so the doubling cannot overflow uint32.
+            grown = jnp.minimum(
+                jnp.maximum(max_steps0, u(1)) * u(2), budget_cap
+            )
+            shrunk = jnp.maximum(
+                jnp.minimum(max_steps0, budget_cap) >> u(1), u(BUDGET_MIN)
+            )
+            next_budget = jnp.where(
+                budget_cap == u(0),
+                max_steps0,
+                jnp.where(
+                    pressure, shrunk,
+                    jnp.where(budget_only, grown, max_steps0),
+                ),
+            )
+
             parts = [
                 jnp.stack(
                     [
@@ -1037,7 +913,6 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                     sact[:scap][topi],
                     used[topi].astype(u),
                 ]
-            parts += ftail
             if canon:
                 parts.append(canc_out[0][None])  # canon_rewrites, last
             params_out = jnp.concatenate(parts)
@@ -1078,21 +953,16 @@ _SEED_LOOP_CACHE: Dict[Tuple, Tuple[TensorModel, Any]] = {}
 
 
 def _build_seed_loop(tm: TensorModel, props, chunk: int, qcap: int, tcap: int,
-                     canon: bool, cov: bool, sample_k: int = 0,
-                     fuse: int = 1):
+                     canon: bool, cov: bool, sample_k: int = 0):
     """Fuse run seeding and the FIRST era into one jitted dispatch.
 
     Every dispatch is a host round-trip, and time-to-first-counterexample
     is a primary metric (BASELINE.md): a bug a few steps deep should cost
     ONE round-trip, not a seed trip plus an era trip. The composed program
-    inlines the raw seeder and era loop (at the engine's fusion factor —
-    the seeding dispatch fuses its trailing eras exactly like a
-    steady-state one); a run whose discovery fires in era 1 (or that
-    completes outright) never pays a second dispatch.
+    inlines the raw seeder and era loop; a run whose discovery fires in
+    era 1 (or that completes outright) never pays a second dispatch.
     """
-    fuse = max(1, int(fuse))
-    key = (id(tm), chunk, qcap, tcap, len(props), canon, cov, sample_k,
-           fuse)
+    key = (id(tm), chunk, qcap, tcap, len(props), canon, cov, sample_k)
     cached = _SEED_LOOP_CACHE.get(key)
     if cached is not None and cached[0] is tm:
         return cached[1]
@@ -1102,7 +972,7 @@ def _build_seed_loop(tm: TensorModel, props, chunk: int, qcap: int, tcap: int,
     import jax
 
     loop = _build_loop(tm, props, chunk, qcap, canon, cov, raw=True,
-                       sample_k=sample_k, fuse=fuse)
+                       sample_k=sample_k)
     seed = _build_seed(tm.state_width, qcap, tcap)
 
     @jax.jit
@@ -1333,22 +1203,12 @@ class TpuBfsChecker(HostEngineBase):
             # Bottom-k space sampling (obs/sample.py): the compiled loop
             # carries the capture slab only when the builder asked for it.
             self._sample_k = self._sampler.k if self._sampler is not None else 0
-            # Multi-era fusion factor (CheckerBuilder.pipeline(fuse=N)): the
-            # compiled program chains up to N eras on device per dispatch.
-            # The factor is part of the loop-cache / executable-cache key.
-            self._fuse = max(1, int(getattr(builder, "fuse_eras_", None) or 1))
             program = _build_loop(
                 self.tm, self._tprops, self._chunk, self._qcap, self._canon,
-                self._cov, sample_k=self._sample_k, fuse=self._fuse,
+                self._cov, sample_k=self._sample_k,
             )
             self._loop = program.serial
             self._loop_chain = program.chain
-            # Absolute params offset of the fusion tail (only present when
-            # fuse > 1); cached for splitting the era loop's readbacks.
-            self._fbase = params_len(
-                self.tm.max_actions, len(self._tprops), self._cov,
-                self._sample_k,
-            )
 
             # Host-side bookkeeping.
             self._unique = 0
@@ -1380,18 +1240,11 @@ class TpuBfsChecker(HostEngineBase):
             # engine.
             self._mux_lane = bool(getattr(builder, "multiplex_lane_", False))
             # Speculative era pipelining (CheckerBuilder.pipeline(), default
-            # on): keep up to K eras chained off the still-on-device state
-            # while their readbacks are in flight. See the _run driver for
-            # the soundness argument (chained dispatch is an identity no-op
-            # on every device-visible host-intervention exit).
+            # on): keep up to CHAIN_DEPTH eras chained off the on-device
+            # state while their readbacks are in flight. See the _run driver
+            # for the soundness argument (chained dispatch is an identity
+            # no-op on every device-visible host-intervention exit).
             self._pipeline = bool(getattr(builder, "pipeline_", True))
-            depth = getattr(builder, "pipeline_depth_", None)
-            # Auto depth 2: one extra era of overlap beyond a single chained
-            # era covers the readback+bookkeeping gap; deeper chains only pay off
-            # when host work per era exceeds a full device era (rare), while
-            # every extra in-flight era grows the wasted-work window on
-            # host-intervention exits.
-            self._chain_depth = max(1, int(depth)) if depth is not None else 2
             # High-water mark of in-flight chained dispatches (gauge).
             self._chain_max = 0
             # Small-workload guard: with a state-count target under the
@@ -1427,16 +1280,15 @@ class TpuBfsChecker(HostEngineBase):
         P = len(self._tprops)
         W = S + 2  # queue lanes: state | ebits | depth
         ncov = _cov_len(A, P) if self._cov else 0
-        # Sample tail sizing (obs/sample.py): [T1, T2, occupied, sdrop]
-        # plus five drained lanes of slab_entries(k) words each.
+        # Sample tail (obs/sample.py): [T1, T2, occupied, sdrop] plus
+        # five drained lanes of slab_entries(k) words each.
         if self._sample_k:
             from ..obs.sample import slab_entries
 
             sk2 = slab_entries(self._sample_k)
-            nsamp = 4 + 5 * sk2
             s_base = P_LEN + 2 * P + ncov
         else:
-            sk2 = nsamp = s_base = 0
+            sk2 = s_base = 0
         last_thresh = None  # threshold words last uploaded to the device
 
         depth_limit = (
@@ -1483,41 +1335,9 @@ class TpuBfsChecker(HostEngineBase):
         last_max_steps = None
         last_budget_cap = budget_cap
         take_cap = self._chunk
-        # Multi-era fusion: tail sizing and the per-dispatch degrade. The
-        # device chains up to fuse_lim eras per dispatch; the host shrinks
-        # fuse_lim to 1 (one compiled program serves every value — it
-        # rides the params tail) whenever a per-era host concern is near:
-        # spill backlog to refill, a state-count target to clamp, or a
-        # wall-clock cadence (checkpoint, timeout) past half-elapsed —
-        # fused eras can't poll mid-dispatch, so fusion backs off before
-        # it could overshoot a cadence rather than after.
-        nfuse = fuse_tail_len(self._fuse)
-        fb = self._fbase
-        last_fuse_lim = None
         # Symmetry word (canon_rewrites), last in the params.
         ncanon = int(self._canon)
-        c_off = fb + nfuse
-
-        def _fuse_lim_now() -> int:
-            if self._fuse <= 1:
-                return 1
-            if self._spill or self._target_state_count is not None:
-                return 1
-            now = time.monotonic()
-            if (
-                self._ckpt_every is not None
-                and now - self._last_ckpt >= self._ckpt_every / 2
-            ):
-                return 1
-            if (
-                self._deadline is not None
-                and now >= self._deadline - self._timeout / 2
-            ):
-                return 1
-            # Auto-N (engines/common.py): when the flight history shows
-            # the dispatch gap already amortized, back the factor off to
-            # keep the wasted-work window on host-intervention exits small.
-            return self._fuse_auto_n(self._fuse)
+        c_off = params_len(A, P, self._cov, self._sample_k)
 
         _dbg("run: encoding inits")
         with self._metrics.phase("run_setup"):
@@ -1594,13 +1414,7 @@ class TpuBfsChecker(HostEngineBase):
                     max_steps0 = max(
                         1, min(max_steps0, 1 + remaining // max(1, C * A))
                     )
-                template = np.zeros(
-                    P_LEN + 2 * P + ncov + nsamp + nfuse + ncanon,
-                    dtype=np.uint32,
-                )
-                if nfuse:
-                    last_fuse_lim = _fuse_lim_now()
-                    template[fb] = last_fuse_lim
+                template = np.zeros(c_off + ncanon, dtype=np.uint32)
                 if self._sampler is not None:
                     t1, t2 = self._sampler.threshold_parts()
                     template[s_base] = t1
@@ -1624,7 +1438,7 @@ class TpuBfsChecker(HostEngineBase):
                 _dbg("run: dispatching fused seed+first-era")
                 seed_run = _build_seed_loop(
                     tm, self._tprops, C, self._qcap, self._tcap, self._canon,
-                    self._cov, sample_k=self._sample_k, fuse=self._fuse,
+                    self._cov, sample_k=self._sample_k,
                 )
                 seed_args = (
                     jnp.asarray(qinit), jnp.asarray(h1), jnp.asarray(h2),
@@ -1685,15 +1499,9 @@ class TpuBfsChecker(HostEngineBase):
                 # percentiles for /stats and the Prometheus exposition.
                 self._metrics.observe("era_secs", era_dt)
                 self._era_t0 = None
-            # Fused dispatch: the readback covers n_inner on-device eras;
-            # the fusion tail carries which inner era tripped plus the
-            # per-inner-era lanes the flight records need.
-            n_inner = 1
-            if nfuse:
-                n_inner = max(1, min(int(vals[fb + 1]), self._fuse))
             _dbg(
                 f"era result steps={vals[10]} gen={vals[8]} count={vals[1]} "
-                f"unique={vals[2]} rec={vals[3]:b} inner={n_inner}"
+                f"unique={vals[2]} rec={vals[3]:b}"
             )
             err = int(vals[11])
             if not err and self._chaos_probe_error_era is not None and (
@@ -1734,15 +1542,11 @@ class TpuBfsChecker(HostEngineBase):
                 # Wall-clock cap feedback: let the device's slow-start
                 # climb only while eras stay well inside the polling
                 # cadence; back the cap off when an era overshoots it.
-                # Under fusion the dispatch covers n_inner eras — the
-                # feedback steers the PER-ERA budget, so compare the
-                # per-era share of the wall time.
-                per_era_dt = era_dt / n_inner
-                if per_era_dt < poll_target / 2 and budget_cap < cap_limit:
+                if era_dt < poll_target / 2 and budget_cap < cap_limit:
                     budget_cap = min(budget_cap * 2, cap_limit)
-                elif per_era_dt > poll_target and budget_cap > BUDGET_MIN:
+                elif era_dt > poll_target and budget_cap > BUDGET_MIN:
                     budget_cap = max(budget_cap // 2, BUDGET_MIN)
-            self._metrics.inc("eras", n_inner)
+            self._metrics.inc("eras")
             self._metrics.inc("steps", int(vals[10]))
             self._metrics.inc("states_generated", int(vals[8]))
             if ncanon:
@@ -1864,37 +1668,7 @@ class TpuBfsChecker(HostEngineBase):
 
             # Flight record after spill/checkpoint so this era's host work
             # lands in its own host_gap (zero extra device reads: every
-            # field is from `vals` or host clocks). A fused dispatch
-            # splits into one record per inner era from the tail lanes
-            # (steps/generated/unique-delta/frontier), keeping the
-            # recording exact: the wall/device identity holds across the
-            # group, and the per-era counters sum to the dispatch totals.
-            inner = None
-            if nfuse and n_inner > 1:
-                fsteps = vals[fb + 2 : fb + 2 + self._fuse]
-                fgen = vals[
-                    fb + 2 + self._fuse : fb + 2 + 2 * self._fuse
-                ]
-                funiq = vals[
-                    fb + 2 + 2 * self._fuse : fb + 2 + 3 * self._fuse
-                ]
-                fcount = vals[
-                    fb + 2 + 3 * self._fuse : fb + 2 + 4 * self._fuse
-                ]
-                u_before = self._unique - int(funiq[:n_inner].sum())
-                inner = []
-                uacc = u_before
-                for j in range(n_inner):
-                    uacc += int(funiq[j])
-                    inner.append(
-                        {
-                            "steps": int(fsteps[j]),
-                            "generated": int(fgen[j]),
-                            "unique": uacc,
-                            "frontier": int(fcount[j]),
-                            "load_factor": round(uacc / self._tcap, 4),
-                        }
-                    )
+            # field is from `vals` or host clocks).
             self._flight_record(
                 device_era_secs=era_dt,
                 steps=int(vals[10]),
@@ -1904,7 +1678,6 @@ class TpuBfsChecker(HostEngineBase):
                 load_factor=round(self._unique / self._tcap, 4),
                 take_cap=take_cap,
                 spill_rows=spilled,
-                inner=inner,
             )
 
             if self._finish_matched(self._discovery_fps):
@@ -2037,20 +1810,9 @@ class TpuBfsChecker(HostEngineBase):
                 max_steps = max(1, min(max_steps, 1 + remaining // max(1, C * A)))
             if max_steps != budget or budget_cap != last_budget_cap:
                 host_dirty = True
-            # Fusion degrade: a changed fuse_lim can only reach the device
-            # through an upload (the tail passes through otherwise).
-            fuse_lim = _fuse_lim_now()
-            if nfuse and fuse_lim != last_fuse_lim:
-                host_dirty = True
 
             if host_dirty:
-                arr = np.zeros(
-                    P_LEN + 2 * P + ncov + nsamp + nfuse + ncanon,
-                    dtype=np.uint32,
-                )
-                if nfuse:
-                    arr[fb] = fuse_lim
-                    last_fuse_lim = fuse_lim
+                arr = np.zeros(c_off + ncanon, dtype=np.uint32)
                 if self._sampler is not None:
                     t1, t2 = self._sampler.threshold_parts()
                     arr[s_base] = t1
@@ -2093,7 +1855,7 @@ class TpuBfsChecker(HostEngineBase):
                 f"block dirty={host_dirty} max_steps={max_steps} "
                 f"dispatch={time.monotonic() - _t0:.3f}s"
             )
-            # K-deep speculative chain (oldest first): chain[i] is the
+            # Speculative chain (oldest first, at most CHAIN_DEPTH): chain[i] is the
             # params output of the i-th era chained past the one whose
             # readback (params_dev) the host is about to consume;
             # chain_t0[i] its dispatch timestamp.
@@ -2107,7 +1869,7 @@ class TpuBfsChecker(HostEngineBase):
                     # ones already in flight.
                     while (
                         pipeline
-                        and len(chain) < self._chain_depth
+                        and len(chain) < CHAIN_DEPTH
                         and not self._spill
                         and not self._ckpt_stop.is_set()
                         and not self._timed_out()
@@ -2252,16 +2014,8 @@ class TpuBfsChecker(HostEngineBase):
         if self._unique < SMALL_WORKLOAD_STATES:
             self._small_workload_hint(self._unique, "explored")
 
-        # Mega-dispatch gauges: the deepest speculative chain reached and
-        # the realized fusion ratio (device eras per host dispatch — the
-        # dispatch-amortization headline, 1.0 when neither chaining nor
-        # fusion engaged).
+        # The deepest speculative chain reached.
         self._metrics.set_gauge("spec_chain_depth", self._chain_max)
-        n_disp = max(1, self._metrics.get("dispatches"))
-        self._metrics.set_gauge(
-            "fused_eras_per_dispatch",
-            round(self._metrics.get("eras") / n_disp, 3),
-        )
 
         # Retained (on device) for path reconstruction, which walks it there.
         self._table_dev = table
@@ -2277,8 +2031,6 @@ class TpuBfsChecker(HostEngineBase):
                 led.attach("packed_params", params_dev)
                 led.attach("coverage_slab", params_dev)
                 led.attach("sample_slab", params_dev)
-                if self._fuse > 1:
-                    led.attach("fusion_tail", params_dev)
         return
 
     def _on_spill_tier(self, direction, rows, nbytes, disk_bytes) -> None:
@@ -2349,7 +2101,6 @@ class TpuBfsChecker(HostEngineBase):
             table_capacity=self._tcap,
             coverage=self._cov,
             sample_k=self._sample_k,
-            fuse=self._fuse,
             canon=self._canon,
         )
         arrays = {
@@ -2360,8 +2111,6 @@ class TpuBfsChecker(HostEngineBase):
             "coverage_slab": params_dev,
             "sample_slab": params_dev,
         }
-        if self._fuse > 1:
-            arrays["fusion_tail"] = params_dev
         if self._canon:
             arrays["canon_tail"] = params_dev
         rec.register_components(sizes, arrays=arrays)

@@ -18,7 +18,7 @@ The load-bearing split is per-era wall time into
 
 ROADMAP item 1 claims the engines are dispatch/launch-bound, not
 bandwidth-bound; ``host_gap_secs`` is the direct per-era measurement of
-that claim, and the instrument any mega-era/dispatch-pipelining work
+that claim, and the instrument any dispatch-pipelining work
 must attribute its gains against. The accounting is OVERLAP-AWARE: with
 speculative era pipelining the engine reports each era's MARGINAL
 device time (readback-to-readback), but a clock skew or an engine that
@@ -29,9 +29,7 @@ clamping. By construction every record satisfies
     device_era_secs - overlap_secs + host_gap_secs == wall_secs
 
 (both the gap and the overlap are clamped at zero, exactly one of them
-is nonzero), and bench.py asserts the run-level
-``device - overlap + gap`` sum reconciles with the externally timed
-wall clock within 5%.
+is nonzero).
 
 One record per era::
 
@@ -172,90 +170,6 @@ class FlightRecorder:
             self._overlap_secs += overlap
             self._wall_secs += wall
             return rec
-
-    def record_fused(
-        self,
-        *,
-        device_era_secs,
-        inner,
-        take_cap=0,
-        spill_rows=0,
-        refill_rows=0,
-        table_growths=0,
-        checkpoint_saves=0,
-        shards=None,
-        memory=None,
-        t=None,
-    ):
-        """Split ONE fused dispatch into ``len(inner)`` consecutive era
-        records; returns the last record dict.
-
-        ``inner`` is the per-inner-era attribution the engine read from
-        the fusion tail — dicts with ``steps``/``generated``/``unique``/
-        ``frontier``/``load_factor``. The dispatch's wall window (since
-        the previous record) and its measured device time are
-        apportioned by each inner era's share of the executed steps
-        (evenly when no steps ran), with the LAST record pinned to the
-        true readback timestamp and the device remainder — so the
-        per-record ``device - overlap + gap == wall`` identity AND the
-        run-level totals stay exact: N fused records reconcile to the
-        same sums as N serial eras. Counter fields that happen once per
-        dispatch (spill/refill/growths/checkpoints, shards, memory) land
-        on the last record only, mirroring where the host work actually
-        sits.
-        """
-        n = len(inner)
-        if n <= 1:
-            r0 = dict(inner[0]) if inner else {}
-            return self.record(
-                device_era_secs=device_era_secs,
-                take_cap=take_cap,
-                spill_rows=spill_rows,
-                refill_rows=refill_rows,
-                table_growths=table_growths,
-                checkpoint_saves=checkpoint_saves,
-                shards=shards,
-                memory=memory,
-                t=t,
-                **r0,
-            )
-        now = time.monotonic() if t is None else float(t)
-        device = max(0.0, float(device_era_secs))
-        with self._lock:
-            t_prev = self._t_last
-        if t_prev is None:
-            t_prev = now - device  # same anchoring record() would apply
-        wall = max(0.0, now - t_prev)
-        tot = sum(max(0, int(r.get("steps", 0))) for r in inner)
-        cumw = 0.0
-        dev_used = 0.0
-        last = None
-        for j, r in enumerate(inner):
-            w = (
-                max(0, int(r.get("steps", 0))) / tot if tot else 1.0 / n
-            )
-            cumw += w
-            is_last = j == n - 1
-            t_j = now if is_last else t_prev + wall * cumw
-            d_j = (device - dev_used) if is_last else device * w
-            dev_used += d_j
-            last = self.record(
-                device_era_secs=d_j,
-                steps=int(r.get("steps", 0)),
-                generated=int(r.get("generated", 0)),
-                unique=int(r.get("unique", 0)),
-                frontier=int(r.get("frontier", 0)),
-                load_factor=float(r.get("load_factor", 0.0)),
-                take_cap=take_cap,
-                spill_rows=spill_rows if is_last else 0,
-                refill_rows=refill_rows if is_last else 0,
-                table_growths=table_growths if is_last else 0,
-                checkpoint_saves=checkpoint_saves if is_last else 0,
-                shards=shards if is_last else None,
-                memory=memory if is_last else None,
-                t=t_j,
-            )
-        return last
 
     def records(self):
         """Copies of the retained records, oldest first."""

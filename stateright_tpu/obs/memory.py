@@ -150,7 +150,6 @@ def bfs_component_sizes(
     table_capacity: int = 1 << 22,
     coverage: bool = True,
     sample_k: int = 64,
-    fuse: int = 1,
     canon: bool = False,
 ) -> Dict[str, Dict[str, Any]]:
     """Device buffers of the solo BFS engine (engines/tpu_bfs.py).
@@ -162,14 +161,11 @@ def bfs_component_sizes(
     buffer — the coverage and sample slabs are carved out analytically
     but share the params allocation). The sample tail is
     [T1, T2, occupied, sdrop] + (fp1|fp2|depth|action|ok) x
-    slab_entries(k) words (``sample_k=0`` = sampling off). With
-    multi-era fusion (``fuse > 1``) the params additionally carry the
-    fusion tail ([fuse_lim, n_inner] + 4 per-inner-era lanes) — carved
-    out as its own component so the nbytes parity stays exact. Under
+    slab_entries(k) words (``sample_k=0`` = sampling off). Under
     symmetry reduction (``canon``) one last word carries the dispatch's
     ``canon_rewrites``.
     """
-    from ..engines.tpu_bfs import P_LEN, _cov_len, fuse_tail_len
+    from ..engines.tpu_bfs import P_LEN, _cov_len
 
     A = max(1, int(A))
     chunk = min(int(chunk), int(queue_capacity) // (2 * A))
@@ -187,8 +183,6 @@ def bfs_component_sizes(
         from .sample import slab_entries
 
         sizes["sample_slab"] = _entry((4 + 5 * slab_entries(int(sample_k)),))
-    if int(fuse) > 1:
-        sizes["fusion_tail"] = _entry((fuse_tail_len(int(fuse)),))
     if canon:
         sizes["canon_tail"] = _entry((1,))
     return sizes
@@ -252,7 +246,6 @@ def mesh_component_sizes(
     n_shards: int = 8,
     coverage: bool = True,
     sample_k: int = 64,
-    fuse: int = 1,
 ) -> Dict[str, Dict[str, Any]]:
     """Device buffers of the sharded mesh engine (parallel/mesh.py).
 
@@ -262,10 +255,6 @@ def mesh_component_sizes(
     coverage tail of A + P + 1 + DEPTH_CAP words, psum'd on device, +
     per-shard sample tails of 4 + 4*slab_entries(k) words — fp1|fp2|
     depth|ok, un-reduced: the host unions the per-shard bottom-k).
-    With multi-era fusion (``fuse > 1``) each params row additionally
-    carries the fusion tail ([fuse_lim, n_inner] + 4 per-inner-era
-    lanes + P per-shard discovery-era indices), carved out as its own
-    component.
     """
     from .coverage import DEPTH_CAP
 
@@ -287,12 +276,6 @@ def mesh_component_sizes(
 
         sizes["sample_slab"] = _entry(
             (N, 4 + 4 * slab_entries(int(sample_k)))
-        )
-    if int(fuse) > 1:
-        from ..parallel.mesh import shard_fuse_tail_len
-
-        sizes["fusion_tail"] = _entry(
-            (N, shard_fuse_tail_len(int(fuse), int(P)))
         )
     return sizes
 

@@ -72,6 +72,10 @@ P_LEN = 17
 #: the work while the rest idle in the lockstep collective.
 SHARD_IMBALANCE_WARN = 4.0
 
+#: Speculative chain depth: blocks kept chained off the on-device state
+#: while their readbacks are in flight (engines/tpu_bfs.py CHAIN_DEPTH).
+CHAIN_DEPTH = 2
+
 _LOOP_CACHE: Dict[Tuple, Tuple[TensorModel, Any]] = {}
 
 
@@ -92,23 +96,11 @@ class BlockProgram(NamedTuple):
     chain: Any
 
 
-def shard_fuse_tail_len(fuse: int, n_props: int) -> int:
-    """Extra packed-params words per shard when multi-era fusion is on
-    (``fuse > 1``): ``[fuse_lim, n_inner]`` + per-inner-era
-    steps/generated/unique/frontier lanes (``4 * fuse``) + the per-shard
-    inner-era index of each property's best discovery (``n_props`` —
-    the host needs it to reproduce the serial driver's
-    (depth, era, shard) discovery tie-break exactly)."""
-    return (2 + 4 * fuse + n_props) if fuse > 1 else 0
-
-
-def shard_params_len(A: int, P: int, cov: bool, sample_k: int,
-                     fuse: int = 1) -> int:
+def shard_params_len(A: int, P: int, cov: bool, sample_k: int) -> int:
     """Length of one shard's packed uint32 params row: scalars +
     optional coverage tail + optional sampling tail ([T1,T2,occ,0] and
-    four drained lanes) + optional multi-era fusion tail. Mirrors
-    `engines.tpu_bfs.params_len` minus the rec_fp tail (the sharded
-    block passes rec_fp as separate args)."""
+    four drained lanes). Mirrors `engines.tpu_bfs.params_len` minus the
+    rec_fp tail (the sharded block passes rec_fp as separate args)."""
     from ..obs.coverage import DEPTH_CAP
 
     n = P_LEN + ((A + P + 1 + DEPTH_CAP) if cov else 0)
@@ -116,12 +108,11 @@ def shard_params_len(A: int, P: int, cov: bool, sample_k: int,
         from ..obs.sample import slab_entries
 
         n += 4 + 4 * slab_entries(sample_k)
-    return n + shard_fuse_tail_len(fuse, P)
+    return n
 
 
 def block_abstract_args(tm: TensorModel, props, qcap: int, tcap: int,
-                        n_shards: int, cov: bool, sample_k: int,
-                        fuse: int = 1):
+                        n_shards: int, cov: bool, sample_k: int):
     """`jax.ShapeDtypeStruct` pytree matching `_build_block`'s jitted
     signature `(table, queue, rec_fp1, rec_fp2, params)` — global shapes
     with the leading shard axis. Used by the STR6xx program lint to
@@ -139,7 +130,7 @@ def block_abstract_args(tm: TensorModel, props, qcap: int, tcap: int,
         sds((N, tcap), u32),
     )
     queue = tuple(sds((N, qcap), u32) for _ in range(S + 2))
-    plen = shard_params_len(A, P, cov, sample_k, fuse)
+    plen = shard_params_len(A, P, cov, sample_k)
     return (
         table,
         queue,
@@ -151,10 +142,10 @@ def block_abstract_args(tm: TensorModel, props, qcap: int, tcap: int,
 
 def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
                  quota: int, mesh, axis: str, cov: bool = True,
-                 sample_k: int = 0, fuse: int = 1):
+                 sample_k: int = 0):
     key = (
         id(tm), chunk, qcap, n_shards, quota, len(props), cov, sample_k,
-        fuse, tuple(id(d) for d in mesh.devices.flat),
+        tuple(id(d) for d in mesh.devices.flat),
     )
     cached = _LOOP_CACHE.get(key)
     if cached is not None and cached[0] is tm:
@@ -209,7 +200,6 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
         s_high = slab_high_water(sample_k)
         scap = s_high + R  # next step's captures (<= R) always fit
     s_base = P_LEN + ((A + NP_ + 1 + DEPTH_CAP) if cov else 0)
-    f_base = shard_params_len(A, NP_, cov, sample_k)  # fusion tail start
 
     def per_device(table, queue, rec_fp1, rec_fp2, params):
         u = jnp.uint32
@@ -241,424 +231,7 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
         # under shard_map (constants would be unvarying on the mesh axis).
         vzero = params[0] & u(0)
 
-        def run_era(table, queue, head0, count0, unique0, rec_bits,
-                    max_steps, err0, take_cap0, covc0, sampc0):
-            """ONE complete era — the lockstep step loop plus its
-            once-per-era epilogue — threaded so up to ``fuse`` of them
-            chain inside a single dispatch (multi-era fusion). Per-era
-            accumulators (property first-hit lanes, the iteration
-            counter) reset here; cross-era state (table/queue, counters,
-            coverage, the sampling slab) threads through the arguments.
-            Every value the outer fusion gate needs — ``budget_only``
-            (the era's ONLY exit reason was budget exhaustion) and the
-            global slab-occupancy bit — comes out of the one epilogue
-            psum, so the gate is uniform across shards and the outer
-            loop stays lockstep."""
-
-            def global_gates(count, unique, err_cnt, hseen, rec_acc0, its,
-                             socc):
-                """One stacked psum produces every exit condition,
-                IDENTICAL on all shards (the while predicate must be
-                uniform): work left, congestion (a shard cannot refuse
-                all_to_all deliveries, so no shard may pop while ANY
-                shard's ring or table is within one step's receive of its
-                limit), probe errors, and the finish policy's GLOBAL
-                discovery bits."""
-                local = [
-                    (count > u(0)).astype(u),
-                    ((count > high_water) | (unique > grow_limit)).astype(u),
-                    (err_cnt > u(0)).astype(u),
-                ] + [
-                    jnp.minimum(hseen[pi].sum(dtype=u), u(1))
-                    for pi in range(NP_)
-                ]
-                if sample_k:
-                    # Sampling-slab occupancy: when ANY shard's slab passes
-                    # its high-water mark the era ends so the host can
-                    # drain it (appended LAST so the established g[]
-                    # indices hold).
-                    local.append((socc > u(s_high)).astype(u))
-                g = lax.psum(jnp.stack(local), axis)
-                rec_acc = rec_acc0
-                for pi in range(NP_):
-                    rec_acc = rec_acc | (
-                        jnp.minimum(g[3 + pi], u(1)) << u(pi)
-                    )
-                fin_hit = ((rec_acc & fin_any) != u(0)) | (
-                    (fin_all_en != u(0)) & ((rec_acc & fin_all) == fin_all)
-                )
-                g_cont = (
-                    (g[0] > u(0))
-                    & (g[1] == u(0))
-                    & (g[2] == u(0))
-                    & ~fin_hit
-                    & (its < max_steps)
-                )
-                if sample_k:
-                    g_cont = g_cont & (g[3 + NP_] == u(0))
-                return g_cont.astype(u)
-
-            def cond(carry):
-                return carry[-1] != u(0)  # carried uniform gate
-
-            def body(carry):
-                (
-                    table,
-                    queue,
-                    head,
-                    count,
-                    unique,
-                    gen,
-                    steps,
-                    err_cnt,
-                    take_cap,
-                    hseen,
-                    facc1,
-                    facc2,
-                    faccd,
-                    covc,
-                    sampc,
-                    its,
-                    _g_cont,
-                ) = carry
-                pred = count > 0
-                take = jnp.where(
-                    pred, jnp.minimum(jnp.minimum(count, u(chunk)), take_cap), u(0)
-                )
-                active = jnp.arange(chunk, dtype=u) < take
-                with stage_scope("ring"):
-                    popped, _ = fr.ring_gather(queue, head, chunk)
-                rows = popped[:S]
-                ebits = popped[S]
-                depth = popped[S + 1]
-                # Recomputed on pop, elementwise (the ring no longer carries
-                # fingerprints — same round-5 redesign as engines/tpu_bfs.py).
-                with stage_scope("hash"):
-                    row_h1, row_h2 = hash_lanes_jnp(rows)
-
-                with stage_scope("expand"):
-                    ex = expand_lean(rows, ebits, depth, active, depth_limit)
-
-                # COMPACT EARLY: validity compaction is the only padded-width
-                # random-access op; hashing, dedup, bucketing, and the exchange
-                # all run at the compacted [vcap] width.
-                with stage_scope("compact"):
-                    vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
-                    cl = tuple(ex.flat[s][vids] for s in range(S))
-                with stage_scope("hash"):
-                    ch1, ch2 = hash_lanes_jnp(cl)
-                with stage_scope("compact"):
-                    src = vids % u(chunk)
-                    cp1 = jnp.where(vvalid, row_h1[src], u(0))
-                    cp2 = jnp.where(vvalid, row_h2[src], u(0))
-                    cebits = ex.ebits[src]
-                    cdepth = depth[src] + u(1)
-
-                with stage_scope("claim"):
-                    reps = fr.claim_dedup(ch1, ch2, vvalid, dedup_cap)
-
-                with stage_scope("exchange"):
-                    owner = ch1 % u(n_shards)
-                    # Bucket by owner with ONE rank computation (no per-
-                    # destination Python loop — program size stays flat in
-                    # n_shards): a [vcap, N] one-hot cumsum yields each
-                    # candidate's rank within its owner bucket and the
-                    # per-owner counts in one pass.
-                    onehot = (
-                        owner[:, None] == jnp.arange(n_shards, dtype=u)[None, :]
-                    ) & reps[:, None]
-                    csum = jnp.cumsum(onehot.astype(u), axis=0)  # [vcap, N]
-                    rank = (csum * onehot.astype(u)).sum(axis=1) - u(1)
-                    counts_per_owner = csum[-1]  # [N]
-                    n_ovf_total = (
-                        counts_per_owner
-                        - jnp.minimum(counts_per_owner, u(quota))
-                    ).sum(dtype=u)
-                    my = jnp.arange(vcap, dtype=u)
-                    dest = jnp.where(
-                        reps & (rank < u(quota)),
-                        owner * u(quota) + rank,
-                        u(n_shards * quota) + my,  # distinct drop targets
-                    )
-                    send_cand = cl + (cp1, cp2, cebits, cdepth)
-                    send = [
-                        jnp.zeros(n_shards * quota, dtype=u)
-                        .at[dest]
-                        .set(c, mode="drop", unique_indices=True)
-                        for c in send_cand
-                    ]
-
-                    # The ICI hop: one all_to_all per lane; each shard
-                    # receives the buckets addressed to it from every shard.
-                    recv = [
-                        lax.all_to_all(
-                            x, axis, split_axis=0, concat_axis=0, tiled=True
-                        )
-                        for x in send
-                    ]
-                rstates = tuple(recv[t] for t in range(S))
-                rp1 = recv[S]
-                rp2 = recv[S + 1]
-                # Parent fingerprints are nonzero as a pair for every real
-                # candidate; an all-zero parent pair means "empty slot".
-                r_valid = (rp1 | rp2) != u(0)
-                with stage_scope("hash"):
-                    rh1, rh2 = hash_lanes_jnp(rstates)  # owner-side recompute
-
-                with stage_scope("probe"):
-                    table, is_new, unresolved, _ovf_ins = vs.insert(
-                        table, rh1, rh2, rp1, rp2, r_valid
-                    )
-                    unres = unresolved.sum(dtype=u)
-                    new_count = is_new.sum(dtype=u)
-
-                if sample_k:
-                    with stage_scope("sample"):
-                        # Capture below-threshold inserts into this shard's slab.
-                        # `is_new` is exactly-once (retried partial-commit steps
-                        # re-deliver already-inserted rows, which are not new), so
-                        # no fingerprint is ever captured twice. Writes happen at
-                        # the full receive width R — never truncated; the trash
-                        # slot at index scap absorbs masked lanes.
-                        below = is_new & (
-                            (rh1 < st1) | ((rh1 == st1) & (rh2 < st2))
-                        )
-
-                        def _capture(sc):
-                            sfp1, sfp2, sdep, socc = sc
-                            cids, cvalid, n_c = vs._compact_ids(below, R)
-                            pos = socc + jnp.arange(R, dtype=u)
-                            ok_w = cvalid & (pos < u(scap))
-                            widx = jnp.where(ok_w, pos, u(scap))
-                            return (
-                                sfp1.at[widx].set(rh1[cids]),
-                                sfp2.at[widx].set(rh2[cids]),
-                                sdep.at[widx].set(recv[S + 3][cids]),
-                                socc + n_c,
-                            )
-
-                        # Tight-threshold steps capture nothing almost always;
-                        # the cond skips the compaction and slab scatters then.
-                        # Per-shard predicate — shards diverge, which is fine:
-                        # nothing inside the branch communicates.
-                        sampc = lax.cond(
-                            below.any(), _capture, lambda sc: sc, sampc
-                        )
-
-                with stage_scope("ring"):
-                    qrows = rstates + (recv[S + 2], recv[S + 3])
-                    tail = (head + count) & u(qmask)
-                    queue = fr.ring_scatter(queue, tail, qrows, is_new)
-
-                # Partial-commit overflow protocol (see module docstring).
-                # Probe-tail overflow (unresolved candidates at the OWNER) is
-                # retryable the same way, but the veto must be GLOBAL: the
-                # unresolved candidates' parents were popped on OTHER shards,
-                # so every shard must decline to consume and shrink its take
-                # (a sender cannot know which owner overflowed). Fatal only
-                # when no shard can shrink further — genuinely exhausted
-                # probe chains, i.e. state loss.
-                g_us = lax.psum(
-                    jnp.stack([unres, (take > u(1)).astype(u)]), axis
-                )
-                g_unres = g_us[0]
-                g_can_shrink = g_us[1]
-                err_cnt = err_cnt + jnp.where(
-                    g_can_shrink == u(0), g_unres, u(0)
-                )
-                ovf = (n_ovf_total > u(0)) | (g_unres > u(0))
-                consumed = jnp.where(ovf, u(0), take)
-                head = (head + consumed) & u(qmask)
-                count = count - consumed + new_count
-                unique = unique + new_count
-                gen = gen + jnp.where(ovf, u(0), ex.generated)
-                steps = steps + (pred & ~ovf).astype(u)
-                take_cap = jnp.where(
-                    ovf,
-                    jnp.maximum(take >> u(1), u(1)),
-                    jnp.minimum(take_cap + u(max(1, chunk // 16)), u(chunk)),
-                )
-
-                if cov:
-                    with stage_scope("coverage"):
-                        # Shard-local coverage (obs/coverage.py): action counts at
-                        # the SENDER (where expansion attributes candidates to
-                        # their action slot; ovf-gated like `gen`), the consumed
-                        # row count, and the depth histogram at the OWNER (where
-                        # inserts happen; unconditional like `unique`). Shards
-                        # psum these once in the block epilogue.
-                        act, covp, expanded, dhist = covc
-                        pa = ex.valid.astype(u).reshape(A, chunk).sum(axis=1)
-                        act = act + jnp.where(ovf, u(0), pa)
-                        expanded = expanded + consumed
-                        dhist = dhist.at[
-                            jnp.minimum(recv[S + 3], u(DEPTH_CAP - 1))
-                        ].add(is_new.astype(u))
-                        covc = (act, covp, expanded, dhist)
-
-                if NP_:
-                    hseen_n, facc1_n, facc2_n, faccd_n, covp_n = [], [], [], [], []
-                    for pi in range(NP_):
-                        hits = ex.prop_hits[pi]
-                        first = hits & ~hseen[pi]
-                        facc1_n.append(jnp.where(first, row_h1, facc1[pi]))
-                        facc2_n.append(jnp.where(first, row_h2, facc2[pi]))
-                        faccd_n.append(jnp.where(first, depth, faccd[pi]))
-                        hseen_n.append(hseen[pi] | hits)
-                        if cov:
-                            covp_n.append(
-                                covc[1][pi]
-                                + jnp.where(ovf, u(0), hits.sum(dtype=u))
-                            )
-                    hseen = tuple(hseen_n)
-                    facc1 = tuple(facc1_n)
-                    facc2 = tuple(facc2_n)
-                    faccd = tuple(faccd_n)
-                    if cov:
-                        covc = (covc[0], tuple(covp_n), covc[2], covc[3])
-
-                its = its + u(1)
-                g_cont = global_gates(
-                    count, unique, err_cnt, hseen, rec_bits, its,
-                    sampc[3] if sample_k else its,
-                )
-                return (
-                    table, queue, head, count, unique, gen, steps, err_cnt,
-                    take_cap, hseen, facc1, facc2, faccd, covc, sampc, its,
-                    g_cont,
-                )
-
-            # err seeds from err0 (like engines/tpu_bfs.py): a chained
-            # (speculative) dispatch off a probe-error era re-derives the
-            # error exit and becomes an identity no-op instead of running
-            # on a table with dropped states. The slab-occupancy seed is
-            # the THREADED occupancy: a later fused era resumes where the
-            # previous one left its slab.
-            g0 = global_gates(
-                count0,
-                unique0,
-                err0,
-                tuple(false_lane for _ in range(NP_)),
-                rec_bits,
-                vzero,
-                sampc0[3] if sample_k else vzero,
-            )
-            init = (
-                table,
-                queue,
-                head0,
-                count0,
-                unique0,
-                vzero,
-                vzero,
-                err0,  # carried: closes the gate on a chained dispatch
-                jnp.minimum(jnp.maximum(take_cap0, u(1)), u(chunk)),
-                tuple(false_lane for _ in range(NP_)),
-                tuple(zero_lane for _ in range(NP_)),
-                tuple(zero_lane for _ in range(NP_)),
-                tuple(zero_lane for _ in range(NP_)),
-                covc0,
-                sampc0,
-                vzero,  # iteration counter (uniform: shards run lockstep)
-                g0,
-            )
-            (
-                table, queue, head, count, unique, gen, steps, err_cnt,
-                take_cap_out, hseen, facc1, facc2, faccd, covc_out,
-                sampc_out, its_out, _gc,
-            ) = lax.while_loop(cond, body, init)
-
-            # Era epilogue (once per era): BLOCK-LOCAL discovery reports.
-            # The host keeps the min-depth discovery across blocks and
-            # shards — shards skew, so a shallower hit can surface in a
-            # LATER block than a deeper one (the reference's multithreaded
-            # BFS has the same benign race, bfs.rs:243-244; tracking min
-            # depth host-side makes us strictly better, not just equal).
-            with stage_scope("epilogue"):
-                if NP_:
-                    ef1, ef2, edd = [], [], []
-                    for pi in range(NP_):
-                        found = jnp.any(hseen[pi])
-                        sel = jnp.argmin(
-                            jnp.where(hseen[pi], faccd[pi], u(0xFFFFFFFF))
-                        )
-                        ef1.append(jnp.where(found, facc1[pi][sel], u(0)))
-                        ef2.append(jnp.where(found, facc2[pi][sel], u(0)))
-                        edd.append(
-                            jnp.where(found, faccd[pi][sel], u(0xFFFFFFFF))
-                        )
-                    era_fp1 = jnp.stack(ef1)
-                    era_fp2 = jnp.stack(ef2)
-                    era_dd = jnp.stack(edd)
-                else:
-                    era_fp1 = jnp.zeros(0, dtype=u) + vzero
-                    era_fp2 = jnp.zeros(0, dtype=u) + vzero
-                    era_dd = jnp.zeros(0, dtype=u) + vzero
-                maxd = jnp.where(
-                    steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
-                )
-                # Adaptive era budget (device-side emission, mirroring
-                # engines/tpu_bfs.py): every input to the formula is globally
-                # uniform (one epilogue psum for pressure/err/work/global rec
-                # bits; `its_out` runs lockstep), so every shard emits the SAME
-                # next budget and a chained dispatch stays uniform too. The
-                # cost is one collective per ERA, not per step — and the same
-                # psum carries the fusion gate (`budget_only`, slab occupancy),
-                # so chaining eras on device adds no extra collectives.
-                glocal = [
-                    ((count > high_water) | (unique > grow_limit)).astype(u),
-                    (err_cnt > u(0)).astype(u),
-                    (count > u(0)).astype(u),
-                ] + [
-                    jnp.minimum(hseen[pi].sum(dtype=u), u(1))
-                    for pi in range(NP_)
-                ]
-                if sample_k and fuse > 1:
-                    socc_out = sampc_out[3]
-                    glocal.append((socc_out > u(s_high)).astype(u))
-                gb = lax.psum(jnp.stack(glocal), axis)
-                g_pressure = gb[0] > u(0)
-                g_err = gb[1] > u(0)
-                g_work = gb[2] > u(0)
-                rec_all = rec_bits
-                for pi in range(NP_):
-                    rec_all = rec_all | (jnp.minimum(gb[3 + pi], u(1)) << u(pi))
-                fin_hit_final = ((rec_all & fin_any) != u(0)) | (
-                    (fin_all_en != u(0)) & ((rec_all & fin_all) == fin_all)
-                )
-                budget_only = (
-                    (its_out >= max_steps)
-                    & g_work
-                    & ~g_pressure
-                    & ~g_err
-                    & ~fin_hit_final
-                )
-                g_slab_full = (
-                    gb[3 + NP_] > u(0) if (sample_k and fuse > 1) else None
-                )
-                grown = jnp.minimum(
-                    jnp.maximum(max_steps, u(1)) * u(2), budget_cap
-                )
-                shrunk = jnp.maximum(
-                    jnp.minimum(max_steps, budget_cap) >> u(1),
-                    u(64),  # BUDGET_MIN (engines/tpu_bfs.py)
-                )
-                next_budget = jnp.where(
-                    budget_cap == u(0),
-                    max_steps,
-                    jnp.where(
-                        g_pressure, shrunk,
-                        jnp.where(budget_only, grown, max_steps),
-                    ),
-                )
-            return (
-                table, queue, head, count, unique, rec_all, err_cnt,
-                take_cap_out, covc_out, sampc_out, era_fp1, era_fp2,
-                era_dd, steps, gen, maxd, next_budget, budget_only,
-                g_slab_full,
-            )
-
-        sampc_init = (
+        sampc0 = (
             (
                 jnp.zeros(scap + 1, dtype=u) + vzero,  # fp1 (+ trash slot)
                 jnp.zeros(scap + 1, dtype=u) + vzero,  # fp2
@@ -668,7 +241,7 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
             if sample_k
             else ()
         )
-        covc_init = (
+        covc0 = (
             (
                 jnp.zeros(A, dtype=u) + vzero,  # per-action valid counts
                 tuple(vzero for _ in range(NP_)),  # per-property hits
@@ -679,99 +252,400 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
             else ()
         )
 
-        if fuse == 1:
-            (
-                table, queue, head, count, unique, rec_all, err_cnt,
-                take_cap_out, covc_out, sampc_out, rec_fp1, rec_fp2,
-                disc_depth, steps, gen, maxd, next_budget, _budget_only,
-                _g_slab,
-            ) = run_era(
-                table, queue, params[P_HEAD], params[P_COUNT],
-                params[P_UNIQUE], rec_bits0, max_steps0, params[P_ERR],
-                params[P_TAKE_CAP], covc_init, sampc_init,
-            )
-            ftail = []
-        else:
-            # Multi-era fusion: chain up to fuse_lim eras inside THIS one
-            # dispatch. An era chains iff its ONLY exit reason was budget
-            # exhaustion (globally uniform: psum-derived) and, with
-            # sampling on, no shard's slab passed its high-water mark —
-            # exactly the cases where the serial host would immediately
-            # re-dispatch with nothing but a budget/threshold refresh.
-            # Everything else (spill pressure, growth, probe error,
-            # finish-policy hit, drained frontier) exits the outer loop so
-            # the readback reports which inner era tripped.
-            fuse_lim = jnp.minimum(
-                jnp.maximum(params[f_base], u(1)), u(fuse)
-            )
-            fzero = jnp.zeros(fuse, dtype=u) + vzero
-            np_zero = jnp.zeros(NP_, dtype=u) + vzero
-            # Per-shard best-discovery fold across inner eras: strict
-            # less-than keeps the EARLIEST era on depth ties, and the
-            # per-property era index rides the params tail so the host
-            # can reproduce the serial (depth, era, shard) tie-break.
-            dd_init = np_zero + u(0xFFFFFFFF)
+        # The era: the lockstep step loop plus its once-per-era epilogue.
+        head0 = params[P_HEAD]
+        count0 = params[P_COUNT]
+        unique0 = params[P_UNIQUE]
+        err0 = params[P_ERR]
+        take_cap0 = params[P_TAKE_CAP]
 
-            def ocond(oc):
-                return (oc[0] < fuse_lim) & (oc[1] != u(0))
-
-            def obody(oc):
-                (
-                    k, _cont, steps_acc, gen_acc, maxd_acc, fsteps, fgen,
-                    funiq, fcnt, table, queue, head, count, unique, rbits,
-                    ms, err, tc, covc, sampc, afp1, afp2, add, aera,
-                ) = oc
-                uniq_in = unique
-                (
-                    table, queue, head, count, unique, rbits, err, tc,
-                    covc, sampc, efp1, efp2, edd, steps, gen, maxd,
-                    next_budget, budget_only, g_slab,
-                ) = run_era(
-                    table, queue, head, count, unique, rbits, ms, err, tc,
-                    covc, sampc,
-                )
-                cont = budget_only
-                if sample_k:
-                    cont = cont & ~g_slab
-                upd = edd < add
-                return (
-                    k + u(1),
-                    cont.astype(u),
-                    steps_acc + steps,
-                    gen_acc + gen,
-                    jnp.maximum(maxd_acc, maxd),
-                    fsteps.at[k].set(steps),
-                    fgen.at[k].set(gen),
-                    funiq.at[k].set(unique - uniq_in),
-                    fcnt.at[k].set(count),
-                    table, queue, head, count, unique, rbits,
-                    next_budget, err, tc, covc, sampc,
-                    jnp.where(upd, efp1, afp1),
-                    jnp.where(upd, efp2, afp2),
-                    jnp.where(upd, edd, add),
-                    jnp.where(upd, k, aera),
-                )
-
-            oinit = (
-                vzero,  # k: inner-era counter (uniform)
-                vzero + u(1),  # cont: always run at least one era
-                vzero, vzero, vzero,  # steps/gen/maxd accumulators
-                fzero, fzero, fzero, fzero,  # per-inner-era tail lanes
-                table, queue, params[P_HEAD], params[P_COUNT],
-                params[P_UNIQUE], rec_bits0, max_steps0, params[P_ERR],
-                params[P_TAKE_CAP], covc_init, sampc_init,
-                np_zero, np_zero, dd_init, np_zero,
-            )
-            (
-                k_out, _cont, steps, gen, maxd, fsteps, fgen, funiq, fcnt,
-                table, queue, head, count, unique, rec_all, next_budget,
-                err_cnt, take_cap_out, covc_out, sampc_out, rec_fp1,
-                rec_fp2, disc_depth, disc_era,
-            ) = lax.while_loop(ocond, obody, oinit)
-            ftail = [
-                jnp.stack([fuse_lim, k_out]),
-                fsteps, fgen, funiq, fcnt, disc_era,
+        def global_gates(count, unique, err_cnt, hseen, rec_acc0, its,
+                         socc):
+            """One stacked psum produces every exit condition,
+            IDENTICAL on all shards (the while predicate must be
+            uniform): work left, congestion (a shard cannot refuse
+            all_to_all deliveries, so no shard may pop while ANY
+            shard's ring or table is within one step's receive of its
+            limit), probe errors, and the finish policy's GLOBAL
+            discovery bits."""
+            local = [
+                (count > u(0)).astype(u),
+                ((count > high_water) | (unique > grow_limit)).astype(u),
+                (err_cnt > u(0)).astype(u),
+            ] + [
+                jnp.minimum(hseen[pi].sum(dtype=u), u(1))
+                for pi in range(NP_)
             ]
+            if sample_k:
+                # Sampling-slab occupancy: when ANY shard's slab passes
+                # its high-water mark the era ends so the host can
+                # drain it (appended LAST so the established g[]
+                # indices hold).
+                local.append((socc > u(s_high)).astype(u))
+            g = lax.psum(jnp.stack(local), axis)
+            rec_acc = rec_acc0
+            for pi in range(NP_):
+                rec_acc = rec_acc | (
+                    jnp.minimum(g[3 + pi], u(1)) << u(pi)
+                )
+            fin_hit = ((rec_acc & fin_any) != u(0)) | (
+                (fin_all_en != u(0)) & ((rec_acc & fin_all) == fin_all)
+            )
+            g_cont = (
+                (g[0] > u(0))
+                & (g[1] == u(0))
+                & (g[2] == u(0))
+                & ~fin_hit
+                & (its < max_steps0)
+            )
+            if sample_k:
+                g_cont = g_cont & (g[3 + NP_] == u(0))
+            return g_cont.astype(u)
+
+        def cond(carry):
+            return carry[-1] != u(0)  # carried uniform gate
+
+        def body(carry):
+            (
+                table,
+                queue,
+                head,
+                count,
+                unique,
+                gen,
+                steps,
+                err_cnt,
+                take_cap,
+                hseen,
+                facc1,
+                facc2,
+                faccd,
+                covc,
+                sampc,
+                its,
+                _g_cont,
+            ) = carry
+            pred = count > 0
+            take = jnp.where(
+                pred, jnp.minimum(jnp.minimum(count, u(chunk)), take_cap), u(0)
+            )
+            active = jnp.arange(chunk, dtype=u) < take
+            with stage_scope("ring"):
+                popped, _ = fr.ring_gather(queue, head, chunk)
+            rows = popped[:S]
+            ebits = popped[S]
+            depth = popped[S + 1]
+            # Recomputed on pop, elementwise (the ring no longer carries
+            # fingerprints — same round-5 redesign as engines/tpu_bfs.py).
+            with stage_scope("hash"):
+                row_h1, row_h2 = hash_lanes_jnp(rows)
+
+            with stage_scope("expand"):
+                ex = expand_lean(rows, ebits, depth, active, depth_limit)
+
+            # COMPACT EARLY: validity compaction is the only padded-width
+            # random-access op; hashing, dedup, bucketing, and the exchange
+            # all run at the compacted [vcap] width.
+            with stage_scope("compact"):
+                vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
+                cl = tuple(ex.flat[s][vids] for s in range(S))
+            with stage_scope("hash"):
+                ch1, ch2 = hash_lanes_jnp(cl)
+            with stage_scope("compact"):
+                src = vids % u(chunk)
+                cp1 = jnp.where(vvalid, row_h1[src], u(0))
+                cp2 = jnp.where(vvalid, row_h2[src], u(0))
+                cebits = ex.ebits[src]
+                cdepth = depth[src] + u(1)
+
+            with stage_scope("claim"):
+                reps = fr.claim_dedup(ch1, ch2, vvalid, dedup_cap)
+
+            with stage_scope("exchange"):
+                owner = ch1 % u(n_shards)
+                # Bucket by owner with ONE rank computation (no per-
+                # destination Python loop — program size stays flat in
+                # n_shards): a [vcap, N] one-hot cumsum yields each
+                # candidate's rank within its owner bucket and the
+                # per-owner counts in one pass.
+                onehot = (
+                    owner[:, None] == jnp.arange(n_shards, dtype=u)[None, :]
+                ) & reps[:, None]
+                csum = jnp.cumsum(onehot.astype(u), axis=0)  # [vcap, N]
+                rank = (csum * onehot.astype(u)).sum(axis=1) - u(1)
+                counts_per_owner = csum[-1]  # [N]
+                n_ovf_total = (
+                    counts_per_owner
+                    - jnp.minimum(counts_per_owner, u(quota))
+                ).sum(dtype=u)
+                my = jnp.arange(vcap, dtype=u)
+                dest = jnp.where(
+                    reps & (rank < u(quota)),
+                    owner * u(quota) + rank,
+                    u(n_shards * quota) + my,  # distinct drop targets
+                )
+                send_cand = cl + (cp1, cp2, cebits, cdepth)
+                send = [
+                    jnp.zeros(n_shards * quota, dtype=u)
+                    .at[dest]
+                    .set(c, mode="drop", unique_indices=True)
+                    for c in send_cand
+                ]
+
+                # The ICI hop: one all_to_all per lane; each shard
+                # receives the buckets addressed to it from every shard.
+                recv = [
+                    lax.all_to_all(
+                        x, axis, split_axis=0, concat_axis=0, tiled=True
+                    )
+                    for x in send
+                ]
+            rstates = tuple(recv[t] for t in range(S))
+            rp1 = recv[S]
+            rp2 = recv[S + 1]
+            # Parent fingerprints are nonzero as a pair for every real
+            # candidate; an all-zero parent pair means "empty slot".
+            r_valid = (rp1 | rp2) != u(0)
+            with stage_scope("hash"):
+                rh1, rh2 = hash_lanes_jnp(rstates)  # owner-side recompute
+
+            with stage_scope("probe"):
+                table, is_new, unresolved, _ovf_ins = vs.insert(
+                    table, rh1, rh2, rp1, rp2, r_valid
+                )
+                unres = unresolved.sum(dtype=u)
+                new_count = is_new.sum(dtype=u)
+
+            if sample_k:
+                with stage_scope("sample"):
+                    # Capture below-threshold inserts into this shard's slab.
+                    # `is_new` is exactly-once (retried partial-commit steps
+                    # re-deliver already-inserted rows, which are not new), so
+                    # no fingerprint is ever captured twice. Writes happen at
+                    # the full receive width R — never truncated; the trash
+                    # slot at index scap absorbs masked lanes.
+                    below = is_new & (
+                        (rh1 < st1) | ((rh1 == st1) & (rh2 < st2))
+                    )
+
+                    def _capture(sc):
+                        sfp1, sfp2, sdep, socc = sc
+                        cids, cvalid, n_c = vs._compact_ids(below, R)
+                        pos = socc + jnp.arange(R, dtype=u)
+                        ok_w = cvalid & (pos < u(scap))
+                        widx = jnp.where(ok_w, pos, u(scap))
+                        return (
+                            sfp1.at[widx].set(rh1[cids]),
+                            sfp2.at[widx].set(rh2[cids]),
+                            sdep.at[widx].set(recv[S + 3][cids]),
+                            socc + n_c,
+                        )
+
+                    # Tight-threshold steps capture nothing almost always;
+                    # the cond skips the compaction and slab scatters then.
+                    # Per-shard predicate — shards diverge, which is fine:
+                    # nothing inside the branch communicates.
+                    sampc = lax.cond(
+                        below.any(), _capture, lambda sc: sc, sampc
+                    )
+
+            with stage_scope("ring"):
+                qrows = rstates + (recv[S + 2], recv[S + 3])
+                tail = (head + count) & u(qmask)
+                queue = fr.ring_scatter(queue, tail, qrows, is_new)
+
+            # Partial-commit overflow protocol (see module docstring).
+            # Probe-tail overflow (unresolved candidates at the OWNER) is
+            # retryable the same way, but the veto must be GLOBAL: the
+            # unresolved candidates' parents were popped on OTHER shards,
+            # so every shard must decline to consume and shrink its take
+            # (a sender cannot know which owner overflowed). Fatal only
+            # when no shard can shrink further — genuinely exhausted
+            # probe chains, i.e. state loss.
+            g_us = lax.psum(
+                jnp.stack([unres, (take > u(1)).astype(u)]), axis
+            )
+            g_unres = g_us[0]
+            g_can_shrink = g_us[1]
+            err_cnt = err_cnt + jnp.where(
+                g_can_shrink == u(0), g_unres, u(0)
+            )
+            ovf = (n_ovf_total > u(0)) | (g_unres > u(0))
+            consumed = jnp.where(ovf, u(0), take)
+            head = (head + consumed) & u(qmask)
+            count = count - consumed + new_count
+            unique = unique + new_count
+            gen = gen + jnp.where(ovf, u(0), ex.generated)
+            steps = steps + (pred & ~ovf).astype(u)
+            take_cap = jnp.where(
+                ovf,
+                jnp.maximum(take >> u(1), u(1)),
+                jnp.minimum(take_cap + u(max(1, chunk // 16)), u(chunk)),
+            )
+
+            if cov:
+                with stage_scope("coverage"):
+                    # Shard-local coverage (obs/coverage.py): action counts at
+                    # the SENDER (where expansion attributes candidates to
+                    # their action slot; ovf-gated like `gen`), the consumed
+                    # row count, and the depth histogram at the OWNER (where
+                    # inserts happen; unconditional like `unique`). Shards
+                    # psum these once in the block epilogue.
+                    act, covp, expanded, dhist = covc
+                    pa = ex.valid.astype(u).reshape(A, chunk).sum(axis=1)
+                    act = act + jnp.where(ovf, u(0), pa)
+                    expanded = expanded + consumed
+                    dhist = dhist.at[
+                        jnp.minimum(recv[S + 3], u(DEPTH_CAP - 1))
+                    ].add(is_new.astype(u))
+                    covc = (act, covp, expanded, dhist)
+
+            if NP_:
+                hseen_n, facc1_n, facc2_n, faccd_n, covp_n = [], [], [], [], []
+                for pi in range(NP_):
+                    hits = ex.prop_hits[pi]
+                    first = hits & ~hseen[pi]
+                    facc1_n.append(jnp.where(first, row_h1, facc1[pi]))
+                    facc2_n.append(jnp.where(first, row_h2, facc2[pi]))
+                    faccd_n.append(jnp.where(first, depth, faccd[pi]))
+                    hseen_n.append(hseen[pi] | hits)
+                    if cov:
+                        covp_n.append(
+                            covc[1][pi]
+                            + jnp.where(ovf, u(0), hits.sum(dtype=u))
+                        )
+                hseen = tuple(hseen_n)
+                facc1 = tuple(facc1_n)
+                facc2 = tuple(facc2_n)
+                faccd = tuple(faccd_n)
+                if cov:
+                    covc = (covc[0], tuple(covp_n), covc[2], covc[3])
+
+            its = its + u(1)
+            g_cont = global_gates(
+                count, unique, err_cnt, hseen, rec_bits0, its,
+                sampc[3] if sample_k else its,
+            )
+            return (
+                table, queue, head, count, unique, gen, steps, err_cnt,
+                take_cap, hseen, facc1, facc2, faccd, covc, sampc, its,
+                g_cont,
+            )
+
+        # err seeds from err0 (like engines/tpu_bfs.py): a chained
+        # (speculative) dispatch off a probe-error era re-derives the
+        # error exit and becomes an identity no-op instead of running
+        # on a table with dropped states.
+        g0 = global_gates(
+            count0,
+            unique0,
+            err0,
+            tuple(false_lane for _ in range(NP_)),
+            rec_bits0,
+            vzero,
+            sampc0[3] if sample_k else vzero,
+        )
+        init = (
+            table,
+            queue,
+            head0,
+            count0,
+            unique0,
+            vzero,
+            vzero,
+            err0,  # carried: closes the gate on a chained dispatch
+            jnp.minimum(jnp.maximum(take_cap0, u(1)), u(chunk)),
+            tuple(false_lane for _ in range(NP_)),
+            tuple(zero_lane for _ in range(NP_)),
+            tuple(zero_lane for _ in range(NP_)),
+            tuple(zero_lane for _ in range(NP_)),
+            covc0,
+            sampc0,
+            vzero,  # iteration counter (uniform: shards run lockstep)
+            g0,
+        )
+        (
+            table, queue, head, count, unique, gen, steps, err_cnt,
+            take_cap_out, hseen, facc1, facc2, faccd, covc_out,
+            sampc_out, its_out, _gc,
+        ) = lax.while_loop(cond, body, init)
+
+        # Era epilogue (once per era): BLOCK-LOCAL discovery reports.
+        # The host keeps the min-depth discovery across blocks and
+        # shards — shards skew, so a shallower hit can surface in a
+        # LATER block than a deeper one (the reference's multithreaded
+        # BFS has the same benign race, bfs.rs:243-244; tracking min
+        # depth host-side makes us strictly better, not just equal).
+        with stage_scope("epilogue"):
+            if NP_:
+                ef1, ef2, edd = [], [], []
+                for pi in range(NP_):
+                    found = jnp.any(hseen[pi])
+                    sel = jnp.argmin(
+                        jnp.where(hseen[pi], faccd[pi], u(0xFFFFFFFF))
+                    )
+                    ef1.append(jnp.where(found, facc1[pi][sel], u(0)))
+                    ef2.append(jnp.where(found, facc2[pi][sel], u(0)))
+                    edd.append(
+                        jnp.where(found, faccd[pi][sel], u(0xFFFFFFFF))
+                    )
+                rec_fp1 = jnp.stack(ef1)
+                rec_fp2 = jnp.stack(ef2)
+                disc_depth = jnp.stack(edd)
+            else:
+                rec_fp1 = jnp.zeros(0, dtype=u) + vzero
+                rec_fp2 = jnp.zeros(0, dtype=u) + vzero
+                disc_depth = jnp.zeros(0, dtype=u) + vzero
+            maxd = jnp.where(
+                steps > 0, queue[S + 1][(head - u(1)) & u(qmask)], u(0)
+            )
+            # Adaptive era budget (device-side emission, mirroring
+            # engines/tpu_bfs.py): every input to the formula is globally
+            # uniform (one epilogue psum for pressure/err/work/global rec
+            # bits; `its_out` runs lockstep), so every shard emits the SAME
+            # next budget and a chained dispatch stays uniform too. The
+            # cost is one collective per ERA, not per step.
+            glocal = [
+                ((count > high_water) | (unique > grow_limit)).astype(u),
+                (err_cnt > u(0)).astype(u),
+                (count > u(0)).astype(u),
+            ] + [
+                jnp.minimum(hseen[pi].sum(dtype=u), u(1))
+                for pi in range(NP_)
+            ]
+            gb = lax.psum(jnp.stack(glocal), axis)
+            g_pressure = gb[0] > u(0)
+            g_err = gb[1] > u(0)
+            g_work = gb[2] > u(0)
+            rec_all = rec_bits0
+            for pi in range(NP_):
+                rec_all = rec_all | (jnp.minimum(gb[3 + pi], u(1)) << u(pi))
+            fin_hit_final = ((rec_all & fin_any) != u(0)) | (
+                (fin_all_en != u(0)) & ((rec_all & fin_all) == fin_all)
+            )
+            budget_only = (
+                (its_out >= max_steps0)
+                & g_work
+                & ~g_pressure
+                & ~g_err
+                & ~fin_hit_final
+            )
+            grown = jnp.minimum(
+                jnp.maximum(max_steps0, u(1)) * u(2), budget_cap
+            )
+            shrunk = jnp.maximum(
+                jnp.minimum(max_steps0, budget_cap) >> u(1),
+                u(64),  # BUDGET_MIN (engines/tpu_bfs.py)
+            )
+            next_budget = jnp.where(
+                budget_cap == u(0),
+                max_steps0,
+                jnp.where(
+                    g_pressure, shrunk,
+                    jnp.where(budget_only, grown, max_steps0),
+                ),
+            )
+
         # P_REC emits the GLOBAL accumulated bits (rec_all), not the
         # shard-local bits: the host ORs the rows anyway, and a chained
         # (speculative) dispatch feeds the row straight back in —
@@ -821,12 +695,6 @@ def _build_block(tm: TensorModel, props, chunk: int, qcap: int, n_shards: int,
                     sdep[:scap][topi],
                     used[topi].astype(u),
                 ]
-            # Fusion tail (fuse > 1 only): [fuse_lim (pass-through), n_inner]
-            # + per-inner-era steps | generated | unique-delta | frontier
-            # lanes + per-property best-discovery era index. One readback
-            # then reconstructs n_inner exact flight records and the serial
-            # discovery tie-break.
-            parts += ftail
             params_out = jnp.concatenate(parts)
 
         def exp(x):
@@ -997,18 +865,11 @@ class ShardedBfsChecker(HostEngineBase):
             # on) — see _run_loop and engines/tpu_bfs.py for the soundness
             # argument.
             self._pipeline = bool(getattr(builder, "pipeline_", True))
-            # K-deep speculative chain (CheckerBuilder.pipeline(depth=K)) and
-            # on-device multi-era fusion (fuse=N): both amortize host
-            # bookkeeping over dispatches; defaults keep a depth of 2 (auto)
-            # and one era per dispatch.
-            depth = getattr(builder, "pipeline_depth_", None)
-            self._chain_depth = max(1, int(depth)) if depth is not None else 2
             self._chain_max = 0
-            self._fuse = max(1, int(getattr(builder, "fuse_eras_", None) or 1))
             self._block = _build_block(
                 self.tm, self._tprops, self._chunk, self._qcap, self.n_shards,
                 self._quota, self.mesh, "shards", self._cov,
-                sample_k=self._sample_k, fuse=self._fuse,
+                sample_k=self._sample_k,
             )
 
             self._unique = 0
@@ -1216,7 +1077,6 @@ class ShardedBfsChecker(HostEngineBase):
             n_shards=self.n_shards,
             coverage=self._cov,
             sample_k=self._sample_k,
-            fuse=self._fuse,
         )
         arrays = {
             "visited_table": table,
@@ -1226,8 +1086,6 @@ class ShardedBfsChecker(HostEngineBase):
             "coverage_slab": params_dev,
             "sample_slab": params_dev,
         }
-        if self._fuse > 1:
-            arrays["fusion_tail"] = params_dev
         rec.register_components(sizes, arrays=arrays)
         rec.set_geometry(
             rows=self._tcap,
@@ -1315,8 +1173,6 @@ class ShardedBfsChecker(HostEngineBase):
             sk2 = slab_entries(self._sample_k)
             nsamp = 4 + 4 * sk2  # [T1,T2,occupied,0] + fp1|fp2|dep|ok
         s_base = P_LEN + ncov
-        nfuse = shard_fuse_tail_len(self._fuse, NP_)
-        f_base = s_base + nsamp
         last_thresh = None
         max_sync = (
             self._max_sync_steps
@@ -1373,33 +1229,6 @@ class ShardedBfsChecker(HostEngineBase):
         # could fire.
         pipeline = self._pipeline and self._target_state_count is None
 
-        def _fuse_lim_now() -> int:
-            """Inner-era cap for the NEXT dispatch (P_FUSE_LIM lane):
-            degrade fusion to one era whenever a host-only concern needs
-            per-era boundaries — spill backlog, a state-count target, or
-            checkpoint / timeout cadence at half-elapsed (mirrors
-            engines/tpu_bfs.py)."""
-            if self._fuse <= 1:
-                return 1
-            if any(self._spill[s] for s in range(N)):
-                return 1
-            if self._target_state_count is not None:
-                return 1
-            now = _time.monotonic()
-            if (
-                self._ckpt_every is not None
-                and now - self._last_ckpt >= self._ckpt_every / 2
-            ):
-                return 1
-            if (
-                self._deadline is not None
-                and now >= self._deadline - self._timeout / 2
-            ):
-                return 1
-            # Auto-N (engines/common.py): back off when the flight history
-            # shows the dispatch gap already amortized.
-            return self._fuse_auto_n(self._fuse)
-
         def consume(*args, **kwargs):
             with self._metrics.phase("era_host"):
                 return consume_block(*args, **kwargs)
@@ -1420,11 +1249,6 @@ class ShardedBfsChecker(HostEngineBase):
             nonlocal per_shard_unique, rec_bits, rec_fp1, rec_fp2
             nonlocal budget, budget_cap, regrow_budget, disc_depth_best
             nonlocal flight_prev_unique, imbalance_warned, stop
-            # Inner eras executed by this (possibly fused) dispatch: the
-            # fusion tail's k_out lane, uniform across shards.
-            n_inner = 1
-            if nfuse:
-                n_inner = max(1, min(int(vals[0, f_base + 1]), self._fuse))
             err = bool(vals[:, P_ERR].any())
             if not err and self._chaos_probe_error_era is not None and (
                 self._metrics.get("eras") >= self._chaos_probe_error_era
@@ -1478,19 +1302,17 @@ class ShardedBfsChecker(HostEngineBase):
             # computed from psum'd inputs); the host steers only the cap.
             budget = int(vals[0, P_MAX_STEPS])
             self._metrics.set_gauge("era_step_budget", int(era_budget))
-            # Wall feedback steers the PER-ERA budget cap; under fusion the
-            # dispatch wall covers n_inner eras, so feed back the mean.
-            per_era_wall = era_wall / n_inner
+            # Wall feedback steers the per-era budget cap.
             if poll_target is not None and era_wall > 0.0:
-                if per_era_wall < poll_target / 2 and budget_cap < cap_limit:
+                if era_wall < poll_target / 2 and budget_cap < cap_limit:
                     budget_cap = min(budget_cap * 2, cap_limit)
-                elif per_era_wall > poll_target and budget_cap > 64:
+                elif era_wall > poll_target and budget_cap > 64:
                     budget_cap = max(budget_cap // 2, 64)
             per_shard_unique = list(vals[:, P_UNIQUE].astype(np.int64))
             self._unique = int(sum(per_shard_unique))
             self._state_count += int(vals[:, P_GEN].sum())
             self._max_depth = max(self._max_depth, int(vals[:, P_MAXD].max()))
-            self._metrics.inc("eras", n_inner)
+            self._metrics.inc("eras")
             self._metrics.inc("steps", int(vals[:, P_STEPS].sum()))
             self._metrics.inc("states_generated", int(vals[:, P_GEN].sum()))
             self._metrics.set_gauge("take_cap", int(min(take_caps)))
@@ -1535,29 +1357,10 @@ class ShardedBfsChecker(HostEngineBase):
                 fp1 = np.asarray(fp1_dev)
                 fp2 = np.asarray(fp2_dev)
                 depths = np.asarray(dd_dev)  # [N, NP_]
-                if nfuse:
-                    # Per-shard inner-era index of each best discovery
-                    # (fusion tail): the serial driver's tie-break is
-                    # lexicographic (depth, era, shard) — the device fold
-                    # kept the per-shard (depth, era) lexmin, lexsort
-                    # recovers the global serial winner across shards.
-                    e_off = f_base + 2 + 4 * self._fuse
-                    disc_era = vals[:, e_off : e_off + NP_].astype(np.int64)
                 for pi, p in enumerate(self._tprops):
                     if not (block_bits >> pi) & 1:
                         continue
-                    if nfuse:
-                        s = int(
-                            np.lexsort(
-                                (
-                                    np.arange(N),
-                                    disc_era[:, pi],
-                                    depths[:, pi].astype(np.int64),
-                                )
-                            )[0]
-                        )
-                    else:
-                        s = int(np.argmin(depths[:, pi]))
+                    s = int(np.argmin(depths[:, pi]))
                     d = int(depths[s, pi])
                     if (
                         p.name not in self._discovery_fps
@@ -1682,35 +1485,7 @@ class ShardedBfsChecker(HostEngineBase):
             # lands in its own host_gap. Under pipelining era_wall is the
             # MARGINAL readback-to-readback span, so the summary still
             # reconciles with the external wall clock (obs/flight.py
-            # overlap-aware accounting). A fused dispatch hands the
-            # per-inner-era attribution lanes through so the recorder can
-            # split it into n_inner exact records.
-            inner = None
-            if nfuse:
-                F = self._fuse
-                off = f_base + 2
-                fsteps = vals[:, off : off + F].astype(np.int64)
-                fgen = vals[:, off + F : off + 2 * F].astype(np.int64)
-                funiq = vals[:, off + 2 * F : off + 3 * F].astype(np.int64)
-                fcnt = vals[:, off + 3 * F : off + 4 * F].astype(np.int64)
-                inner = []
-                for j in range(n_inner):
-                    # Reconstruct each era's post-era per-shard unique by
-                    # peeling back the later eras' per-shard deltas.
-                    u_after = shard_unique - funiq[
-                        :, j + 1 : n_inner
-                    ].sum(axis=1)
-                    inner.append(
-                        {
-                            "steps": int(fsteps[:, j].sum()),
-                            "generated": int(fgen[:, j].sum()),
-                            "unique": int(u_after.sum()),
-                            "frontier": int(fcnt[:, j].sum()),
-                            "load_factor": round(
-                                int(u_after.max()) / max(1, self._tcap), 4
-                            ),
-                        }
-                    )
+            # overlap-aware accounting).
             self._flight_record(
                 device_era_secs=era_wall,
                 steps=int(vals[:, P_STEPS].sum()),
@@ -1724,7 +1499,6 @@ class ShardedBfsChecker(HostEngineBase):
                 spill_rows=spilled,
                 shards=shards_rec,
                 grow_rows=int(max(per_shard_unique)),
-                inner=inner,
             )
 
             if self._finish_matched(self._discovery_fps):
@@ -1832,7 +1606,7 @@ class ShardedBfsChecker(HostEngineBase):
                 )
 
             params_np = np.zeros(
-                (N, P_LEN + ncov + nsamp + nfuse), dtype=np.uint32
+                (N, P_LEN + ncov + nsamp), dtype=np.uint32
             )
             for s in range(N):
                 params_np[s, :P_LEN] = [
@@ -1846,8 +1620,6 @@ class ShardedBfsChecker(HostEngineBase):
                 params_np[:, s_base] = t1
                 params_np[:, s_base + 1] = t2
                 last_thresh = (t1, t2)
-            if nfuse:
-                params_np[:, f_base] = _fuse_lim_now()
             with self._metrics.phase("upload"):
                 params_in = self._put(params_np)
             _era_w0 = _time.monotonic()
@@ -1862,10 +1634,8 @@ class ShardedBfsChecker(HostEngineBase):
                 self._memory.attach("packed_params", params)
                 self._memory.attach("coverage_slab", params)
                 self._memory.attach("sample_slab", params)
-                if self._fuse > 1:
-                    self._memory.attach("fusion_tail", params)
             cur_budget = max_steps
-            # K-deep speculative chain (oldest first): chain[i] holds the
+            # Speculative chain (oldest first, at most CHAIN_DEPTH): chain[i] holds the
             # i-th chained block's OUTPUT handles (params, rec_fp1,
             # rec_fp2, disc_depth) plus its dispatch timestamp. Unlike the
             # single-device engine, each entry pairs the era with its OWN
@@ -1878,7 +1648,7 @@ class ShardedBfsChecker(HostEngineBase):
                 # (non-blocking) behind the ones already in flight.
                 while (
                     pipeline
-                    and len(chain) < self._chain_depth
+                    and len(chain) < CHAIN_DEPTH
                     and not any(self._spill[s] for s in range(N))
                     and not self._ckpt_stop.is_set()
                     and not self._timed_out()
@@ -2015,15 +1785,8 @@ class ShardedBfsChecker(HostEngineBase):
         # rebuilds the stacks from the checkpoint's spill arrays).
         for s in range(N):
             self._spill[s].close()
-        # Mega-dispatch gauges: deepest speculative chain reached and the
-        # realized fusion ratio (device eras per host dispatch — 1.0 when
-        # neither chaining nor fusion engaged).
+        # The deepest speculative chain reached.
         self._metrics.set_gauge("spec_chain_depth", self._chain_max)
-        n_disp = max(1, self._metrics.get("dispatches"))
-        self._metrics.set_gauge(
-            "fused_eras_per_dispatch",
-            round(self._metrics.get("eras") / n_disp, 3),
-        )
         self._table_dev = table
         if self._memory is not None:
             # Final era's live buffers, for the post-run nbytes parity.

@@ -393,47 +393,6 @@ def test_explorer_coverage_prometheus_and_explain():
         server.shutdown()
 
 
-def test_bench_compare_prints_delta_table(tmp_path, capsys):
-    import bench
-
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(
-        json.dumps(
-            {
-                "value": 100.0,
-                "detail": {
-                    "tpc7": {
-                        "states_per_sec": 100.0,
-                        "telemetry": {"phase_ms": {"device_era": 50.0}},
-                    }
-                },
-            }
-        )
-        + "\n"
-    )
-    b.write_text(
-        json.dumps(
-            {
-                "value": 48.0,
-                "detail": {
-                    "tpc7": {
-                        "states_per_sec": 48.0,
-                        "telemetry": {"phase_ms": {"device_era": 110.0}},
-                    }
-                },
-            }
-        )
-        + "\n"
-    )
-    assert bench.compare_bench(str(a), str(b)) == 0
-    out = capsys.readouterr().out
-    assert "detail.tpc7.states_per_sec" in out
-    assert "-52.0%" in out
-    assert "detail.tpc7.telemetry.phase_ms.device_era" in out
-    assert "+120.0%" in out
-
-
 def test_explorer_ui_ships_coverage_panel():
     # The SPA bundle must actually wire the coverage dashboard: panel +
     # explain view in the page, polling/render logic in the script.

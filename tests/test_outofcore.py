@@ -319,7 +319,7 @@ def test_tcap_change_forces_full_base(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Proactive reshard parity (solo + mesh, pipelined+fused)
+# Proactive reshard parity (solo + mesh, pipelined)
 # ---------------------------------------------------------------------------
 
 
@@ -330,7 +330,6 @@ def test_tpu_bfs_proactive_reshard_parity(monkeypatch):
     checker = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
-        .pipeline(depth=4, fuse=4)
         .spawn_tpu_bfs(
             chunk_size=64, queue_capacity=1 << 12, table_capacity=1 << 8
         )
@@ -352,7 +351,6 @@ def test_mesh_proactive_reshard_parity(monkeypatch):
     checker = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
-        .pipeline(depth=4, fuse=4)
         .spawn_sharded_bfs(
             devices=jax.devices()[:4],
             chunk_size=64,
@@ -392,7 +390,7 @@ def test_mesh_disk_spill_golden(tmp_path, monkeypatch):
 @pytest.mark.slow
 def test_mesh_paxos2_outofcore_parity(monkeypatch):
     """ISSUE 20 acceptance shape: paxos-2 on the full 8-device virtual
-    mesh under a device cap + spill budget, pipelined and fused, must be
+    mesh under a device cap + spill budget, pipelined, must be
     bit-identical to the unconstrained mesh run."""
     import jax
 
@@ -417,7 +415,6 @@ def test_mesh_paxos2_outofcore_parity(monkeypatch):
     capped = (
         TensorModelAdapter(PaxosTensorExhaustive(2))
         .checker()
-        .pipeline(depth=4, fuse=4)
         .spawn_sharded_bfs(devices=jax.devices()[:8], **mesh_opts)
         .join()
     )
@@ -457,97 +454,3 @@ def test_rearm_warning_is_idempotent():
     rec = MemoryRecorder("t", device_limit_bytes=None)
     rec.rearm_warning()  # nothing armed: no-op, no events
     assert rec.warning is None
-
-
-# ---------------------------------------------------------------------------
-# Auto-N fusion pick (satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_fuse_auto_n_backs_off_on_low_gap():
-    from stateright_tpu.engines.common import HostEngineBase
-
-    class _Metrics:
-        def __init__(self):
-            self.gauges = {}
-            self.eras = 0
-
-        def get(self, k):
-            return self.eras if k == "eras" else 0
-
-        def set_gauge(self, k, v):
-            self.gauges[k] = v
-
-    class _Flight:
-        def __init__(self, eras, gap):
-            self._s = {"eras": eras, "host_gap_pct": gap}
-
-        def summary(self):
-            return dict(self._s)
-
-    class _Host:
-        _fuse_auto_n = HostEngineBase._fuse_auto_n
-
-    h = _Host()
-    h._metrics = _Metrics()
-    # Amortized gap -> halve the factor (floor 2 keeps fusion engaged).
-    h._flight = _Flight(eras=32, gap=0.5)
-    assert h._fuse_auto_n(8) == 4
-    assert h._metrics.gauges["fuse_auto_n"] == 4
-    h2 = _Host()
-    h2._metrics = _Metrics()
-    h2._flight = _Flight(eras=32, gap=0.5)
-    assert h2._fuse_auto_n(4) == 2
-    # Gap still material -> keep the configured factor.
-    h3 = _Host()
-    h3._metrics = _Metrics()
-    h3._flight = _Flight(eras=32, gap=25.0)
-    assert h3._fuse_auto_n(4) == 4
-    # Too little history -> keep the configured factor.
-    h4 = _Host()
-    h4._metrics = _Metrics()
-    h4._flight = _Flight(eras=2, gap=0.5)
-    assert h4._fuse_auto_n(4) == 4
-    # No flight recorder -> keep the configured factor.
-    h5 = _Host()
-    h5._metrics = _Metrics()
-    h5._flight = None
-    assert h5._fuse_auto_n(4) == 4
-
-
-def test_fuse_auto_n_result_is_cached_between_rechecks():
-    from stateright_tpu.engines.common import (
-        FUSE_AUTO_RECHECK_ERAS,
-        HostEngineBase,
-    )
-
-    calls = []
-
-    class _Metrics:
-        def __init__(self):
-            self.eras = 0
-
-        def get(self, k):
-            return self.eras
-
-        def set_gauge(self, k, v):
-            pass
-
-    class _Flight:
-        def summary(self):
-            calls.append(1)
-            return {"eras": 32, "host_gap_pct": 0.5}
-
-    class _Host:
-        _fuse_auto_n = HostEngineBase._fuse_auto_n
-
-    h = _Host()
-    h._metrics = _Metrics()
-    h._flight = _Flight()
-    assert h._fuse_auto_n(8) == 4
-    h._metrics.eras += FUSE_AUTO_RECHECK_ERAS - 1
-    assert h._fuse_auto_n(8) == 4
-    assert len(calls) == 1  # cached: summary() not re-walked
-    h._metrics.eras += 1
-    assert h._fuse_auto_n(8) == 4
-    assert len(calls) == 2

@@ -62,7 +62,7 @@ def test_c2_device_engine_reference_golden():
 
 def test_c2_threaded_host_oracle_golden():
     """The vectorized threaded host engine re-derives the reference golden
-    in under a second — the live oracle bench.py uses."""
+    in under a second."""
     twin = (
         TensorModelAdapter(PaxosTensorFull(2))
         .checker()

@@ -17,7 +17,16 @@ reload and never corrupts the resumed run).
 import jax
 import pytest
 
-from stateright_tpu.models import TwoPhaseTensor
+from stateright_tpu.engines import tpu_bfs
+from stateright_tpu.models import (
+    AbdOrderedTensor,
+    AbdTensor,
+    IncrementLockTensor,
+    SingleCopyTensor,
+    TwoPhaseTensor,
+)
+from stateright_tpu.models.paxos import PaxosTensor
+from stateright_tpu.parallel import mesh
 from stateright_tpu.tensor import TensorModelAdapter
 
 # sync_steps=4 forces many short eras so speculative chains actually
@@ -53,7 +62,7 @@ def _fingerprint(c):
     sampler = getattr(c, "_sampler", None)
     if sampler is not None and sampler.size():
         # The deterministic bottom-k sample is part of the golden
-        # contract too: fusion reorders nothing the sampler can see.
+        # contract too: the chain reorders nothing the sampler can see.
         fp["sample"] = tuple(sampler.fingerprints())
     return fp
 
@@ -138,117 +147,97 @@ def test_mesh_parity_2pc5(devices):
 
 
 # ---------------------------------------------------------------------------
-# Mega-dispatch sweep (ISSUE 19 tentpole): K-deep chains x on-device
-# multi-era fusion must stay golden-identical to the serial driver.
+# Era-length sweep: the chain against the serial driver at the same
+# sync_steps.
 # ---------------------------------------------------------------------------
 #
-# depth only changes host scheduling (no new compiled shape); fuse > 1
-# compiles the inner-loop program. The sweep covers K in {1, 2, 4} and
-# fused N in {1, 4} ((4, 4) exercises fusion under a deep chain, which
-# subsumes the shallow-chain fused case): every config must reproduce
-# the serial unique count, max depth, discovery fingerprints, coverage
-# histograms, AND the deterministic bottom-k sample — and a fused run
-# must retire its eras in strictly fewer dispatches.
+# sync_steps sets where eras end, and so how often the chain is entered
+# and how often a chained era does real work rather than an identity
+# no-op. Every length must reproduce the serial run at that length: the
+# unique count, max depth, discovery fingerprints, coverage histograms
+# and the deterministic bottom-k sample.
 
-MEGA_SWEEP = [(1, 1), (2, 1), (4, 1), (4, 4)]
+SYNC_SWEEP = [1, 2, 4, 64]
+
+
+def _mesh_opts(devices, sync_steps=4):
+    return dict(
+        devices=devices,
+        chunk_size=64,
+        queue_capacity_per_shard=1 << 11,
+        table_capacity_per_shard=1 << 10,
+        sync_steps=sync_steps,
+    )
 
 
 @pytest.fixture(scope="module")
-def serial_2pc5_solo():
+def serial_2pc5_solo(sync_steps):
     c = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
         .coverage()
         .pipeline(False)
-        .spawn_tpu_bfs(**OPTS)
+        .spawn_tpu_bfs(**dict(OPTS, sync_steps=sync_steps))
         .join()
     )
     return _fingerprint(c)
 
 
-@pytest.mark.parametrize("depth,fuse", MEGA_SWEEP)
-def test_tpu_bfs_mega_sweep_2pc5(depth, fuse, serial_2pc5_solo):
+@pytest.mark.parametrize("sync_steps", SYNC_SWEEP, scope="module")
+def test_tpu_bfs_mega_sweep_2pc5(sync_steps, serial_2pc5_solo):
     c = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
         .coverage()
-        .pipeline(depth=depth, fuse=fuse)
-        .spawn_tpu_bfs(**OPTS)
+        .spawn_tpu_bfs(**dict(OPTS, sync_steps=sync_steps))
         .join()
     )
     fp = _fingerprint(c)
     assert fp == serial_2pc5_solo
     assert fp["unique"] == 8832
-    tel = c.telemetry()
-    assert tel["spec_chain_depth"] <= depth
-    if fuse > 1:
-        # The amortization headline: strictly fewer host dispatches
-        # than device eras, and the gauge reports the realized ratio.
-        assert tel["dispatches"] < tel["eras"]
-        assert tel["fused_eras_per_dispatch"] > 1.0
-    else:
-        assert tel["fused_eras_per_dispatch"] <= 1.0
+    assert c.telemetry()["spec_chain_depth"] <= tpu_bfs.CHAIN_DEPTH
 
 
 @pytest.fixture(scope="module")
-def serial_2pc5_mesh(devices):
+def serial_2pc5_mesh(sync_steps, devices):
     c = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
         .coverage()
         .pipeline(False)
-        .spawn_sharded_bfs(
-            devices=devices,
-            chunk_size=64,
-            queue_capacity_per_shard=1 << 11,
-            table_capacity_per_shard=1 << 10,
-            sync_steps=4,
-        )
+        .spawn_sharded_bfs(**_mesh_opts(devices, sync_steps))
         .join()
     )
     return _fingerprint(c)
 
 
-@pytest.mark.parametrize("depth,fuse", MEGA_SWEEP)
-def test_mesh_mega_sweep_2pc5(depth, fuse, devices, serial_2pc5_mesh):
+@pytest.mark.parametrize("sync_steps", SYNC_SWEEP, scope="module")
+def test_mesh_mega_sweep_2pc5(sync_steps, devices, serial_2pc5_mesh):
     c = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
         .coverage()
-        .pipeline(depth=depth, fuse=fuse)
-        .spawn_sharded_bfs(
-            devices=devices,
-            chunk_size=64,
-            queue_capacity_per_shard=1 << 11,
-            table_capacity_per_shard=1 << 10,
-            sync_steps=4,
-        )
+        .spawn_sharded_bfs(**_mesh_opts(devices, sync_steps))
         .join()
     )
     fp = _fingerprint(c)
     assert fp == serial_2pc5_mesh
     assert fp["unique"] == 8832
-    tel = c.telemetry()
-    assert tel["spec_chain_depth"] <= depth
-    if fuse > 1:
-        assert tel["dispatches"] < tel["eras"]
-        assert tel["fused_eras_per_dispatch"] > 1.0
+    assert c.telemetry()["spec_chain_depth"] <= mesh.CHAIN_DEPTH
 
 
 def test_tpu_bfs_mega_parity_paxos2():
-    """The deepest config against serial on the bigger model."""
+    """The chain against serial on the bigger model."""
     from stateright_tpu.models.paxos import PaxosTensorExhaustive
 
     fps = {}
-    for cfg in (None, (4, 4)):
+    for on in (False, True):
         b = TensorModelAdapter(PaxosTensorExhaustive(2)).checker().coverage()
-        if cfg is None:
-            b.pipeline(False)
-        else:
-            b.pipeline(depth=cfg[0], fuse=cfg[1])
-        fps[cfg] = _fingerprint(b.spawn_tpu_bfs(**_paxos_opts()).join())
-    assert fps[(4, 4)] == fps[None]
-    assert fps[None]["unique"] == 16_668
+        fps[on] = _fingerprint(
+            b.pipeline(on).spawn_tpu_bfs(**_paxos_opts()).join()
+        )
+    assert fps[True] == fps[False]
+    assert fps[False]["unique"] == 16_668
 
 
 def test_mesh_mega_parity_paxos2(devices):
@@ -262,22 +251,84 @@ def test_mesh_mega_parity_paxos2(devices):
         sync_steps=64,
     )
     fps = {}
-    for cfg in (None, (4, 4)):
+    for on in (False, True):
         b = TensorModelAdapter(PaxosTensorExhaustive(2)).checker().coverage()
-        if cfg is None:
-            b.pipeline(False)
+        fps[on] = _fingerprint(b.pipeline(on).spawn_sharded_bfs(**opts).join())
+    assert fps[True] == fps[False]
+    assert fps[False]["unique"] == 16_668
+
+
+# ---------------------------------------------------------------------------
+# The other bundled lane twins, at short eras, on both device engines.
+# ---------------------------------------------------------------------------
+
+TWINS = {
+    "increment-lock-3": lambda: IncrementLockTensor(3),
+    "single-copy-2x2": lambda: SingleCopyTensor(2, 2),
+    "abd-2": lambda: AbdTensor(2),
+    "abd-ordered-2": lambda: AbdOrderedTensor(2),
+    "paxos-1": lambda: PaxosTensor(1),
+}
+
+
+def _spawn(builder, engine, devices, **kw):
+    if engine == "tpu_bfs":
+        return builder.spawn_tpu_bfs(**dict(OPTS, **kw))
+    return builder.spawn_sharded_bfs(**dict(_mesh_opts(devices), **kw))
+
+
+@pytest.mark.parametrize("twin", sorted(TWINS))
+@pytest.mark.parametrize("engine", ["tpu_bfs", "sharded"])
+def test_twin_parity_short_eras(engine, twin, devices):
+    fps = {}
+    for on in (False, True):
+        b = TensorModelAdapter(TWINS[twin]()).checker().coverage().pipeline(on)
+        fps[on] = _fingerprint(_spawn(b, engine, devices).join())
+    assert fps[True] == fps[False]
+    assert fps[False]["unique"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The adaptive step budget: a timeout or a checkpoint cadence makes eras
+# end on their step budget, so chained eras do real work at budget exits.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("poll", ["timeout", "checkpoint"])
+@pytest.mark.parametrize("engine", ["tpu_bfs", "sharded"])
+def test_adaptive_budget_parity(engine, poll, devices, tmp_path):
+    fps = {}
+    for on in (False, True):
+        b = (
+            TensorModelAdapter(TwoPhaseTensor(5))
+            .checker()
+            .coverage()
+            .pipeline(on)
+        )
+        kw = dict(sync_steps=4096)
+        if poll == "timeout":
+            b = b.timeout(600)
         else:
-            b.pipeline(depth=cfg[0], fuse=cfg[1])
-        fps[cfg] = _fingerprint(b.spawn_sharded_bfs(**opts).join())
-    assert fps[(4, 4)] == fps[None]
-    assert fps[None]["unique"] == 16_668
+            kw.update(
+                checkpoint_path=str(tmp_path / f"adaptive-{on}.ckpt.npz"),
+                checkpoint_every=600,
+            )
+        c = _spawn(b, engine, devices, **kw).join()
+        fps[on] = (_fingerprint(c), c.telemetry())
+    (fp_on, tel_on), (fp_off, tel_off) = fps[True], fps[False]
+    assert fp_on == fp_off
+    assert fp_on["unique"] == 8832
+    # Budget exits: more than one era in both runs, and the chain ran.
+    assert tel_off["eras"] > 1
+    assert tel_on.get("spec_dispatch", 0) >= 1
+    assert tel_off.get("spec_dispatch", 0) == 0
 
 
 def test_tpu_bfs_kill_resume_under_deep_chain(tmp_path):
-    """A checkpointed run killed at a boundary and resumed with a deep
-    fused chain must land on the serial golden (the final checkpoint of
-    a partial run is the exact stopping point, and the resumed mega-
-    dispatch driver replays nothing and skips nothing)."""
+    """A checkpointed run killed at a boundary and resumed with the
+    speculative chain must land on the serial golden (the final
+    checkpoint of a partial run is the exact stopping point, and the
+    resumed pipelined driver replays nothing and skips nothing)."""
     ckpt = str(tmp_path / "deep.ckpt.npz")
     part = (
         TensorModelAdapter(TwoPhaseTensor(5))
@@ -291,7 +342,6 @@ def test_tpu_bfs_kill_resume_under_deep_chain(tmp_path):
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
         .coverage()
-        .pipeline(depth=4, fuse=4)
         .spawn_tpu_bfs(resume_from=ckpt, **OPTS)
         .join()
     )
@@ -328,7 +378,6 @@ def test_tpu_bfs_chaos_spec_discard_recovers(tmp_path):
     checker = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
-        .pipeline(depth=4, fuse=4)
         .spawn_tpu_bfs(
             resume_from=ckpt,
             checkpoint_path=ckpt,
@@ -348,13 +397,7 @@ def test_tpu_bfs_chaos_spec_discard_recovers(tmp_path):
 
 def test_mesh_chaos_spec_discard_recovers(tmp_path, devices):
     ckpt = str(tmp_path / "mesh-spec.ckpt.npz")
-    opts = dict(
-        devices=devices,
-        chunk_size=64,
-        queue_capacity_per_shard=1 << 11,
-        table_capacity_per_shard=1 << 10,
-        sync_steps=4,
-    )
+    opts = _mesh_opts(devices)
     part = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
@@ -366,7 +409,6 @@ def test_mesh_chaos_spec_discard_recovers(tmp_path, devices):
     checker = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
-        .pipeline(depth=4, fuse=4)
         .spawn_sharded_bfs(
             resume_from=ckpt,
             checkpoint_path=ckpt,

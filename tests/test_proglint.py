@@ -227,29 +227,6 @@ def test_exact_budget_match_is_silent():
     assert "STR604" not in codes(report)
 
 
-def test_fusion_factor_keys_distinct_budget_rows():
-    """Two fusion factors are two different compiled artifacts: each gets
-    its own committed budget row (`tpu_bfs|...` vs `tpu_bfs+f4|...`) with
-    the fusion factor pinned in the geometry, so neither ratchet can
-    silently absorb the other's growth."""
-    from stateright_tpu.engines.compiled import model_signature
-
-    sig = model_signature(IncrementTensor(2))
-    with open(proglint.BUDGETS_PATH) as fh:
-        entries = json.load(fh)["entries"]
-    f = proglint.FUSED_LINT_FACTOR
-    for base in ("tpu_bfs", "sharded"):
-        classic = entries[f"{base}|{sig}"]
-        fused = entries[f"{proglint._engine_key(base, f)}|{sig}"]
-        assert classic["geometry"]["fuse"] == 1
-        assert fused["geometry"]["fuse"] == f
-        # The fused program carries the inner loop + fusion tail: it can
-        # never be the same artifact as the classic one.
-        assert fused["ops"] != classic["ops"]
-    assert proglint._engine_key("tpu_bfs", 1) == "tpu_bfs"
-    assert proglint._engine_key("tpu_bfs", 4) == "tpu_bfs+f4"
-
-
 # ---------------------------------------------------------------------------
 # STR605 — compile-signature instability
 # ---------------------------------------------------------------------------

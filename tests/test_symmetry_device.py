@@ -127,14 +127,13 @@ def test_device_closure_matches_the_plain_closure(n):
     } == {"abort agreement": n + 1, "commit agreement": 3 * n + 2}
 
 
-def test_canon_rewrites_under_fused_pipelined_eras():
-    """Fused and chained eras carry the counter across inner eras and
-    retried partial steps without counting twice."""
+def test_canon_rewrites_under_pipelined_short_eras():
+    """Chained short eras carry the counter across eras and retried
+    partial steps without counting twice."""
     sym = (
         TensorModelAdapter(TwoPhaseTensor(5))
         .checker()
         .symmetry()
-        .pipeline(depth=4, fuse=4)
         .spawn_tpu_bfs(
             chunk_size=64, queue_capacity=1 << 13, table_capacity=1 << 14,
             sync_steps=4,
@@ -142,7 +141,6 @@ def test_canon_rewrites_under_fused_pipelined_eras():
         .join()
     )
     tel = sym.telemetry()
-    assert tel["dispatches"] < tel["eras"]
     assert sym.unique_state_count() == TPC5_SYM_CLOSURE
     assert tel["canon_rewrites"] == closure.rewrites(5) == 1_560
     # The params' last word is the counter; the memory ledger carves it.
