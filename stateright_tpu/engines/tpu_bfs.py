@@ -459,20 +459,21 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
             with stage_scope("expand"):
                 ex = expand_lean(rows, ebits, depth, active, depth_limit)
             # COMPACT EARLY (the round-5 redesign): validity compaction is
-            # the only [C*A]-wide random-access work in the step. Hashing,
-            # parent lookup, the visited-set insert, and the ring append
-            # all run at the compacted [vcap] width. In-batch duplicate
+            # the only [C*A]-wide random-access work in the step. Parent
+            # lookup, the visited-set insert, and the ring append all run
+            # at the compacted widths. In-batch duplicate
             # candidates need no separate dedup pass — the insert's claim
             # protocol arbitrates them exactly (one winner per distinct
             # key, same benign-race semantics as the reference's DashMap
             # entry API, bfs.rs:302-315).
             with stage_scope("compact"):
                 vids, vvalid, n_val = vs._compact_ids(ex.valid, vcap)
-                cl = tuple(ex.flat[s][vids] for s in range(S))
             if canon:
                 # Symmetry reduction: canonicalize at the compacted width,
                 # before fingerprinting — the ring and table then only
                 # ever see representatives.
+                with stage_scope("compact"):
+                    cl = tuple(ex.flat[s][vids] for s in range(S))
                 with stage_scope("canon"):
                     rep = tm.representative_lanes(jnp, cl)
                     moved = rep[0] != cl[0]
@@ -480,8 +481,20 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                         moved = moved | (rep[s] != cl[s])
                     rewrites = (moved & vvalid).sum(dtype=jnp.uint32)
                     cl = rep
-            with stage_scope("hash"):
-                ch1, ch2 = hash_lanes_jnp(cl)
+                with stage_scope("hash"):
+                    ch1, ch2 = hash_lanes_jnp(cl)
+            else:
+                # Without symmetry the rows are final at expansion: hash
+                # at the padded width (elementwise, so the expand
+                # arithmetic fuses into it) and gather only the two hash
+                # lanes here. A gather costs by the indices it touches,
+                # so the S state lanes are gathered once, below, at the
+                # deduplicated width.
+                with stage_scope("hash"):
+                    fh1, fh2 = hash_lanes_jnp(ex.flat)
+                with stage_scope("compact"):
+                    ch1 = fh1[vids]
+                    ch2 = fh2[vids]
             # Stage-2 compaction: in-batch dedup (claim-arbitrated,
             # approximate — the insert arbitrates leftovers exactly) then
             # re-compact to the distinct-candidate width, so the probe
@@ -495,9 +508,16 @@ def _build_loop(tm: TensorModel, props, chunk: int, qcap: int, canon: bool = Fal
                 dids, dvalid, n_d = vs._compact_ids(reps, rcap)
                 dh1 = ch1[dids]
                 dh2 = ch2[dids]
-                dl = tuple(cl[s][dids] for s in range(S))
+                # fid: flat candidate id a*C+c. (The symmetric body takes
+                # its rows from the canonicalized `cl` instead.)
+                if canon:
+                    dl = tuple(cl[s][dids] for s in range(S))
+                    fid = vids[dids]
+                else:
+                    fid = vids[dids]
+                    dl = tuple(ex.flat[s][fid] for s in range(S))
                 # parent row of candidate a*C+c is c
-                src = vids[dids] % u(chunk)
+                src = fid % u(chunk)
                 dp1 = jnp.where(dvalid, row_h1[src], u(0))
                 dp2 = jnp.where(dvalid, row_h2[src], u(0))
                 debits = ex.ebits[src]

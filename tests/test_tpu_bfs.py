@@ -6,11 +6,21 @@ validity of reconstructed discovery paths must agree. Runs on the virtual
 CPU platform in CI; the same code path is what executes on the TPU chip.
 """
 
+import collections
+import re
+
 import numpy as np
 import pytest
 
-from stateright_tpu import Expectation, Property, TensorModelAdapter
+from stateright_tpu import Expectation, HasDiscoveries, Property, TensorModelAdapter
+from stateright_tpu.engines.compiled import era_geometry
+from stateright_tpu.engines.multiplex import run_multiplexed
+from stateright_tpu.engines.tpu_bfs import _build_loop, _vcap, loop_abstract_args
 from stateright_tpu.models import IncrementTensor, TwoPhaseTensor
+from stateright_tpu.models.abd import AbdTensor
+from stateright_tpu.models.paxos import PaxosTensor, PaxosTensorExhaustive
+from stateright_tpu.models.single_copy import SingleCopyTensor
+from stateright_tpu.path import Path
 from stateright_tpu.tensor import TensorModel, TensorProperty
 
 
@@ -151,3 +161,96 @@ def test_telemetry_surfaces_engine_gauges():
     assert 0 < t["load_factor"] < 1
     assert t["take_cap"] >= 1
     assert "Telemetry." in buf.getvalue()
+
+
+_GATHER = re.compile(
+    r'"stablehlo\.gather".*: \(tensor<(\d+)x\w+>, tensor<\d+x1x\w+>\)'
+    r" -> tensor<(\d+)x\w+>"
+)
+
+
+@pytest.mark.parametrize("canon", [False, True], ids=["plain", "symmetric"])
+def test_state_lanes_gathered_once_at_the_distinct_width(canon):
+    """Without symmetry the era body hashes every padded candidate and
+    gathers only the two hash lanes at the valid width; the S state lanes
+    are gathered once, from the padded rows, at the distinct width. The
+    symmetric body canonicalizes the gathered rows before it hashes them,
+    so its S lanes are gathered at the valid width and again from there."""
+    tm = TwoPhaseTensor(4) if canon else PaxosTensor(2)
+    S, A = tm.state_width, tm.max_actions
+    props = tm.tensor_properties()
+    g = era_geometry(tm)
+    chunk = g["chunk"]
+    program = _build_loop(
+        tm, props, chunk, g["qcap"], canon, g["cov"], sample_k=g["sample_k"]
+    )
+    args = loop_abstract_args(
+        tm, props, chunk, g["qcap"], g["tcap"], g["cov"], g["sample_k"], canon
+    )
+    # (operand elements, result elements) of every 1-D gather
+    widths = collections.Counter(
+        (int(m.group(1)), int(m.group(2)))
+        for m in _GATHER.finditer(program.serial.lower(*args).as_text())
+    )
+    padded, vcap = chunk * A, _vcap(A, chunk)
+    rcap = max(128 * A, (2 * vcap) // 5)
+    assert S >= 3
+    if canon:
+        assert widths[(padded, vcap)] == S
+        assert widths[(padded, rcap)] == 0
+        assert widths[(vcap, rcap)] >= S
+    else:
+        assert widths[(padded, vcap)] == 2
+        assert widths[(padded, rcap)] == S
+        assert widths[(vcap, rcap)] < S
+
+
+_NEVER = HasDiscoveries.all_of(["<no such property>"])
+
+PARITY_MODELS = {
+    "2pc-5": lambda: TwoPhaseTensor(5),
+    "paxos-2": lambda: PaxosTensorExhaustive(2),
+    "single-copy-2x2": lambda: SingleCopyTensor(2, 2),
+    "abd-2": lambda: AbdTensor(2),
+}
+
+
+def _assert_matches_host_oracle(tm, dev):
+    """Counts, discoveries and replayable shortest paths against the
+    vectorized host BFS run to exhaustion on the same model."""
+    adapter = TensorModelAdapter(tm)
+    host = adapter.checker().finish_when(_NEVER).threads(4).spawn_bfs().join()
+    assert dev.unique_state_count() == host.unique_state_count()
+    assert dev.state_count() == host.state_count()
+    found = dev.discoveries()
+    assert sorted(found) == sorted(host.discoveries())
+    props = {p.name: p for p in adapter.properties()}
+    for name, path in found.items():
+        states = path.into_states()
+        replayed = Path.from_actions(adapter, states[0], path.into_actions())
+        assert replayed is not None and replayed.into_states() == states, name
+        assert len(path) == len(host.discovery(name)), name
+        holds = props[name].condition(adapter, states[-1])
+        assert holds == (props[name].expectation == Expectation.SOMETIMES), name
+
+
+@pytest.mark.parametrize("name", sorted(PARITY_MODELS))
+def test_era_body_matches_host_oracle(name):
+    tm = PARITY_MODELS[name]()
+    dev = (
+        TensorModelAdapter(tm)
+        .checker()
+        .finish_when(_NEVER)
+        .spawn_tpu_bfs(chunk_size=256, queue_capacity=1 << 15, table_capacity=1 << 15)
+        .join()
+    )
+    _assert_matches_host_oracle(tm, dev)
+
+
+def test_multiplexed_era_body_matches_host_oracle():
+    builders = [
+        TensorModelAdapter(TwoPhaseTensor(4)).checker().finish_when(_NEVER)
+        for _ in range(2)
+    ]
+    for lane in run_multiplexed(builders, lanes=2):
+        _assert_matches_host_oracle(TwoPhaseTensor(4), lane)
